@@ -61,7 +61,7 @@ def _supervise(cmd, env, max_restarts: int, monitor_interval: float,
     The child is polled every ``monitor_interval`` seconds. With
     ``watchdog_timeout > 0`` a heartbeat file is exported as
     ``ACCELERATE_HEARTBEAT_FILE``; if the child stops touching it for longer
-    than the timeout (hung collective, dead relay) it is killed and counted
+    than the timeout (hung collective, lost host) it is killed and counted
     as a failure.
 
     Signals: SIGTERM/SIGINT sent to the supervisor (TPU preemption targets

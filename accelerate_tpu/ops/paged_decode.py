@@ -8,20 +8,23 @@ in full) rather than with live tokens. The kernels here walk each slot's
 block table *inside* the kernel instead:
 
 * **`paged_flash_decode`** — one query token per slot. Grid ``(slots,
-  kv_heads, blocks_per_row)``; the block tables and per-slot positions ride
-  in as scalar-prefetch operands so every kv tile's BlockSpec index map
-  resolves ``tables[slot, j]`` directly — the DMA fetches pool block
-  ``tables[slot, j]``, nothing else. Blocks wholly past a slot's position
-  are *skipped* (``@pl.when``), never partially weighted — exactly the
-  contract documented on ``paged_attention`` (masked scores softmax to an
-  exp-underflow-exact 0.0, so skipping == computing). For a live slot the
-  skipped tail *is* the row's null-block padding (allocation covers every
-  position ``<= pos``), so released/unallocated entries are never read as
-  real context. int8 pools dequantize per fetched tile from the
-  per-(block, position) scales — only live blocks' scales are ever applied.
-  Online softmax (acc/m/l VMEM scratch, init at j==0, finalize at the last
-  block) with the grouped-GQA layout: q is blocked ``(1, 1, n_rep, d)`` per
-  kv head, so KV is read once per *group*, never repeated ``n_rep``×.
+  blocks_per_row)``; the block tables and per-slot positions ride in as
+  scalar-prefetch operands so every kv tile's BlockSpec index map resolves
+  ``tables[slot, j]`` directly — the DMA fetches pool block
+  ``tables[slot, j]`` whole (all KV heads: one contiguous copy, and the one
+  tile of a position-major pool the TPU lowering accepts), nothing else.
+  Blocks wholly past a slot's position are *skipped* (``@pl.when``), never
+  partially weighted — exactly the contract documented on
+  ``paged_attention`` (masked scores softmax to an exp-underflow-exact 0.0,
+  so skipping == computing). For a live slot the skipped tail *is* the
+  row's null-block padding (allocation covers every position ``<= pos``),
+  so released/unallocated entries are never read as real context. int8
+  pools dequantize per fetched tile from the per-(block, position) scales —
+  only live blocks' scales are ever applied. Online softmax (acc/m/l VMEM
+  scratch, init at j==0, finalize at the last block) with the grouped-GQA
+  layout: q is ``(h_kv, n_rep, d)`` and the kernel loops the KV heads of
+  the fetched block, so KV is read once per *group*, never repeated
+  ``n_rep``×.
 
 * **`paged_flash_verify`** — the W-token speculative-verify window. Same
   table walk over committed history, masked *strictly* ``k_pos < pos``
@@ -34,7 +37,7 @@ block table *inside* the kernel instead:
 * **`fused_sample`** — the sampling epilogue, semantics pinned by
   ``engine.py::_filter_logits`` / ``_sample_rows``: temperature scaling,
   top-k, top-p ("nucleus") filtering and the categorical draw fused into
-  one kernel, one program instance per slot row. Instead of materializing
+  one kernel, eight slot rows per program instance. Instead of materializing
   a sorted copy of the logits (the reference's ``sort``/``cumsum``), both
   filters reduce to *threshold* comparisons computed by a 32-step binary
   search over the order-preserving uint32 image of f32 — the k-th largest
@@ -84,22 +87,49 @@ def _resolve_interpret(interpret):
 
 
 # ------------------------------------------------------------ decode kernel
+def _online_softmax_update(g, s, v, acc_ref, m_ref, l_ref):
+    """Fold one tile's masked scores ``s`` (rows, n) and values ``v`` (n, d)
+    into kv head ``g``'s running (acc, m, l)."""
+    m_prev = m_ref[g]  # (rows, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    pexp = jnp.exp(s - m_cur)
+    l_ref[g] = alpha * l_ref[g] + jnp.sum(pexp, axis=-1, keepdims=True)
+    acc_ref[g] = acc_ref[g] * alpha + _dot_f32(pexp.astype(v.dtype), v)
+    m_ref[g] = m_cur
+
+
+def _load_kv_head(k_ref, v_ref, g, scales):
+    """KV head ``g`` of the fetched pool block as two (bs, d) tiles. The
+    block holds every KV head — ``(1, bs, h_kv, d)``, the only tile of the
+    position-major pool whose last two dimensions the TPU lowering accepts
+    (they equal the array's) — so one head is a static strided read."""
+    k = k_ref[0, :, g, :]
+    v = v_ref[0, :, g, :]
+    if scales is not None:
+        ks_ref, vs_ref = scales  # (1, 1, bs, 1): this block's scale column
+        k = k.astype(jnp.float32) * ks_ref[0, 0]
+        v = v.astype(jnp.float32) * vs_ref[0, 0]
+    return k, v
+
+
 def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   block_size, scale, softcap, quantized):
+                   block_size, h_kv, scale, softcap, quantized):
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        *scales, o_ref, acc_ref, m_ref, l_ref = rest
     else:
+        scales = None
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
     p = pos_ref[b]
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     # Block j holds positions [j*bs, (j+1)*bs): skip it entirely once its
     # first position is past the query — the paged_attention contract (a
@@ -110,31 +140,28 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     # j==0 since pos >= 0) guarantees l > 0 at finalize.
     @pl.when(j * block_size <= p)
     def _compute():
-        q = q_ref[0, 0]          # (n_rep, d) — the kv head's whole GQA group
-        k = k_ref[0, :, 0, :]    # (bs, d)
-        v = v_ref[0, :, 0, :]
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0][:, None]
-        s = _dot_f32(q, k, transpose_b=True) * scale  # (n_rep, bs), f32
-        if softcap is not None:  # Gemma-2 tanh capping, pre-mask
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos <= p, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        pexp = jnp.exp(s - m_cur[:, None])
-        l_ref[:, 0] = alpha * l_prev + jnp.sum(pexp, axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + _dot_f32(pexp.astype(v.dtype), v)
-        m_ref[:, 0] = m_cur
+        for g in range(h_kv):
+            q = q_ref[0, g]  # (n_rep, d) — the kv head's whole GQA group
+            k, v = _load_kv_head(k_ref, v_ref, g, scales)
+            s = _dot_f32(q, k, transpose_b=True) * scale  # (n_rep, bs), f32
+            if softcap is not None:  # Gemma-2 tanh capping, pre-mask
+                s = softcap * jnp.tanh(s / softcap)
+            k_pos = j * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos <= p, s, NEG_INF)
+            _online_softmax_update(g, s, v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == nb - 1)
     def _finalize():
-        l = l_ref[:, 0]
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _gathered_scale_columns(scale, block_tables):
+    """Per-slot scale columns for the kernels: ``scale`` (num_blocks, bs) →
+    (B, blocks_per_row, bs, 1), gathered through the tables by XLA. A
+    ``(1, bs)`` row of the pool-shaped array is not a tile the TPU lowering
+    accepts; this copy is 4 bytes a position beside the ``2 * h_kv * d`` the
+    kernel reads for it, and only live blocks' columns are ever applied."""
+    return scale[block_tables][..., None]
 
 
 def paged_flash_decode(
@@ -183,33 +210,33 @@ def paged_flash_decode(
     quantized = k_scale is not None
 
     qg = q.reshape(b, h_kv, n_rep, d)
-    kv_spec = pl.BlockSpec((1, bs, 1, d), lambda bb, g, j, t, p: (t[bb, j], 0, g, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, n_rep, d), lambda bb, g, j, t, p: (bb, g, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+    q_spec = pl.BlockSpec((1, h_kv, n_rep, d), lambda bb, j, t, p: (bb, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, h_kv, d), lambda bb, j, t, p: (t[bb, j], 0, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     args = [qg, k_pool, v_pool]
     if quantized:
-        s_spec = pl.BlockSpec((1, bs), lambda bb, g, j, t, p: (t[bb, j], 0))
+        s_spec = pl.BlockSpec((1, 1, bs, 1), lambda bb, j, t, p: (bb, j, 0, 0))
         in_specs += [s_spec, s_spec]
-        args += [k_scale, v_scale]
+        args += [
+            _gathered_scale_columns(k_scale, block_tables),
+            _gathered_scale_columns(v_scale, block_tables),
+        ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h_kv, bpr),
+        grid=(b, bpr),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, n_rep, d), lambda bb, g, j, t, p: (bb, g, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((n_rep, d), jnp.float32),
-            pltpu.VMEM((n_rep, 1), jnp.float32),
-            pltpu.VMEM((n_rep, 1), jnp.float32),
+            pltpu.VMEM((h_kv, n_rep, d), jnp.float32),
+            pltpu.VMEM((h_kv, n_rep, 1), jnp.float32),
+            pltpu.VMEM((h_kv, n_rep, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _decode_kernel, block_size=bs, scale=scale, softcap=softcap,
-            quantized=quantized,
+            _decode_kernel, block_size=bs, h_kv=h_kv, scale=scale,
+            softcap=softcap, quantized=quantized,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h_kv, n_rep, d), q.dtype),
@@ -220,34 +247,29 @@ def paged_flash_decode(
 
 # ------------------------------------------------------------ verify kernel
 def _verify_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, wk_ref, wv_ref,
-                   *rest, block_size, w, n_rep, scale, softcap, quantized):
+                   *rest, block_size, h_kv, w, n_rep, scale, softcap, quantized):
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        *scales, o_ref, acc_ref, m_ref, l_ref = rest
     else:
+        scales = None
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)  # blocks_per_row + 1 (last step = the window)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)  # blocks_per_row + 1 (last step = the window)
     p = pos_ref[b]
     rows = n_rep * w
-    # q row layout: (head-in-group r) * w + (window index q_idx)
-    q_idx = lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % w
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _accumulate(s, v):
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        pexp = jnp.exp(s - m_cur[:, None])
-        l_ref[:, 0] = alpha * l_prev + jnp.sum(pexp, axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + _dot_f32(pexp.astype(v.dtype), v)
-        m_ref[:, 0] = m_cur
+    def _scores(q, k):
+        s = _dot_f32(q, k, transpose_b=True) * scale
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        return s
 
     # History phase: committed pool blocks, masked STRICTLY k_pos < pos —
     # the window's own positions [pos, pos+W) are not in the pool (the
@@ -257,18 +279,12 @@ def _verify_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, wk_ref, wv_ref,
     # verify_attention's k_pos <= pos + q_idx on every committed position.
     @pl.when((j < nj - 1) & (j * block_size < p))
     def _history():
-        q = q_ref[0, 0]          # (rows, d)
-        k = k_ref[0, :, 0, :]    # (bs, d)
-        v = v_ref[0, :, 0, :]
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0][:, None]
-        s = _dot_f32(q, k, transpose_b=True) * scale  # (rows, bs)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < p, s, NEG_INF)
-        _accumulate(s, v)
+        for g in range(h_kv):
+            k, v = _load_kv_head(k_ref, v_ref, g, scales)
+            s = _scores(q_ref[0, g], k)  # (rows, bs)
+            k_pos = j * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos < p, s, NEG_INF)
+            _online_softmax_update(g, s, v, acc_ref, m_ref, l_ref)
 
     # Window phase (last grid step): the W fresh K/V columns, attended
     # causally within the window — query q_idx sees window key k_idx iff
@@ -276,17 +292,18 @@ def _verify_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, wk_ref, wv_ref,
     # finalize even when no history block survives (pos == 0).
     @pl.when(j == nj - 1)
     def _window():
-        q = q_ref[0, 0]
-        k = wk_ref[0, :, 0, :]   # (w, d) — full precision, never quantized
-        v = wv_ref[0, :, 0, :]
-        s = _dot_f32(q, k, transpose_b=True) * scale  # (rows, w)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        k_idx = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_idx <= q_idx, s, NEG_INF)
-        _accumulate(s, v)
-        l = l_ref[:, 0]
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        # q row layout: (head-in-group r) * w + (window index q_idx)
+        row = lax.broadcasted_iota(jnp.int32, (rows, w), 0)
+        q_idx = row
+        for r in range(1, n_rep):
+            q_idx = q_idx - jnp.where(row >= r * w, w, 0)
+        causal = lax.broadcasted_iota(jnp.int32, (rows, w), 1) <= q_idx
+        for g in range(h_kv):
+            v = wv_ref[0, :, g, :]  # (w, d) — full precision, never quantized
+            s = _scores(q_ref[0, g], wk_ref[0, :, g, :])  # (rows, w)
+            s = jnp.where(causal, s, NEG_INF)
+            _online_softmax_update(g, s, v, acc_ref, m_ref, l_ref)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_flash_verify(
@@ -331,42 +348,42 @@ def paged_flash_verify(
     qf = q.reshape(b, w, h_kv, n_rep, d).transpose(0, 2, 3, 1, 4)
     qf = qf.reshape(b, h_kv, rows, d)
 
-    def _pool_index(bb, g, j, t, p):
+    def _pool_block(bb, j, t):
         # clamped on the (skipped) window step so the map stays total
-        return (t[bb, jnp.minimum(j, bpr - 1)], 0, g, 0)
+        return t[bb, jnp.minimum(j, bpr - 1)]
 
-    kv_spec = pl.BlockSpec((1, bs, 1, d), _pool_index)
-    win_spec = pl.BlockSpec((1, w, 1, d), lambda bb, g, j, t, p: (bb, 0, g, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, rows, d), lambda bb, g, j, t, p: (bb, g, 0, 0)),
-        kv_spec,
-        kv_spec,
-        win_spec,
-        win_spec,
-    ]
+    q_spec = pl.BlockSpec((1, h_kv, rows, d), lambda bb, j, t, p: (bb, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, bs, h_kv, d), lambda bb, j, t, p: (_pool_block(bb, j, t), 0, 0, 0)
+    )
+    win_spec = pl.BlockSpec((1, w, h_kv, d), lambda bb, j, t, p: (bb, 0, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec, win_spec, win_spec]
     args = [qf, k_pool, v_pool, win_k, win_v]
     if quantized:
         s_spec = pl.BlockSpec(
-            (1, bs), lambda bb, g, j, t, p: (t[bb, jnp.minimum(j, bpr - 1)], 0)
+            (1, 1, bs, 1), lambda bb, j, t, p: (bb, jnp.minimum(j, bpr - 1), 0, 0)
         )
         in_specs += [s_spec, s_spec]
-        args += [k_scale, v_scale]
+        args += [
+            _gathered_scale_columns(k_scale, block_tables),
+            _gathered_scale_columns(v_scale, block_tables),
+        ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h_kv, bpr + 1),
+        grid=(b, bpr + 1),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, d), lambda bb, g, j, t, p: (bb, g, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((h_kv, rows, d), jnp.float32),
+            pltpu.VMEM((h_kv, rows, 1), jnp.float32),
+            pltpu.VMEM((h_kv, rows, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _verify_kernel, block_size=bs, w=w, n_rep=n_rep, scale=scale,
-            softcap=softcap, quantized=quantized,
+            _verify_kernel, block_size=bs, h_kv=h_kv, w=w, n_rep=n_rep,
+            scale=scale, softcap=softcap, quantized=quantized,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h_kv, rows, d), q.dtype),
@@ -387,10 +404,13 @@ def _float_key(x):
     return jnp.where(neg, ~u, u | jnp.uint32(0x80000000))
 
 
+_SAMPLE_ROWS = 8  # rows per program: one f32 sublane tile
+
+
 def _sample_kernel(temp_ref, tk_ref, tp_ref, logits_ref, noise_ref, out_ref,
                    *, vocab):
-    # Semantics contract: engine._filter_logits + engine._sample_rows, one
-    # row per program. The reference sorts the row and derives (a) the
+    # Semantics contract: engine._filter_logits + engine._sample_rows, every
+    # reduction per row. The reference sorts the row and derives (a) the
     # k-th largest value `kth` and (b) the top-p cutoff `sorted_f[c-1]`
     # where c = #(exclusive-cumsum(softmax(top-k-kept, sorted)) < p); its
     # final rule is value-level: keep x iff [~k_on or x >= kth] and
@@ -407,18 +427,25 @@ def _sample_kernel(temp_ref, tk_ref, tp_ref, logits_ref, noise_ref, out_ref,
     #     search finds the minimal float key satisfying S < p*Z; summation
     #     order differs from the reference cumsum only in last-ulp rounding
     #     AT the p boundary (measure-zero on real logits).
-    i = pl.program_id(0)
-    t = temp_ref[i]
-    tk = tk_ref[i]
-    tp = tp_ref[i]
-    x = logits_ref[...]  # (1, V) f32
+    t = temp_ref[...]    # (R, 1) per-row knobs
+    tk = tk_ref[...]
+    tp = tp_ref[...]
+    x = logits_ref[...]  # (R, V) f32
     noise = noise_ref[...]
-    iota = lax.broadcasted_iota(jnp.int32, (1, vocab), 1)
+    iota = lax.broadcasted_iota(jnp.int32, x.shape, 1)
     neg_inf = jnp.float32(-jnp.inf)
 
+    def row_sum(a):
+        return jnp.sum(a, axis=-1, keepdims=True)
+
+    def row_max(a):
+        return jnp.max(a, axis=-1, keepdims=True)
+
+    def first_index_of(a, m):
+        return jnp.min(jnp.where(a == m, iota, vocab), axis=-1, keepdims=True)
+
     # greedy = argmax of the RAW logits (first max index), per _sample_rows
-    m_raw = jnp.max(x)
-    greedy = jnp.min(jnp.where(x == m_raw, iota, vocab))
+    greedy = first_index_of(x, row_max(x))
 
     safe_t = jnp.where(t > 0, t, jnp.float32(1.0))
     scaled = x / safe_t
@@ -428,41 +455,40 @@ def _sample_kernel(temp_ref, tk_ref, tp_ref, logits_ref, noise_ref, out_ref,
     k_eff = jnp.clip(tk, 1, vocab)
     # maximal key with count(key >= key0) >= k_eff == key of the k-th
     # largest element (count() only steps at element keys)
-    kkey = jnp.uint32(0)
+    kkey = jnp.zeros_like(tk, dtype=jnp.uint32)
     for bit in range(31, -1, -1):
         cand = kkey | jnp.uint32(1 << bit)
-        cnt = jnp.sum(jnp.where(key >= cand, 1, 0))
+        cnt = row_sum(jnp.where(key >= cand, 1, 0))
         kkey = jnp.where(cnt >= k_eff, cand, kkey)
-    kth = jnp.max(jnp.where(key == kkey, scaled, neg_inf))
+    kth = row_max(jnp.where(key == kkey, scaled, neg_inf))
     keep_k = jnp.logical_or(jnp.logical_not(k_on), scaled >= kth)
 
     # top-p over the top-k survivors' distribution (reference: softmax of
     # the SORTED top-k row, so Z counts exactly k_eff entries — ties at
     # kth beyond k_eff are kept by the filter but excluded from Z)
-    m_s = jnp.max(scaled)
+    m_s = row_max(scaled)
     e = jnp.exp(scaled - m_s)
-    cnt_gt = jnp.sum(jnp.where(scaled > kth, 1, 0))
-    z_k = (jnp.sum(jnp.where(scaled > kth, e, 0.0))
+    cnt_gt = row_sum(jnp.where(scaled > kth, 1, 0))
+    z_k = (row_sum(jnp.where(scaled > kth, e, 0.0))
            + (k_eff - cnt_gt).astype(jnp.float32) * jnp.exp(kth - m_s))
-    z = jnp.where(k_on, z_k, jnp.sum(e))
+    z = jnp.where(k_on, z_k, row_sum(e))
     p_on = tp < 1.0
     pz = jnp.where(p_on, tp, jnp.float32(1.0)) * z
     # minimal key u0 with S(u0) < p*Z, via maximal key with S >= p*Z
-    u1 = jnp.uint32(0)
+    u1 = jnp.zeros_like(tk, dtype=jnp.uint32)
     for bit in range(31, -1, -1):
         cand = u1 | jnp.uint32(1 << bit)
-        s_above = jnp.sum(jnp.where(key > cand, e, 0.0))
+        s_above = row_sum(jnp.where(key > cand, e, 0.0))
         u1 = jnp.where(s_above >= pz, cand, u1)
-    s_at_u1 = jnp.sum(jnp.where(key > u1, e, 0.0))
+    s_at_u1 = row_sum(jnp.where(key > u1, e, 0.0))
     u0 = jnp.where(s_at_u1 >= pz, u1 + jnp.uint32(1), u1)
     keep_p = jnp.logical_or(jnp.logical_not(p_on), key >= u0)
 
     final = jnp.where(jnp.logical_and(keep_k, keep_p), scaled, neg_inf)
     # categorical == argmax(final + gumbel) with the caller's per-row noise
     g = final + noise
-    m_g = jnp.max(g)
-    sampled = jnp.min(jnp.where(g == m_g, iota, vocab))
-    out_ref[0, 0] = jnp.where(t > 0, sampled, greedy)
+    sampled = first_index_of(g, row_max(g))
+    out_ref[...] = jnp.where(t > 0, sampled, greedy)
 
 
 def fused_sample(
@@ -475,7 +501,7 @@ def fused_sample(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused sampling epilogue: temperature / top-k / top-p filter +
-    categorical draw in one kernel, one grid step per row.
+    categorical draw in one kernel, eight rows per grid step.
 
     ``logits`` (S, V) f32 raw logits, ``noise`` (S, V) f32 per-row Gumbel
     noise — generate it as ``vmap(lambda k: jax.random.gumbel(k, (V,),
@@ -485,29 +511,43 @@ def fused_sample(
     lowering). ``temperature``/``top_k``/``top_p`` are the (S,) per-row
     knobs with `engine._sample_rows` semantics: temperature <= 0 is greedy
     argmax over the RAW logits. Returns (S,) int32 token ids.
+
+    A row of a 2-D array is not a tile the TPU lowering accepts, so each
+    program takes ``_SAMPLE_ROWS`` whole rows (the vocabulary axis stays
+    whole, whatever its size) and ``S`` is padded up to a multiple of that
+    with greedy rows that are dropped again.
     """
     s, v = logits.shape
     interpret = _resolve_interpret(interpret)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, v), lambda i, t, k, p: (i, 0)),
-            pl.BlockSpec((1, v), lambda i, t, k, p: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, t, k, p: (i, 0)),
-        scratch_shapes=[],
-    )
+    r = _SAMPLE_ROWS
+    pad = -s % r
+
+    def rows(a, dtype):
+        a = a.astype(dtype)
+        return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) if pad else a
+
+    def knob(a, dtype):
+        return rows(a, dtype)[:, None]
+
+    row_spec = pl.BlockSpec((r, v), lambda i: (i, 0))
+    knob_spec = pl.BlockSpec((r, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_sample_kernel, vocab=v),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.int32),
+        grid=((s + pad) // r,),
+        in_specs=[knob_spec, knob_spec, knob_spec, row_spec, row_spec],
+        out_specs=knob_spec,
+        out_shape=jax.ShapeDtypeStruct((s + pad, 1), jnp.int32),
+        # two double-buffered inputs and the filter's live rows: a 152k
+        # vocabulary needs 27.7 MB of the chip's 128, past the 16 MB default
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, 8 * r * v * 4)
+        ),
         interpret=interpret,
     )(
-        temperature.astype(jnp.float32),
-        top_k.astype(jnp.int32),
-        top_p.astype(jnp.float32),
-        logits.astype(jnp.float32),
-        noise.astype(jnp.float32),
+        knob(temperature, jnp.float32),
+        knob(top_k, jnp.int32),
+        knob(top_p, jnp.float32),
+        rows(logits, jnp.float32),
+        rows(noise, jnp.float32),
     )
-    return out[:, 0]
+    return out[:s, 0]

@@ -423,7 +423,8 @@ def constrain_activation(x, kind: str = "residual", mesh: Optional[Mesh] = None)
     cp/sp), so the partitioner turns each row-parallel matmul's output
     all-reduce into reduce-scatter + the next block's all-gather (half the
     TP bytes) and — the big one — saved-for-backward residuals shrink by
-    the tp degree (the 70B tp8 HBM blowup in runs/hlo_report_index.md).
+    the tp degree (without it a 70B model under tp=8 keeps every block's
+    full-sequence residual on every chip and does not fit).
     Norms/elementwise between blocks run seq-sharded for free.
     """
     if mesh is None:

@@ -66,13 +66,7 @@ def _maybe_init_jax_distributed() -> None:
     # (this function's whole reason to exist) would always crash. Found by
     # the 4-process supervisor test; the debug_launcher path masked it by
     # initializing distributed itself before PartialState.
-    try:
-        initialized = jax.distributed.is_initialized()
-    except AttributeError:  # older jax: peek the client directly
-        from jax._src import distributed as _dist
-
-        initialized = _dist.global_state.client is not None
-    if initialized:
+    if jax.distributed.is_initialized():
         return
     coord = os.environ.get("ACCELERATE_COORDINATOR_ADDRESS")
     nproc = os.environ.get("ACCELERATE_NUM_PROCESSES")

@@ -5,8 +5,9 @@ The training loop's safety/observability hooks (``check_step_health``,
 ``Accelerator.log``) used to be host sync points: every call flushed the
 async dispatch pipeline with a ``device_get`` — and with ``check_grads``
 one blocking transfer *per gradient leaf*. That undoes the dispatch-
-overhead wins the fused ``train_step`` exists for (runs/overhead_ab.md:
-~22 µs/step amortized dispatch vs ~ms-scale forced readbacks). Keeping
+overhead wins the fused ``train_step`` exists for (a dispatch that the
+host issues ahead of the device costs microseconds, a forced readback
+waits for the whole step). Keeping
 the host ahead of the device is the whole game; this module makes every
 per-step host interaction cost ~zero steady-state step time:
 

@@ -12,33 +12,37 @@ import os
 from contextlib import contextmanager
 from typing import Any
 
+# the checkout: what the program builds or caches at run time lives under it
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 _TRUE = {"1", "true", "yes", "on", "y", "t"}
 _FALSE = {"0", "false", "no", "off", "n", "f", ""}
 
 
 def default_compile_cache_dir() -> str:
-    """Per-user default for the persistent JAX compile cache.
+    """Where the persistent JAX compile cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``.jax_cache`` at the root of
+    this checkout.
 
-    A world-shared path like ``/tmp/accelerate_tpu_jax_cache`` is a
-    poisoned-cache risk on multi-user hosts: cache entries are deserialized
-    compiled executables, so anyone who can write the directory can plant
-    code that the next user's process runs. ``JAX_COMPILATION_CACHE_DIR``
-    still wins when set; otherwise XDG/`~/.cache`, with a uid-salted tmpdir
-    as the last resort (e.g. HOME unset in a stripped container)."""
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
-    base = os.environ.get("XDG_CACHE_HOME", "")
-    if not base:
-        home = os.path.expanduser("~")
-        if home and home != "~":
-            base = os.path.join(home, ".cache")
-    if not base:
-        import tempfile
+    The path is part of every cache key, so it has to be the same in every
+    process and every run: no home directory, no temp name, no uid, pid or
+    time. A machine that is built fresh for each call keeps nothing outside
+    the checkout anyway, and a caller that wants the cache elsewhere (a disk
+    that survives, a directory other users cannot write: entries are
+    deserialized into compiled executables) names it in the variable."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        REPO_ROOT, ".jax_cache"
+    )
 
-        uid = os.getuid() if hasattr(os, "getuid") else "user"
-        base = os.path.join(tempfile.gettempdir(), f"accelerate_tpu-{uid}")
-    return os.path.join(base, "accelerate_tpu", "jax")
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compile cache on at :func:`default_compile_cache_dir`
+    — the one place in the repository that sets the path. Returns it."""
+    import jax
+
+    path = default_compile_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def str_to_bool(value: str) -> int:

@@ -1,19 +1,23 @@
 """ctypes bridge to the native (C++) host-side kernels in csrc/.
 
-Builds ``libaccel_packing.so`` on demand with g++ -O3 (cached under
-``~/.cache/accelerate_tpu``); every entry point has a NumPy fallback so the
-framework works on toolchain-less machines.
+Builds ``libaccel_packing-<hash of csrc/packing.cpp>.so`` on demand with
+g++ -O3 under ``.native_cache`` at the root of the checkout
+(``ACCELERATE_TPU_CACHE`` names another directory); every entry point has a
+NumPy fallback so the framework works on toolchain-less machines.
 """
 
 from __future__ import annotations
 
 import ctypes
 import functools
+import hashlib
 import os
 import subprocess
 from typing import Optional
 
 import numpy as np
+
+from .environment import REPO_ROOT
 
 __all__ = [
     "get_packing_lib",
@@ -26,13 +30,12 @@ __all__ = [
 ]
 
 _CACHE_DIR = os.path.expanduser(
-    os.environ.get("ACCELERATE_TPU_CACHE", "~/.cache/accelerate_tpu")
+    os.environ.get("ACCELERATE_TPU_CACHE") or os.path.join(REPO_ROOT, ".native_cache")
 )
 
 
 def _source_path() -> str:
-    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    return os.path.join(here, "csrc", "packing.cpp")
+    return os.path.join(REPO_ROOT, "csrc", "packing.cpp")
 
 
 @functools.lru_cache(maxsize=1)
@@ -41,15 +44,23 @@ def get_packing_lib() -> Optional[ctypes.CDLL]:
     src = _source_path()
     if not os.path.exists(src):
         return None
-    os.makedirs(_CACHE_DIR, exist_ok=True)
-    out = os.path.join(_CACHE_DIR, "libaccel_packing.so")
+    # the library's name carries the hash of its source, so a build of any
+    # other packing.cpp can never be loaded in its place
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(_CACHE_DIR, f"libaccel_packing-{digest}.so")
     try:
-        if not os.path.exists(out) or os.path.getmtime(out) < os.path.getmtime(src):
+        if not os.path.exists(out):
+            os.makedirs(_CACHE_DIR, exist_ok=True)
+            # build beside the target and rename: a killed or concurrent
+            # build never leaves a half-written library under the final name
+            tmp = f"{out}.{os.getpid()}.tmp"
             subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", out],
+                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
                 check=True,
                 capture_output=True,
             )
+            os.replace(tmp, out)
         lib = ctypes.CDLL(out)
     except (OSError, subprocess.CalledProcessError):
         return None

@@ -71,5 +71,5 @@ def bench(label, flatten, steps=8, seq=2048, batch=8):
 
 if __name__ == "__main__":
     bench("pytree  path", False)
-    bench("flatbuf path", "auto")
-    bench("flatbuf path (repeat)", "auto")
+    bench("flatbuf path", True)
+    bench("flatbuf path (repeat)", True)

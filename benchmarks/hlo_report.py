@@ -71,9 +71,8 @@ SIZES = {
     "70b": (8192, 28672, 80, 64, 8, 32000),
     "7b": (4096, 11008, 32, 32, 32, 32000),
     "1b": (2048, 5632, 16, 32, 32, 32000),
-    # the round-1 measured config (bench.py @ c6493e4): the ONE hardware
-    # datum (v5e 1 chip, seq 2048, bs 8, remat "nothing" -> 11.1k tok/s,
-    # 10.3% MFU) — used to calibrate this predictor
+    # the config of round one's only chip run (v5e, seq 2048, bs 8, remat
+    # "nothing"). Its timed call held a recompile, so it calibrates nothing
     "0.3b": (1024, 2816, 16, 16, 16, 32000),
     "tiny": (256, 688, 4, 8, 8, 2048),
 }
@@ -313,8 +312,8 @@ def run_decode(args):
             predicted_prefill_s=t_prefill,
             assumptions=dict(matmul_eff=MATMUL_EFF, ici_eff=ICI_EFF,
                              hbm_eff=HBM_EFF),
-            calibration="ceiling; train-side calibration bounds apply "
-                        "(runs/hlo_report_index.md)",
+            calibration="ceiling from published peaks and assumed "
+                        "efficiencies; never calibrated against a chip run",
         ),
         memory=dict(hbm_live_estimate=hbm_live,
                     hbm_capacity=int(chip["hbm_bytes"]),

@@ -14,8 +14,8 @@ optimizer steps through each framework's idiomatic loop —
 At tiny model sizes compute is negligible, so steps/s measures the
 per-step host overhead each framework imposes — the quantity that caps
 small-model/step-frequency workloads. This is NOT a TPU compute claim
-(see runs/hlo_report_index.md for that); it isolates the framework-
-design term on hardware anyone can rerun.
+(no speed of this repository has been measured on a TPU yet: PERF.md);
+it isolates the framework-design term on hardware anyone can rerun.
 
 Prints one JSON line per framework plus a ratio line.
 """
@@ -144,8 +144,8 @@ def main():
         "metric": "per_step_overhead_ratio",
         "value": round(ours["steps_per_s"] / ref["steps_per_s"], 2),
         "unit": "x reference steps/s (same tiny MLP, same host, CPU)",
-        "note": "framework per-step overhead comparison; TPU compute claims "
-                "live in runs/hlo_report_index.md",
+        "note": "framework per-step overhead comparison on the CPU; says "
+                "nothing about TPU speed",
     }), flush=True)
 
 

@@ -1,8 +1,8 @@
 """Telemetry overhead A/B: fused health checks + async logging vs nothing.
 
 PR 1's watchdog and per-step ``log()`` were host sync points — every call
-flushed the async dispatch pipeline (`runs/overhead_ab.md` measured what
-that pipeline is worth: 206x at the pure-overhead limit). This bench pins
+flushed the async dispatch pipeline (`benchmarks/overhead_ab.py` measures
+what that pipeline is worth at the pure-overhead limit). This bench pins
 the claim that the non-blocking telemetry path costs ~nothing: the same
 tiny-MLP fused train_step loop is timed three ways on CPU —
 
@@ -33,8 +33,8 @@ import numpy as np
 # step time ~8 ms on CPU at these shapes — the ms-scale regime the telemetry
 # is built for (TPU steps). At pure-overhead scale (HIDDEN=256: ~0.6 ms) any
 # extra per-step XLA dispatch is a visible fraction and the gate measures
-# dispatch jitter, not telemetry design; see runs/overhead_ab.md for the
-# pure-overhead numbers.
+# dispatch jitter, not telemetry design; benchmarks/overhead_ab.py measures
+# the pure-overhead regime.
 HIDDEN = int(os.environ.get("TB_HIDDEN", "768"))
 BATCH = int(os.environ.get("TB_BATCH", "128"))
 STEPS = int(os.environ.get("TB_STEPS", "200"))

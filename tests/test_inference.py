@@ -216,7 +216,8 @@ def test_generate_top_k_zero_means_unfiltered_and_positional_compat():
 def test_generate_caches_compiled_program():
     """generate() must reuse ONE compiled program across calls — including
     calls varying temperature/top_p/seed (traced operands, not cache keys).
-    The regression was a full re-trace+recompile per call (runs/overhead_ab.md)."""
+    The regression was a full re-trace+recompile per call, which a timing of
+    the first call after warm-up then counted as generation time."""
     import jax.numpy as jnp
 
     from accelerate_tpu.inference import generate

@@ -99,9 +99,7 @@ def _alias_record(in_dtype, out_dtype, donated=frozenset()):
 
 # ---------------------------------------------------------------- G401
 def test_g401_flags_f64():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         rec = _record(lambda x: x * 2.0, np.zeros(4, np.float64))
     found = check_f64(rec)
     assert _codes(found) == ["G401"] and "f64" in found[0].message

@@ -1,0 +1,174 @@
+"""The kernels of the main paths, compiled by the TPU's own compiler at real widths.
+
+Interpret mode cannot see what the chip's compiler refuses: a block shape whose
+last two dimensions are neither tile-aligned nor whole, a kernel that needs more
+fast memory than it may take. These tests compile each kernel for a v5e that is
+described and not attached (``on-chip-measurement`` guide, section 2), about two
+seconds each. Nothing runs, so they say nothing about results or times:
+``chip_smoke.py`` does that on the chip.
+
+Everything that touches the TPU's library is built inside the module-scoped
+fixtures below: only the pytest worker that is given this file loads it, and it
+keeps it, so the tests compile in their own process and live in this one file.
+"""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from accelerate_tpu.ops.flash_attention import flash_attention
+from accelerate_tpu.ops.paged_decode import (
+    fused_sample,
+    paged_flash_decode,
+    paged_flash_verify,
+)
+
+# (query heads, kv heads, head_dim, vocabulary) as published
+WIDTHS = {
+    "gpt2_large": (20, 20, 64, 50257),
+    "mistral_7b": (32, 8, 128, 32000),
+    "qwen2_7b": (28, 4, 128, 152064),
+}
+SLOTS, BLOCK_SIZE, BLOCKS_PER_ROW, POOL_BLOCKS, WINDOW = 8, 16, 128, 512, 5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing a chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: the next one would warn and compile
+    again. Off around these tests, and back as it was after them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip, no_persistent_cache):
+    """``compile_for_chip(fn, (shape, dtype), ...)`` compiles ``fn`` for the
+    described chip and returns the optimized program's text."""
+
+    def compile_(fn, *operands):
+        shapes = [
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in operands
+        ]
+        text = jax.jit(fn).lower(*shapes).compile().as_text()
+        assert "tpu_custom_call" in text, "no Pallas kernel in the compiled program"
+        return text
+
+    return compile_
+
+
+def _flash_loss(q, k, v, **kwargs):
+    return jnp.sum(flash_attention(q, k, v, interpret=False, **kwargs).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("seq_len", [2048, 4096])
+def test_flash_forward_and_backward_compile_at_mistral_heads(compile_for_chip, seq_len):
+    heads, kv_heads, head_dim, _ = WIDTHS["mistral_7b"]
+    loss = functools.partial(
+        _flash_loss, causal=True, window=4096, block_q=2048, block_k=512
+    )
+    q = ((1, seq_len, heads, head_dim), jnp.bfloat16)
+    kv = ((1, seq_len, kv_heads, head_dim), jnp.bfloat16)
+    compile_for_chip(loss, q, kv, kv)
+    # the two backward kernels (dq; dk and dv) beside the forward's recompute
+    text = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_flash_packed_segments_compile_at_gpt2_head_dim(compile_for_chip):
+    heads, kv_heads, head_dim, _ = WIDTHS["gpt2_large"]
+
+    def loss(q, k, v, segments):
+        return _flash_loss(q, k, v, causal=True, segment_ids=segments)
+
+    qkv = ((2, 1024, heads, head_dim), jnp.bfloat16)
+    compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv, ((2, 1024), jnp.int32)
+    )
+
+
+def _pool_operands(width, quantized):
+    heads, kv_heads, head_dim, _ = WIDTHS[width]
+    pool = (
+        (POOL_BLOCKS, BLOCK_SIZE, kv_heads, head_dim),
+        jnp.int8 if quantized else jnp.bfloat16,
+    )
+    tables = ((SLOTS, BLOCKS_PER_ROW), jnp.int32)
+    pos = ((SLOTS,), jnp.int32)
+    scales = [((POOL_BLOCKS, BLOCK_SIZE), jnp.float32)] * 2 if quantized else []
+    return heads, kv_heads, head_dim, pool, tables, pos, scales
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16_pool", "int8_pool"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_paged_flash_decode_compiles(compile_for_chip, width, quantized):
+    heads, _, head_dim, pool, tables, pos, scales = _pool_operands(width, quantized)
+
+    def decode(q, k, v, tables, pos, *scales):
+        kwargs = dict(zip(("k_scale", "v_scale"), scales))
+        return paged_flash_decode(q, k, v, tables, pos, interpret=False, **kwargs)
+
+    compile_for_chip(
+        decode, ((SLOTS, 1, heads, head_dim), jnp.bfloat16), pool, pool, tables, pos, *scales
+    )
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16_pool", "int8_pool"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_paged_flash_verify_compiles(compile_for_chip, width, quantized):
+    heads, kv_heads, head_dim, pool, tables, pos, scales = _pool_operands(width, quantized)
+
+    def verify(q, k, v, win_k, win_v, tables, pos, *scales):
+        kwargs = dict(zip(("k_scale", "v_scale"), scales))
+        return paged_flash_verify(
+            q, k, v, win_k, win_v, tables, pos, interpret=False, **kwargs
+        )
+
+    window = ((SLOTS, WINDOW, kv_heads, head_dim), jnp.bfloat16)
+    compile_for_chip(
+        verify, ((SLOTS, WINDOW, heads, head_dim), jnp.bfloat16), pool, pool,
+        window, window, tables, pos, *scales,
+    )
+
+
+# five rows: padded up to one eight-row tile. Qwen2's 152k vocabulary: more
+# fast memory than the 16 MB a kernel gets unasked (and 15 s of compiling)
+@pytest.mark.parametrize(
+    "width,rows",
+    [("gpt2_large", 8), ("gpt2_large", 5), ("mistral_7b", 8), ("qwen2_7b", 8)],
+)
+def test_fused_sample_compiles(compile_for_chip, width, rows):
+    vocab = WIDTHS[width][3]
+    logits = ((rows, vocab), jnp.float32)
+    knob = lambda dtype: ((rows,), dtype)  # noqa: E731
+    compile_for_chip(
+        functools.partial(fused_sample, interpret=False),
+        logits, logits, knob(jnp.float32), knob(jnp.int32), knob(jnp.float32),
+    )
