@@ -1,0 +1,149 @@
+"""The one generator of traffic. A cell's file gives parameters (lengths,
+sharing of greedy and sampled requests, clients); this turns them and ``--seed``
+into rows to train on or requests to serve.
+
+Every seed gets the same set of sizes in another order: lengths are the
+stratified quantiles of the stated distribution, and the seed permutes them and
+draws the token ids. The sizes come round again after ``pool`` requests, so that
+a window, which takes as many requests as the system is fast, holds whole rounds
+of the same work whatever the seed; the token ids are fresh for every request (a
+prompt sent twice would be served from the prefix cache).
+
+A closed loop that starts with every client on a fresh request is not in its
+steady state: nothing finishes for a while, then much at once. So the requests
+that stand in the slots when the window opens are drawn as the steady state has
+them, longer requests the likelier, each somewhere along its life
+(``standing_requests``); the requests of the mix follow them.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import statistics
+
+import numpy as np
+
+
+@dataclasses.dataclass
+class Request:
+    index: int
+    prompt: np.ndarray  # (prompt_len,) int32
+    max_new_tokens: int
+    temperature: float
+    top_k: int | None
+    top_p: float | None
+    seed: int
+
+    @property
+    def greedy(self) -> bool:
+        return self.temperature == 0.0
+
+
+def training_rows(params: dict, vocab_size: int, seed: int) -> np.ndarray:
+    """``(sequences, seq_len)`` int32 token ids, uniform over the vocabulary:
+    rows that all differ."""
+    rng = np.random.default_rng([int(seed), 11])
+    return rng.integers(
+        0, vocab_size, size=(params["sequences"], params["seq_len"]), dtype=np.int32
+    )
+
+
+def stratified_lengths(dist: dict, count: int) -> np.ndarray:
+    """``count`` lengths at the mid-quantiles of a log-normal (``median``,
+    ``sigma``) cut to ``[min, max]``, ascending."""
+    if dist.get("dist", "lognormal") != "lognormal":
+        raise ValueError(f"unknown length distribution {dist!r}")
+    normal = statistics.NormalDist()
+    values = [
+        math.exp(math.log(dist["median"]) + dist["sigma"] * normal.inv_cdf((i + 0.5) / count))
+        for i in range(count)
+    ]
+    return np.clip(np.rint(values), dist["min"], dist["max"]).astype(np.int64)
+
+
+def request_pool(params: dict, vocab_size: int, seed: int) -> list:
+    """The ``requests`` requests of a run, in the order in which clients take
+    them; their sizes and kinds repeat with the period ``pool``."""
+    period = params["pool"]
+    rng = np.random.default_rng([int(seed), 23])
+    prompt_lens = rng.permutation(stratified_lengths(params["prompt_len"], period))
+    output_lens = rng.permutation(stratified_lengths(params["output_len"], period))
+    n_greedy = int(round(params["greedy_share"] * period))
+    greedy = rng.permutation(np.arange(period) < n_greedy)
+    sampling = params["sampling"]
+    requests = []
+    for index in range(params["requests"]):
+        i = index % period
+        prompt = rng.integers(0, vocab_size, size=(int(prompt_lens[i]),), dtype=np.int32)
+        if greedy[i]:
+            knobs = dict(temperature=0.0, top_k=None, top_p=None)
+        else:
+            knobs = dict(temperature=sampling["temperature"], top_k=sampling["top_k"],
+                         top_p=sampling["top_p"])
+        requests.append(Request(
+            index=index, prompt=prompt, max_new_tokens=int(output_lens[i]),
+            seed=int(rng.integers(0, 2**31 - 1)), **knobs,
+        ))
+    return requests
+
+
+def warmup_requests(params: dict, vocab_size: int, seed: int) -> list:
+    """One request a client for set-up: prompts of the mix's lengths, outputs of
+    staggered lengths, so that the clients leave set-up out of step."""
+    clients = params["clients"]
+    rng = np.random.default_rng([int(seed), 37])
+    prompt_lens = rng.permutation(stratified_lengths(params["prompt_len"], clients))
+    top = params["warmup_output_max"]
+    requests = []
+    for i in range(clients):
+        prompt = rng.integers(0, vocab_size, size=(int(prompt_lens[i]),), dtype=np.int32)
+        sampled = i % 2 == 1
+        sampling = params["sampling"]
+        requests.append(Request(
+            index=-1 - i, prompt=prompt,
+            max_new_tokens=max(2, int(round(top * (i + 1) / clients))),
+            temperature=sampling["temperature"] if sampled else 0.0,
+            top_k=sampling["top_k"] if sampled else None,
+            top_p=sampling["top_p"] if sampled else None,
+            seed=int(rng.integers(0, 2**31 - 1)),
+        ))
+    return requests
+
+
+def standing_requests(params: dict, vocab_size: int, seed: int) -> list:
+    """One request a client, to stand in the slots when the window opens, as a
+    closed loop in its steady state has them: a request is in flight the likelier
+    the longer it is, and is met at a point of its life that is uniform. So the
+    outputs are what is left of such requests: the stratified quantiles, over the
+    clients, of the mix's output tokens laid end to end; what such a request has
+    made already rides in its prompt (as far as the longest prompt allows), so
+    that the cache is as full as the steady state has it. The same set for
+    every seed, dealt to the clients in an order the seed draws."""
+    clients, period = params["clients"], params["pool"]
+    rng = np.random.default_rng([int(seed), 31])
+    outputs = stratified_lengths(params["output_len"], period)
+    ends = np.cumsum(outputs)
+    left, made = [], []
+    for i in range(clients):
+        at = (i + 0.5) / clients * ends[-1]  # a token of the mix, by its place in the row
+        which = int(np.searchsorted(ends, at, side="right"))
+        left.append(max(1, int(np.ceil(ends[which] - at))))  # what is left of its request
+        made.append(int(outputs[which]) - left[-1])
+    order = rng.permutation(clients)
+    left, made = np.asarray(left)[order], np.asarray(made)[order]
+    prompt_lens = rng.permutation(stratified_lengths(params["prompt_len"], clients))
+    prompt_lens = np.minimum(prompt_lens + made, params["prompt_len"]["max"])
+    greedy = rng.permutation(np.arange(clients) < int(round(params["greedy_share"] * clients)))
+    sampling = params["sampling"]
+    requests = []
+    for i in range(clients):
+        prompt = rng.integers(0, vocab_size, size=(int(prompt_lens[i]),), dtype=np.int32)
+        requests.append(Request(
+            index=-1 - clients - i, prompt=prompt, max_new_tokens=int(left[i]),
+            temperature=0.0 if greedy[i] else sampling["temperature"],
+            top_k=None if greedy[i] else sampling["top_k"],
+            top_p=None if greedy[i] else sampling["top_p"],
+            seed=int(rng.integers(0, 2**31 - 1)),
+        ))
+    return requests
