@@ -1212,9 +1212,12 @@ class Accelerator:
                 scale = scaler_state["scale"] if use_scaler else jnp.float32(1.0)
                 return loss * scale / k, (loss, aux)
 
+            # the step's phases as named scopes: each operation's op_name in
+            # a profile starts with its phase (XProf groups by it)
             if pp_1f1b_cfg is not None:
                 scale = scaler_state["scale"] if use_scaler else jnp.float32(1.0)
-                loss, grads = _pipeline_grads(params, scale, batch)
+                with jax.named_scope("train.forward_backward"):
+                    loss, grads = _pipeline_grads(params, scale, batch)
                 _aux = None
             elif psgd_rank is not None:
                 from .ops.powersgd import make_powersgd_grad_fn
@@ -1245,9 +1248,10 @@ class Accelerator:
                 psgd_fn = make_powersgd_grad_fn(
                     self.mesh, local_grad, params, psgd_rank
                 )
-                loss, _aux, grads, psgd_state = psgd_fn(
-                    params, psgd_state, *batch
-                )
+                with jax.named_scope("train.forward_backward"):
+                    loss, _aux, grads, psgd_state = psgd_fn(
+                        params, psgd_state, *batch
+                    )
                 if use_scaler:
                     # re-apply the scale so the shared accumulate/
                     # finite-check/unscale path downstream is unchanged
@@ -1255,7 +1259,8 @@ class Accelerator:
                         lambda g: g * scaler_state["scale"], grads
                     )
             else:
-                (_, (loss, _aux)), grads = jax.value_and_grad(wrapped, has_aux=True)(params)
+                with jax.named_scope("train.forward_backward"):
+                    (_, (loss, _aux)), grads = jax.value_and_grad(wrapped, has_aux=True)(params)
             if grad_comm_dtype is not None:
                 # comm-hook compression: gradients reduce/accumulate in the
                 # compressed dtype (same semantic as the eager path)
@@ -1263,7 +1268,8 @@ class Accelerator:
                     lambda g: g.astype(grad_comm_dtype), grads
                 )
             grads = _pin_grads(grads)
-            accum = jax.tree_util.tree_map(jnp.add, accum, grads) if k > 1 else grads
+            with jax.named_scope("train.accumulate"):
+                accum = jax.tree_util.tree_map(jnp.add, accum, grads) if k > 1 else grads
             new_count = count + 1
             do_update = (new_count % k) == 0 if k > 1 else jnp.bool_(True)
 
@@ -1278,15 +1284,17 @@ class Accelerator:
                     inv = 1.0 / scaler_state["scale"]
                     g = jax.tree_util.tree_map(lambda x: x * inv, g)
                 if max_grad_norm is not None:
-                    norm = optax.global_norm(g)
-                    factor = jnp.minimum(1.0, max_grad_norm / (norm + 1e-6))
-                    g = jax.tree_util.tree_map(lambda x: x * factor, g)
+                    with jax.named_scope("train.clip"):
+                        norm = optax.global_norm(g)
+                        factor = jnp.minimum(1.0, max_grad_norm / (norm + 1e-6))
+                        g = jax.tree_util.tree_map(lambda x: x * factor, g)
                 if use_scaler:
                     finite = jnp.bool_(True)
                     for leaf in jax.tree_util.tree_leaves(g):
                         finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(leaf)))
-                    updates, maybe_os = tx.update(g, opt_state, params)
-                    new_params = optax.apply_updates(params, updates)
+                    with jax.named_scope("train.optimizer"):
+                        updates, maybe_os = tx.update(g, opt_state, params)
+                        new_params = optax.apply_updates(params, updates)
                     new_params = jax.tree_util.tree_map(
                         lambda new, old: jnp.where(finite, new, old), new_params, params
                     )
@@ -1308,9 +1316,11 @@ class Accelerator:
                     scaler_state = {"scale": new_scale, "good_steps": new_good}
                     params, opt_state = new_params, new_os
                 else:
-                    updates, opt_state = tx.update(g, opt_state, params)
-                    params = optax.apply_updates(params, updates)
-                accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
+                    with jax.named_scope("train.optimizer"):
+                        updates, opt_state = tx.update(g, opt_state, params)
+                        params = optax.apply_updates(params, updates)
+                with jax.named_scope("train.accumulate"):
+                    accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
                 return params, opt_state, accum, scaler_state
 
             if k > 1:
@@ -1493,9 +1503,9 @@ class Accelerator:
             else:
                 in_params, in_opt = model.params, optimizer.opt_state
             # host-side dispatch span only (the fused program runs async on
-            # device); sampled so steady-state cost stays one modulo
-            with tracing.step_span(
-                "train.step_dispatch", optimizer._step_count, flat=use_flat
+            # device), one every step: what the host spends to send a step
+            with tracing.span(
+                "train.step", step=optimizer._step_count, flat=use_flat
             ):
                 params, opt_state, accum, count, scaler_state, psgd_state, loss = compiled(
                     in_params,

@@ -35,7 +35,7 @@ Level 2 — host lint (``analysis/host.py``):
 * **G105** fault-injection point referenced by tests/docs but absent from
   the code's ``fault_point`` registry
 * **G107** tracing discipline: host clock / tracer call inside a jitted
-  function, or ``tracing.span``/``step_span`` used outside a ``with``
+  function, or ``tracing.span`` used outside a ``with``
 * **G108** metric-name discipline: ``bump``/``gauge``/``observe`` call
   site whose metric name is not a ``[a-z0-9_/]+`` literal (or
   literal-fragment f-string) — computed names fork ad-hoc namespaces

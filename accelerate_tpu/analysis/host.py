@@ -17,8 +17,8 @@ invariant a past PR or review cycle established:
   code, or the test silently stops testing anything (PR 1 harness).
 * G107 — tracing discipline (PR 11 flight recorder): no host clocks or
   tracer calls inside jitted functions (they run once at trace time and
-  bake a constant — or worse, retrace), and ``tracing.span``/``step_span``
-  only as ``with`` context managers (a span that is never ``__exit__``-ed
+  bake a constant — or worse, retrace), and ``tracing.span`` only as
+  ``with`` context managers (a span that is never ``__exit__``-ed
   never lands in the ring, so it silently records nothing).
 * G108 — metric-name discipline (PR 15 observatory): every
   ``bump``/``gauge``/``observe`` call site names its metric with a
@@ -453,7 +453,7 @@ def _lint_lock_held(tree, relpath, waivers, findings) -> None:
 # Host clocks: called at trace time they bake a constant into the program
 # (and a tracer ring append inside traced code is pure overhead/retrace bait).
 _CLOCK_FUNCS = {"time", "monotonic", "perf_counter", "perf_counter_ns", "monotonic_ns"}
-_SPAN_FUNCS = {"span", "step_span"}
+_SPAN_FUNCS = {"span"}
 _TRACER_FUNCS = _SPAN_FUNCS | {"flight_dump", "new_trace_id", "get_tracer"}
 
 
@@ -520,7 +520,7 @@ def _lint_jitted_tracing(tree, relpath, waivers, findings) -> None:
 
 
 def _lint_span_discipline(tree, relpath, waivers, findings) -> None:
-    """G107 (usage half): ``span(...)``/``step_span(...)`` must be the
+    """G107 (usage half): ``span(...)`` must be the
     context expression of a ``with`` — any other use (assignment, bare
     expression, argument) skips ``__exit__`` and records nothing."""
     with_ctx_ids: Set[int] = set()

@@ -435,10 +435,10 @@ class _DevicePrefetcher:
 
     def __next__(self):
         # the blocking get IS the data wait: span duration shows how long
-        # the step loop stalled on input (sampled; see TracingConfig)
+        # the step loop stalled on input (one span every fetch)
         step = self._fetches
         self._fetches += 1
-        with tracing.step_span("train.data_wait", step):
+        with tracing.span("train.data_wait", step=step):
             item = self.q.get()
         if item is self._SENTINEL:
             if self.error is not None:

@@ -230,6 +230,7 @@ def _filter_logits(logits, temp, top_k, top_p):
     return jnp.where(filtered < cutoff, -jnp.inf, filtered)
 
 
+@jax.named_scope("engine.sample")
 def _sample_rows(logits, subkeys, temp, top_k, top_p):
     """Per-row sampling over (N, V) logits: per-row temperature (0 = greedy
     argmax), per-row top-k (0 or >= V = off) and top-p (>= 1 = off) via ONE
@@ -535,12 +536,13 @@ class ContinuousBatchingEngine:
             from .ops.paged_decode import fused_sample
 
             v = logits.shape[-1]
-            noise = jax.vmap(
-                lambda kk: jax.random.gumbel(kk, (v,), jnp.float32)
-            )(subs)
-            nxt = fused_sample(
-                logits, noise, carried["temp"], carried["top_k"], carried["top_p"]
-            )
+            with jax.named_scope("engine.sample"):
+                noise = jax.vmap(
+                    lambda kk: jax.random.gumbel(kk, (v,), jnp.float32)
+                )(subs)
+                nxt = fused_sample(
+                    logits, noise, carried["temp"], carried["top_k"], carried["top_p"]
+                )
         else:
             nxt = _sample_rows(logits, subs, carried["temp"], carried["top_k"], carried["top_p"])
         emitting = ~done
@@ -1051,7 +1053,7 @@ class ContinuousBatchingEngine:
         # (G107) — this times the interleaved prefill on the decode thread
         with tracing.span(
             "engine.prefill", trace_id=trace_id,
-            slot=slot, prompt_len=len(prompt),
+            slot=slot, prompt_len=len(prompt), bucket=self.prompt_bucket,
         ):
             self._donated, self._carried, t0, d0 = self._prefill_jit(
                 self._donated, self._carried, self.model.params,
@@ -1157,6 +1159,8 @@ class ContinuousBatchingEngine:
         with tracing.span(
             "engine.prefill_chunk", trace_id=occ.trace_id,
             slot=occ.slot, offset=offset, chunk_len=chunk_len,
+            # the window forward runs every slot's row, one of them real
+            bucket=self.slots * chunk,
         ):
             self._donated, self._carried, t0, d0 = self._chunk_jit(
                 self._donated, self._carried, self.model.params,
@@ -1456,13 +1460,17 @@ class ContinuousBatchingEngine:
 
     def _dispatch_decode(self) -> bool:
         self._record("decode_step", ())
-        # per-decode-step aggregates, SAMPLED every decode_sample_every
-        # steps (tracing this hot loop unsampled would be the overhead the
-        # bench gate forbids); the span times the host dispatch only — the
-        # jitted body itself never sees the tracer (G107)
-        with tracing.step_span(
-            "engine.decode_step", self.steps,
+        # one span EVERY step (a few Python adds over at most `slots`
+        # occupants; nothing is fetched from the device for it). It times
+        # the host dispatch only — the jitted body itself never sees the
+        # tracer (G107) — and carries what the step was dispatched with:
+        # rows doing useful work, and live against reserved KV positions
+        with tracing.span(
+            "engine.decode_step",
             live=self.live_count(), tick=self._tick,
+            decoding=self._decoding_count(), slots=self.slots,
+            kv_live_tokens=self.live_tokens(),
+            kv_reserved_tokens=self._backend.reserved_tokens(),
         ):
             self._donated, self._carried = self._decode_jit(
                 self._donated, self._carried, self.model.params,
@@ -1611,7 +1619,9 @@ class ContinuousBatchingEngine:
             for occ in gated:
                 occ.spec_skips += 1
             return self._dispatch_decode()
-        self._materialize_ring()
+        # spec mode's own wait for the device, ahead of poll's
+        with tracing.span("engine.readback", kind="materialize", popped=0):
+            self._materialize_ring()
         k = self.spec_draft_len
         draft = np.zeros((self.slots, k), np.int32)
         dlen = np.zeros((self.slots,), np.int32)
@@ -1665,8 +1675,8 @@ class ContinuousBatchingEngine:
         # does the host->device transfer cheaper than an explicit
         # device_put, and this sits on the serial critical path (each spec
         # step blocks on the previous verify before it can draft)
-        with tracing.step_span(
-            "engine.spec_verify", self.steps,
+        with tracing.span(
+            "engine.spec_verify",
             drafted=total, live=self.live_count(),
         ):
             (self._donated, self._carried, emitted, m, a) = self._verify_jit(
@@ -1699,14 +1709,20 @@ class ContinuousBatchingEngine:
             popped[kind] += 1
             if kind == "chunk":
                 continue  # no tokens — the last chunk's entry carries t0
+            # the np.asarray reads below are the one place this thread waits
+            # for the device: engine.readback is that wait, so a tick's span
+            # less the readbacks inside it is the host's own work
             if kind == "prefill":
                 occ, tok, done = payload
-                # graft: sync-ok — the ring IS the readback point (K programs late)
-                self._absorb(occ, int(np.asarray(tok)), bool(np.asarray(done)), retired)
+                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())):
+                    # graft: sync-ok — the ring IS the readback point (K programs late)
+                    tok, done = int(np.asarray(tok)), bool(np.asarray(done))
+                self._absorb(occ, tok, done, retired)
             elif kind == "decode":
                 occs, toks, dones = payload
-                # graft: sync-ok — the ring IS the readback point (K programs late)
-                toks, dones = np.asarray(toks), np.asarray(dones)
+                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())):
+                    # graft: sync-ok — the ring IS the readback point (K programs late)
+                    toks, dones = np.asarray(toks), np.asarray(dones)
                 for occ in occs:
                     if occ is None or occ.finished:
                         continue
@@ -1715,8 +1731,9 @@ class ContinuousBatchingEngine:
             else:  # verify: up to W tokens per slot, done applies to the last
                 occs, emitted, ms, accs, dlens, dones = payload
                 # the ring IS the readback point (K programs late)
-                emitted, ms = np.asarray(emitted), np.asarray(ms)  # graft: sync-ok
-                accs, dones = np.asarray(accs), np.asarray(dones)  # graft: sync-ok
+                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())):
+                    emitted, ms = np.asarray(emitted), np.asarray(ms)  # graft: sync-ok
+                    accs, dones = np.asarray(accs), np.asarray(dones)  # graft: sync-ok
                 for occ in occs:
                     if occ is None or occ.finished:
                         continue

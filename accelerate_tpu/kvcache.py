@@ -141,6 +141,7 @@ class PagedKVLayout:
         # view is ever materialized (ops/paged_decode.py)
         self.attention_impl = attention_impl
 
+    @jax.named_scope("kv.gather")
     def view(self, layer_cache):
         """Gather one layer's pool slice into the dense per-slot view:
         ``(num_blocks, bs, kvh, hd)`` (or the int8 ``{"q","s"}`` pair) →
@@ -156,6 +157,7 @@ class PagedKVLayout:
         b, bpr, bs, kvh, hd = dense.shape
         return dense.reshape(b, bpr * bs, kvh, hd).astype(self.compute_dtype)
 
+    @jax.named_scope("kv.scatter")
     def commit(self, layer_cache, view, pos):
         """Scatter the one new column the decode layer wrote at ``pos``
         back into the pool slice. ``pos`` is a traced (B,) vector (engine
@@ -177,6 +179,7 @@ class PagedKVLayout:
             }
         return layer_cache.at[blk, off].set(col.astype(layer_cache.dtype))
 
+    @jax.named_scope("kv.scatter")
     def commit_column(self, layer_cache, col, pos):
         """Scatter one freshly-computed K (or V) column ``col`` (B, 1, kvh,
         hd) at ``pos`` directly into the pool slice — the Pallas decode
@@ -200,6 +203,7 @@ class PagedKVLayout:
             }
         return layer_cache.at[blk, off].set(col.astype(layer_cache.dtype))
 
+    @jax.named_scope("kv.scatter")
     def commit_window(self, layer_cache, window, pos, count):
         """Scatter the first ``count[b]`` columns of a speculative-verify
         window into the pool, stacked over layers: ``window`` is
@@ -678,6 +682,7 @@ class DenseKVBackend(KVCacheBackend):
     def make_layout(self, tables):
         return None
 
+    @jax.named_scope("kv.scatter")
     def prefill_write(self, cache, new_cache, slot, table_row):
         # full-row dynamic_update_slice: zeros beyond the bucket wipe every
         # stale byte of the slot's previous occupant
@@ -690,6 +695,7 @@ class DenseKVBackend(KVCacheBackend):
             for which in ("k", "v")
         }
 
+    @jax.named_scope("kv.scatter")
     def commit_window(self, cache, window_kv, tables, pos, count):
         w = window_kv["k"].shape[2]
         j = jnp.arange(w, dtype=jnp.int32)[None, :]
@@ -828,6 +834,7 @@ class PagedKVBackend(KVCacheBackend):
             attention_impl=self.attention_impl,
         )
 
+    @jax.named_scope("kv.scatter")
     def prefill_write(self, cache, new_cache, slot, table_row):
         """Per-block ``dynamic_update_slice`` writes of the bucketed prefill
         KV into the slot's blocks. The loop bound is static

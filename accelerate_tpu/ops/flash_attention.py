@@ -228,6 +228,7 @@ def _flash_fwd(q, k, v, segs, h, h_kv, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return out, lse
 
@@ -379,6 +380,7 @@ def _flash_bwd(q, k, v, segs, out, lse, do, h, h_kv, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_args)
 
     # merged q index for (kv-merged index g, inner step t): the group's
@@ -425,6 +427,7 @@ def _flash_bwd(q, k, v, segs, out, lse, do, h, h_kv, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_args)
     return dq, dk, dv
 

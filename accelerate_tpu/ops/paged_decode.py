@@ -241,6 +241,7 @@ def paged_flash_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h_kv, n_rep, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), *args)
     return out.reshape(b, 1, h, d)
 
@@ -388,6 +389,7 @@ def paged_flash_verify(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h_kv, rows, d), q.dtype),
         interpret=interpret,
+        name="paged_verify",
     )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), *args)
     out = out.reshape(b, h_kv, n_rep, w, d).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, w, h, d)
@@ -543,6 +545,7 @@ def fused_sample(
             vmem_limit_bytes=max(16 << 20, 8 * r * v * 4)
         ),
         interpret=interpret,
+        name="fused_sample",
     )(
         knob(temperature, jnp.float32),
         knob(top_k, jnp.int32),

@@ -80,5 +80,6 @@ def quantized_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="quant_matmul",
     )(x2, q, scales)
     return out.reshape(*lead, n)

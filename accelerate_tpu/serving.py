@@ -799,21 +799,30 @@ class InferenceServer:
                     self._draining = True
                     if eng.live_count() == 0:
                         return  # queued requests rejected by the finally
-            self._flush_metrics()
-            st = self._breaker.state()
-            if st == _CircuitBreaker.OPEN:
-                # engine failures reset the arena, so an open breaker means
-                # no live occupants: shed hopeless queued requests and wait
-                # out the probe window like static mode
-                self._shed_expired()
-                if eng.live_count() == 0:
-                    time.sleep(
-                        min(0.01, max(self._breaker.seconds_until_probe(), 0.001))
-                    )
-                    continue
-            elif not self._draining:
-                self._admit_slots(probe=(st == _CircuitBreaker.HALF_OPEN))
-            self._engine_tick()
+            # one pass of the scheduler, never opened while the loop sleeps
+            # on _wake above: admission, the decode dispatch, the readback
+            # and the replies nest under it by the span's parent field
+            with tracing.span(
+                "serving.tick", queue_depth=len(self._queue),
+                live=eng.live_count(),
+            ) as tick:
+                self._flush_metrics()
+                st = self._breaker.state()
+                if st == _CircuitBreaker.OPEN:
+                    # engine failures reset the arena, so an open breaker means
+                    # no live occupants: shed hopeless queued requests and wait
+                    # out the probe window like static mode
+                    self._shed_expired()
+                    if eng.live_count() == 0:
+                        time.sleep(
+                            min(0.01, max(self._breaker.seconds_until_probe(), 0.001))
+                        )
+                        continue
+                elif not self._draining:
+                    tick.set("admitted", self._admit_slots(
+                        probe=(st == _CircuitBreaker.HALF_OPEN)
+                    ))
+                self._engine_tick()
 
     # ------------------------------------------------- continuous scheduling
     def _estimated_completion_s(self, budget: int) -> float:
@@ -821,11 +830,12 @@ class InferenceServer:
         request's completion estimate scales with its token budget."""
         return self._batch_time_ewma * max(1, budget)
 
-    def _admit_slots(self, probe: bool = False) -> None:
+    def _admit_slots(self, probe: bool = False) -> int:
         """Admit queued requests into free arena slots. Each admission is an
         interleaved ``prefill_insert`` program; live slots keep their state
         and simply decode alongside the newcomer on the next step. ``probe``
-        (half-open breaker) admits at most one — risk the minimum."""
+        (half-open breaker) admits at most one — risk the minimum. Returns
+        how many were admitted."""
         eng = self._engine
         limit = 1 if probe else eng.free_slots()
         admitted = 0
@@ -920,9 +930,10 @@ class InferenceServer:
                     )
                     raise
                 self._engine_failure(exc, also_fail=req)
-                return
+                return admitted
             self.metrics.bump("engine_inserts")
             admitted += 1
+        return admitted
 
     def _engine_tick(self) -> None:
         """One fused decode step + deferred-ring poll + retirement replies +
@@ -983,6 +994,10 @@ class InferenceServer:
         failure here must fail THESE requests, not strand them."""
         if not retired:
             return
+        with tracing.span("serving.reply", retired=len(retired)):
+            self._reply(retired)
+
+    def _reply(self, retired: list) -> None:
         reqs = [occ.tag for occ in retired]
         try:
             fault_point("serving_before_reply", replica=self.replica_id)

@@ -6,8 +6,8 @@ the serving stack (``fleet.py`` -> ``serving.py`` -> ``engine.py``) and
 the training loop (data wait, fused step dispatch, deferred-readback ring
 drain, checkpoint commit/replication) opens spans through the ONE
 context-manager API in this module, so a single trace ID strings a
-request's placement, queue wait, admission, prefill, sampled decode
-steps, speculative verify, failover hops, and retire into one timeline.
+request's placement, queue wait, admission, prefill, every decode step,
+speculative verify, failover hops, and retire into one timeline.
 
 Design constraints (graftcheck G107 enforces the first two statically):
 
@@ -31,11 +31,22 @@ Design constraints (graftcheck G107 enforces the first two statically):
   ``FailoverExhaustedError``, checkpoint rollback) or SIGUSR1 dumps them
   as Chrome/Perfetto trace-event JSON under ``runs/``.
 
-Clocks: spans read ``time.monotonic()`` only. The tracer records one
-``(monotonic, unix)`` epoch pair at construction — the same epoch a
-``jax.profiler.trace`` session started next to it can be aligned
-against, so host spans overlay XLA device timelines (:func:`epoch`, and
-the ``otherData.epoch_unix`` field of every dump).
+Clocks: spans read ``time.monotonic()`` only, and a dump's timestamps
+are relative to the tracer's construction. While a ``jax.profiler``
+session is active every span ALSO enters a
+``jax.profiler.TraceAnnotation`` of the same name (the bridge, see
+:class:`_SpanCM`): the profiler stamps that on its own clock, the one
+the device planes of the ``.xplane.pb`` use, so XProf or Perfetto shows
+``serving.tick`` above ``jit__decode_impl`` with nothing else to
+install. Such a span is ``profiled``, and the tracer keeps the profiled
+spans of the newest session whole (:meth:`Tracer.session_spans`) — the
+same interval every device-trace metric is computed over, found without
+any absolute time.
+
+Structure: every span has a process-unique ``id`` and the ``parent`` id
+of the span that was open on its thread when it opened (0: none); both
+ride the annotation as stats and a dump as ``args``. ``trace_id`` is
+what it was: the identifier the spans of one request share.
 
 Thread-safety: each ring is appended only by its owner thread (no lock
 on the hot path; list element writes are atomic under the GIL); dumps
@@ -48,6 +59,7 @@ import itertools
 import json
 import os
 import signal
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -65,16 +77,41 @@ __all__ = [
     "Tracer",
     "MetricsRegistry",
     "span",
-    "step_span",
     "flight_dump",
     "new_trace_id",
     "get_tracer",
     "configure",
     "install_signal_handlers",
-    "epoch",
 ]
 
 _TRACE_COUNTER = itertools.count(1)
+_SPAN_COUNTER = itertools.count(1)
+
+# the most profiled spans a tracer keeps of one profiler session; what does
+# not fit is counted (``Tracer.session_dropped``), never silently lost
+SESSION_CAPACITY = 65536
+
+# jax.profiler.TraceAnnotation once resolved, False where this jax has none
+_ANNOTATION: Any = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, resolved once, or None. A process
+    that has not imported jax has no profiler session to join, and a span
+    must never be what imports it (fleet parents stay off JAX)."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as ann
+
+            ann.is_enabled()
+        except Exception:  # noqa: BLE001 — no profiler must not break span()
+            ann = False
+        _ANNOTATION = ann
+    return ann or None
 
 
 def new_trace_id() -> str:
@@ -87,16 +124,24 @@ class Span:
     """One closed (or in-flight) span. Mutated only through the context
     manager that created it — see :meth:`Tracer.span`."""
 
-    __slots__ = ("name", "trace_id", "t0", "t1", "tid", "attrs", "events")
+    __slots__ = ("name", "trace_id", "id", "parent", "profiled",
+                 "t0", "t1", "tid", "attrs", "events")
 
     def __init__(self, name: str, trace_id: Optional[str], attrs: Dict[str, Any]):
         self.name = name
         self.trace_id = trace_id
+        self.id = next(_SPAN_COUNTER)
+        self.parent = 0  # id of the span open on this thread at __enter__
+        self.profiled = False  # a profiler session was active at __enter__
         self.t0 = 0.0
         self.t1 = 0.0
         self.tid = 0
         self.attrs = attrs
         self.events: List[tuple] = []
+
+    @property
+    def duration_s(self) -> float:
+        return max(self.t1 - self.t0, 0.0)
 
     def set(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -137,23 +182,65 @@ class _SpanCM:
     exception as a typed ``error`` event (type name, ``retriable``,
     ``replica_id``, ``__cause__`` chain — taxonomy attributes, never
     prose), and commits the span to the owner thread's ring. Exceptions
-    always propagate."""
+    always propagate.
 
-    __slots__ = ("_tracer", "_span")
+    The bridge: ``__enter__`` asks ``TraceAnnotation.is_enabled()``, and
+    while a ``jax.profiler`` session is active it also enters a
+    ``TraceAnnotation(name, trace_id=, span=, parent=, **scalar attrs)``
+    that ``__exit__`` leaves, so the span is in the ``.xplane.pb`` on the
+    profiler's clock. Only what the span holds when it opens rides the
+    annotation; a later ``Span.set`` stays in process. With no session the
+    added cost is that one call."""
+
+    __slots__ = ("_tracer", "_span", "_stack", "_annotation", "_session")
 
     def __init__(self, tracer: "Tracer", span_obj: Span):
         self._tracer = tracer
         self._span = span_obj
+        self._annotation = None
+        self._session = 0
 
     def __enter__(self) -> Span:
         sp = self._span
+        tracer = self._tracer
         sp.tid = threading.get_ident()
+        local = tracer._local
+        stack = getattr(local, "stack", None)
+        if stack is None:
+            stack = local.stack = []
+        self._stack = stack
+        if stack:
+            sp.parent = stack[-1]
+        stack.append(sp.id)
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            sp.profiled = True
+            self._session = tracer._enter_session()
+            try:
+                stats = {k: v for k, v in sp.attrs.items()
+                         if isinstance(v, (bool, int, float, str))}
+                if sp.trace_id is not None:
+                    stats["trace_id"] = sp.trace_id
+                stats["span"], stats["parent"] = sp.id, sp.parent
+                self._annotation = ann(sp.name, **stats)
+                self._annotation.__enter__()
+            except Exception:  # noqa: BLE001 — an attribute the profiler refuses
+                self._annotation = None
+        elif tracer._in_session:
+            tracer._in_session = False
         sp.t0 = time.monotonic()
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         sp = self._span
         sp.t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        stack = self._stack
+        if stack and stack[-1] == sp.id:
+            stack.pop()
+        elif sp.id in stack:  # closed out of order (a span held by a generator)
+            stack.remove(sp.id)
         if exc is not None:
             cause = getattr(exc, "__cause__", None)
             sp.events.append((sp.t1, "error", {
@@ -162,7 +249,7 @@ class _SpanCM:
                 "replica_id": getattr(exc, "replica_id", None),
                 "cause": type(cause).__name__ if cause is not None else None,
             }))
-        self._tracer._append(sp)
+        self._tracer._append(sp, self._session)
         return False
 
 
@@ -202,6 +289,12 @@ class Tracer:
         self._dump_count = 0
         self._epoch_monotonic = time.monotonic()
         self._epoch_unix = time.time()
+        # the profiled spans of the newest profiler session, whole
+        self._session_lock = threading.Lock()
+        self._session_spans: List[Span] = []
+        self._session_dropped = 0
+        self._session_seen = 0  # sessions seen so far; 0: none yet
+        self._in_session = False
 
     # -- introspection
     @property
@@ -212,20 +305,16 @@ class Tracer:
     def enabled(self) -> bool:
         return self._config.enabled
 
-    @property
-    def sample_every(self) -> int:
-        """Decode-step span sampling period (engine hot loop)."""
-        return self._config.decode_sample_every
-
-    def epoch(self) -> Dict[str, float]:
-        """The shared ``(monotonic, unix)`` epoch pair — start a
-        ``jax.profiler.trace`` next to tracer construction and this is
-        the offset that aligns host spans with the device timeline."""
-        return {"monotonic": self._epoch_monotonic, "unix": self._epoch_unix}
-
     def dropped_spans(self) -> int:
         with self._rings_lock:
             return sum(r.dropped for r in self._rings)
+
+    @property
+    def session_dropped(self) -> int:
+        """Profiled spans of the newest session that did not fit
+        :data:`SESSION_CAPACITY`; a reader that finds this non-zero holds
+        a partial session and should report nothing."""
+        return self._session_dropped
 
     # -- recording
     def span(self, name: str, trace_id: Optional[str] = None, **attrs: Any):
@@ -245,8 +334,32 @@ class Tracer:
                 self._rings.append(ring)
         return ring
 
-    def _append(self, sp: Span) -> None:
+    def _enter_session(self) -> int:
+        """Called by a span that opens while a profiler session is active:
+        the number of that session. A span that found none active in
+        between (``_in_session`` cleared) makes the next one the first of a
+        new session, which empties the list; two sessions with no span
+        opened between them count as one."""
+        if not self._in_session:
+            with self._session_lock:
+                if not self._in_session:
+                    self._session_seen += 1
+                    self._session_spans = []
+                    self._session_dropped = 0
+                    self._in_session = True
+        return self._session_seen
+
+    def _append(self, sp: Span, session: int = 0) -> None:
         self._ring().append(sp)
+        if not session:
+            return
+        with self._session_lock:
+            if session != self._session_seen:  # a straggler of an older session
+                return
+            if len(self._session_spans) < SESSION_CAPACITY:
+                self._session_spans.append(sp)
+            else:
+                self._session_dropped += 1
 
     # -- reading (tests, dumps)
     def spans(self, trace_id: Optional[str] = None,
@@ -260,6 +373,19 @@ class Tracer:
             out.extend(list(ring.spans))
         if trace_id is not None:
             out = [s for s in out if s.trace_id == trace_id]
+        if name is not None:
+            out = [s for s in out if s.name == name]
+        out.sort(key=lambda s: s.t0)
+        return out
+
+    def session_spans(self, name: Optional[str] = None) -> List[Span]:
+        """The profiled spans of the newest profiler session, oldest first:
+        every span that opened while the session was active, and so has
+        its annotation in that session's trace. Unlike the rings this is
+        not overwritten by what runs afterwards; it is emptied when the
+        first span of a later session opens."""
+        with self._session_lock:
+            out = list(self._session_spans)
         if name is not None:
             out = [s for s in out if s.name == name]
         out.sort(key=lambda s: s.t0)
@@ -281,12 +407,13 @@ class Tracer:
                 if sp.t1 < horizon:
                     continue
                 thread_names.setdefault(sp.tid, ring.thread_name)
-                args = {"trace_id": sp.trace_id}
+                args = {"trace_id": sp.trace_id, "span": sp.id,
+                        "parent": sp.parent}
                 args.update(sp.attrs)
                 events.append({
                     "name": sp.name, "ph": "X", "pid": pid, "tid": sp.tid,
                     "ts": (sp.t0 - base) * 1e6,
-                    "dur": max(sp.t1 - sp.t0, 0.0) * 1e6,
+                    "dur": sp.duration_s * 1e6,
                     "args": args,
                 })
                 for t, ev_name, ev_attrs in sp.events:
@@ -426,26 +553,10 @@ def span(name: str, trace_id: Optional[str] = None, **attrs: Any):
     return get_tracer().span(name, trace_id, **attrs)
 
 
-def step_span(name: str, step: int, **attrs: Any):
-    """Sampled span for per-step hot loops (engine decode, train step):
-    records every ``decode_sample_every``-th step and hands back the
-    shared no-op context manager otherwise, so the steady-state cost is
-    one modulo. Same CM discipline as :func:`span` (G107)."""
-    tracer = get_tracer()
-    cfg = tracer.config
-    if not cfg.enabled or step % cfg.decode_sample_every:
-        return _NULL_CM
-    return tracer.span(name, None, **attrs)
-
-
 def flight_dump(reason: str) -> Optional[str]:
     """Typed-failure dump hook on the default tracer (see
     :meth:`Tracer.maybe_dump`)."""
     return get_tracer().maybe_dump(reason)
-
-
-def epoch() -> Dict[str, float]:
-    return get_tracer().epoch()
 
 
 def install_signal_handlers(tracer: Optional[Tracer] = None) -> bool:

@@ -347,9 +347,6 @@ class TracingConfig(KwargsHandler):
       the OLDEST span and counts it (``dropped_spans``).
     * ``retain_s`` — flight-recorder window: a dump serializes only spans
       that ended within the last ``retain_s`` seconds.
-    * ``decode_sample_every`` — the engine opens a ``engine.decode_step``
-      span every N decode steps (per-step spans would dominate the ring
-      and the overhead budget).
     * ``dump_dir``/``max_dumps`` — where auto-dumps land and how many a
       process may write (a crash loop must not fill the disk).
     * ``dump_on_failure`` — auto-dump on typed failures (worker death,
@@ -360,7 +357,6 @@ class TracingConfig(KwargsHandler):
     enabled: bool = True
     ring_capacity: int = 2048
     retain_s: float = 30.0
-    decode_sample_every: int = 16
     dump_dir: str = "runs"
     max_dumps: int = 8
     dump_on_failure: bool = True
@@ -372,11 +368,6 @@ class TracingConfig(KwargsHandler):
             )
         if self.retain_s <= 0:
             raise ValueError(f"retain_s must be > 0, got {self.retain_s}")
-        if self.decode_sample_every < 1:
-            raise ValueError(
-                "decode_sample_every must be >= 1, got "
-                f"{self.decode_sample_every}"
-            )
         if self.max_dumps < 0:
             raise ValueError(f"max_dumps must be >= 0, got {self.max_dumps}")
 

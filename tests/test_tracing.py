@@ -10,12 +10,19 @@
 * ring discipline — bounded per-thread rings drop oldest-first with an
   exact ``dropped_spans`` count; disabled tracing hands back ONE shared
   no-op context manager (no per-call allocation);
+* the profiler bridge — a span opened inside a real ``jax.profiler``
+  session is in the ``.xplane.pb``'s ``/host:CPU`` plane with its
+  attributes, id and parent; the session's spans are kept whole; with no
+  session no annotation is made; every step leaves a span (no sampling);
+  a continuous server's tick nests dispatch, readback and reply;
 * latency surface — ``ServingResult`` reports queue_wait_s / prefill_s /
   decode_steps for every completed request;
 * MetricsRegistry — the unified counters/gauges/reservoir surface and
   the single periodic tracker flush (due/flush/maybe_flush).
 """
 
+import contextlib
+import glob
 import json
 import os
 import threading
@@ -212,17 +219,16 @@ def test_disabled_span_is_shared_noop():
     assert t.spans() == [] and t.dropped_spans() == 0
 
 
-def test_step_span_samples_by_period(tracer, tmp_path):
-    tracing.configure(TracingConfig(
-        enabled=True, decode_sample_every=4, dump_dir=str(tmp_path),
-    ))
+def test_every_step_leaves_a_span(tracer):
+    """No sampling: eight steps leave eight spans, each with its own id."""
     for step in range(8):
-        with tracing.step_span("hot", step):
+        with tracing.span("hot", step=step):
             pass
     recorded = tracing.get_tracer().spans(name="hot")
-    assert len(recorded) == 2  # steps 0 and 4
-    # non-sampled steps return the shared no-op CM
-    assert tracing.step_span("hot", 1) is tracing.step_span("hot", 2)
+    assert [sp.attrs["step"] for sp in recorded] == list(range(8))
+    assert len({sp.id for sp in recorded}) == 8
+    assert not hasattr(tracing, "step_span") and not hasattr(tracing, "epoch")
+    assert not hasattr(TracingConfig(), "decode_sample_every")
 
 
 def test_span_records_exception_as_typed_event(tracer):
@@ -333,3 +339,355 @@ def test_serving_and_fleet_share_registry_flush(tracer):
         ))
     finally:
         router.close(drain=False)
+
+
+# --------------------------------------------------------- profiler bridge
+@contextlib.contextmanager
+def profiler_session(directory):
+    """A real ``jax.profiler`` session on the CPU under the options
+    ``chipbench/run.py:start_trace`` sets."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(directory), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def host_events(directory):
+    """``{name: [(start_ns, end_ns, stats)]}`` of the ``/host:CPU`` plane."""
+    from jax.profiler import ProfileData
+
+    found = sorted(glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
+                             recursive=True))
+    assert found, "the profiler wrote no .xplane.pb"
+    out = {}
+    for plane in ProfileData.from_file(found[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+                )
+    return out
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts what is made."""
+
+    active = False
+    made = 0
+
+    def __init__(self, name, **stats):
+        type(self).made += 1
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.active
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    _FakeAnnotation.active, _FakeAnnotation.made = False, 0
+    monkeypatch.setattr(tracing, "_ANNOTATION", _FakeAnnotation)
+    return _FakeAnnotation
+
+
+def test_parent_and_id_follow_the_threads_open_span(tracer):
+    with tracing.span("outer") as outer:
+        with tracing.span("mid") as mid:
+            with tracing.span("leaf") as leaf:
+                pass
+        with tracing.span("second") as second:
+            pass
+    seen = []
+
+    def other_thread():
+        with tracing.span("elsewhere") as sp:
+            seen.append(sp)
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    t.join()
+    assert outer.parent == 0 and mid.parent == outer.id
+    assert leaf.parent == mid.id and second.parent == outer.id
+    assert seen[0].parent == 0  # the stack is the thread's own
+    assert len({outer.id, mid.id, leaf.id, second.id, seen[0].id}) == 5
+    args = {e["name"]: e["args"] for e in tracer.to_chrome_trace()["traceEvents"]
+            if e["ph"] == "X"}
+    assert args["leaf"]["span"] == leaf.id and args["leaf"]["parent"] == mid.id
+
+
+def test_span_in_a_profiler_session_is_in_the_host_plane(tracer, tmp_path):
+    with profiler_session(tmp_path):
+        with tracing.span("bridge.outer", trace_id="t-1", live=3, share=0.5,
+                          kind="decode", skipped=[1, 2]) as outer:
+            time.sleep(0.002)
+            with tracing.span("bridge.inner", popped=2) as inner:
+                time.sleep(0.002)
+            time.sleep(0.002)
+    assert outer.profiled and inner.profiled
+    events = host_events(tmp_path)
+    (o0, o1, ostats), = events["bridge.outer"]
+    (i0, i1, istats), = events["bridge.inner"]
+    assert ostats["live"] == 3 and ostats["kind"] == "decode"
+    assert ostats["share"] == pytest.approx(0.5)
+    assert "skipped" not in ostats  # scalars only
+    assert ostats["trace_id"] == "t-1"
+    assert ostats["span"] == outer.id and ostats["parent"] == 0
+    assert istats["span"] == inner.id and istats["parent"] == outer.id
+    assert istats["popped"] == 2
+    assert o0 <= i0 and i1 <= o1  # the parent's annotation encloses the child's
+    # on the profiler's clock the annotation is as long as the span
+    assert (o1 - o0) / 1e9 == pytest.approx(outer.duration_s, abs=2e-3)
+
+
+def test_session_spans_are_the_sessions_own(tracer, tmp_path):
+    with tracing.span("before"):
+        pass
+    with tracing.span("straddles"):  # opened outside: no annotation, not kept
+        with profiler_session(tmp_path / "one"):
+            for i in range(3):
+                with tracing.span("inside", i=i):
+                    pass
+    with tracing.span("between"):
+        pass
+    first = tracer.session_spans()
+    assert [sp.name for sp in first] == ["inside"] * 3
+    assert [sp.attrs["i"] for sp in first] == [0, 1, 2]  # oldest first
+    assert all(sp.profiled for sp in first)
+    assert not any(sp.profiled for sp in tracer.spans() if sp.name != "inside")
+    assert tracer.session_spans(name="nothing") == []
+    # kept after the session, whatever runs afterwards
+    for _ in range(20):
+        with tracing.span("after"):
+            pass
+    assert len(tracer.session_spans()) == 3
+    # the first span of a later session empties the list
+    with profiler_session(tmp_path / "two"):
+        with tracing.span("later"):
+            pass
+    assert [sp.name for sp in tracer.session_spans()] == ["later"]
+    assert tracer.session_dropped == 0
+
+
+def test_session_store_counts_what_it_drops(tracer, fake_annotation, monkeypatch):
+    monkeypatch.setattr(tracing, "SESSION_CAPACITY", 8)
+    fake_annotation.active = True
+    for i in range(11):
+        with tracing.span("s", i=i):
+            pass
+    kept = tracer.session_spans()
+    assert [sp.attrs["i"] for sp in kept] == list(range(8))
+    assert tracer.session_dropped == 3
+    fake_annotation.active = False
+    with tracing.span("outside"):
+        pass
+    fake_annotation.active = True
+    with tracing.span("next"):
+        pass
+    assert [sp.name for sp in tracer.session_spans()] == ["next"]
+    assert tracer.session_dropped == 0  # a new session counts anew
+
+
+@pytest.mark.parametrize("session", [False, True])
+def test_annotations_are_made_only_inside_a_session(tracer, fake_annotation, session):
+    fake_annotation.active = session
+    for _ in range(5):
+        with tracing.span("s", k=1) as sp:
+            pass
+    assert fake_annotation.made == (5 if session else 0)
+    assert sp.profiled is session
+    assert len(tracer.session_spans()) == (5 if session else 0)
+
+
+def test_a_profiler_that_fails_does_not_break_span(tracer, monkeypatch):
+    class Broken:
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __init__(self, name, **stats):
+            raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(tracing, "_ANNOTATION", Broken)
+    with tracing.span("s") as sp:
+        sp.set("k", 1)
+    assert tracer.spans(name="s")[0].attrs == {"k": 1}
+
+
+def test_disabled_tracer_is_shared_noop_inside_a_session(fake_annotation):
+    fake_annotation.active = True
+    t = Tracer(TracingConfig(enabled=False))
+    assert t.span("a") is t.span("b", "tid", k=1)
+    with t.span("a"):
+        pass
+    assert fake_annotation.made == 0 and t.session_spans() == []
+
+
+def test_continuous_server_tick_nests_dispatch_readback_and_reply(tracer):
+    """A tiny continuous server: every decode step has its span, and the
+    engine's spans hang under ``serving.tick`` by the parent field."""
+    import jax.numpy as jnp
+
+    from accelerate_tpu.models.llama import LlamaConfig, create_llama
+
+    model = create_llama(LlamaConfig.tiny(compute_dtype=jnp.float32), seed=0)
+    cfg = ServingConfig(
+        mode="continuous", engine_slots=2, engine_max_len=32,
+        engine_prompt_bucket=8, engine_readback_lag=1,
+    )
+    prompts = [[5, 9, 3], [12, 7, 4, 10, 6], [8, 1]]
+    with InferenceServer(model, cfg) as srv:
+        futs = [srv.submit(p, max_new_tokens=4, pad_token_id=0) for p in prompts]
+        for f in futs:
+            f.result(timeout=120)
+        steps = srv.engine.steps
+    by_id = {sp.id: sp for sp in tracer.spans()}
+    named = lambda name: [sp for sp in by_id.values() if sp.name == name]
+
+    def ancestors(sp):
+        while sp.parent:
+            sp = by_id[sp.parent]
+            yield sp.name
+
+    ticks = named("serving.tick")
+    assert ticks and all(sp.parent == 0 for sp in ticks)
+    assert {"queue_depth", "live"} <= set(ticks[0].attrs)
+    assert sum(sp.attrs.get("admitted", 0) for sp in ticks) == len(prompts)
+    decode = named("engine.decode_step")
+    assert len(decode) == steps  # one span a step: nothing is sampled away
+    for sp in decode:
+        assert by_id[sp.parent].name == "serving.tick"
+        assert 1 <= sp.attrs["decoding"] <= sp.attrs["slots"] == 2
+        assert 0 < sp.attrs["kv_live_tokens"] <= sp.attrs["kv_reserved_tokens"]
+    prefill = named("engine.prefill")
+    assert len(prefill) == len(prompts)
+    assert sorted(sp.attrs["prompt_len"] for sp in prefill) == [2, 3, 5]
+    for sp in prefill:
+        assert sp.attrs["bucket"] == 8
+        assert list(ancestors(sp)) == ["serving.admit", "serving.tick"]
+    readback = named("engine.readback")
+    assert {sp.attrs["kind"] for sp in readback} == {"prefill", "decode"}
+    assert all(by_id[sp.parent].name == "serving.tick" for sp in readback)
+    reply = named("serving.reply")
+    assert sum(sp.attrs["retired"] for sp in reply) == len(prompts)
+    assert all(by_id[sp.parent].name == "serving.tick" for sp in reply)
+    assert all("serving.tick" in ancestors(sp) for sp in named("engine.retire"))
+    # a tick's own time is what is left of it once the waits are taken out
+    for tick in ticks:
+        inside = sum(sp.duration_s for sp in readback if sp.parent == tick.id)
+        assert inside <= tick.duration_s + 1e-9
+
+
+def test_train_step_and_data_wait_leave_a_span_every_step(tracer):
+    import optax
+
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+    from accelerate_tpu.test_utils.training import (
+        RegressionModel,
+        make_regression_data,
+        regression_loss,
+    )
+
+    for state in (AcceleratorState, GradientState, PartialState):
+        state._reset_state()
+    try:
+        acc = Accelerator()
+        loader = acc.prepare_data_loader(
+            make_regression_data(64), batch_size=16, drop_last=True
+        )
+        model, opt = acc.prepare(RegressionModel(), optax.sgd(0.1))
+        step = acc.train_step(regression_loss, model=model, optimizer=opt)
+        for batch in loader:
+            step(batch)
+    finally:
+        for state in (AcceleratorState, GradientState, PartialState):
+            state._reset_state()
+    sent = tracer.spans(name="train.step")
+    assert [sp.attrs["step"] for sp in sent] == [0, 1, 2, 3]
+    assert all(sp.parent == 0 for sp in sent)
+    waits = tracer.spans(name="train.data_wait")
+    assert [sp.attrs["step"] for sp in waits][:4] == [0, 1, 2, 3]  # one a fetch
+
+
+# ------------------------------------------------- names in the device trace
+def test_named_scopes_reach_the_lowered_programs(tracer):
+    """A profile names device work by each operation's ``op_name``: the
+    phases of the fused train step and of the engine's programs must be in
+    the lowered text, and every Pallas kernel must carry its ``name=``."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.engine import ContinuousBatchingEngine
+    from accelerate_tpu.models.llama import LlamaConfig, create_llama
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+    from accelerate_tpu.test_utils.training import (
+        RegressionModel,
+        make_regression_data,
+        regression_loss,
+    )
+
+    def lowered(jitted, *args):
+        return jitted.lower(*args).as_text(debug_info=True)
+
+    model = create_llama(LlamaConfig.tiny(compute_dtype=jnp.float32), seed=0)
+    eng = ContinuousBatchingEngine(
+        model, slots=2, max_len=32, prompt_bucket=8, kv_cache="paged",
+        attention_impl="pallas",
+    )
+    text = lowered(eng._decode_jit, eng._donated, eng._carried, model.params,
+                   eng._backend.device_tables())
+    for name in ("kv.scatter", "engine.sample", "paged_decode", "fused_sample"):
+        assert name in text, name
+    dense = ContinuousBatchingEngine(model, slots=2, max_len=32, prompt_bucket=8,
+                                     kv_cache="paged")
+    text = lowered(dense._decode_jit, dense._donated, dense._carried, model.params,
+                   dense._backend.device_tables())
+    assert "kv.gather" in text and "kv.scatter" in text and "engine.sample" in text
+
+    for state in (AcceleratorState, GradientState, PartialState):
+        state._reset_state()
+    try:
+        acc = Accelerator(gradient_accumulation_steps=2)
+        net, opt = acc.prepare(RegressionModel(), optax.sgd(0.1))
+        step = acc.train_step(regression_loss, model=net, optimizer=opt,
+                              max_grad_norm=1.0)
+        batch = {k: jnp.asarray(v[:16]) for k, v in make_regression_data(64).items()}
+        text = step.lower(batch).as_text(debug_info=True)
+    finally:
+        for state in (AcceleratorState, GradientState, PartialState):
+            state._reset_state()
+    for name in ("train.forward_backward", "train.accumulate", "train.clip",
+                 "train.optimizer"):
+        assert name in text, name
+
+
+def test_flash_kernels_carry_their_names():
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text(debug_info=True)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text, name
