@@ -836,7 +836,7 @@ class ContinuousBatchingEngine:
         """Scatter host-tier block payloads into the pool (the restore half
         of the spill/restore plan): ``payload`` mirrors the pool's leaf
         structure with a leading restore-batch axis — f32 ``{"k","v"}`` of
-        (R, L, bs, kvh, hd), int8 adds per-position scales — and ``ids``
+        (R, L, bs, kvh * hd), int8 adds per-position scales — and ``ids``
         (R,) names the target blocks, padded with the null block (write to
         the garbage sink, never a live block). R is fixed at blocks_per_row
         so every restore shares one compiled program."""

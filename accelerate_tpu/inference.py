@@ -198,7 +198,9 @@ def generate(
                 cache, tables = pool_from_dense(
                     cache, kv_block_size, quantized=kv_backend == "paged_int8"
                 )
-                kv_layout = PagedKVLayout(tables, kv_block_size, config.compute_dtype)
+                kv_layout = PagedKVLayout(
+                    tables, kv_block_size, config.compute_dtype, config.head_dim
+                )
             else:
                 kv_layout = None
             done0 = jnp.zeros((b,), dtype=bool)

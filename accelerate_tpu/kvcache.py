@@ -9,12 +9,12 @@ bounded-program discipline intact (two jitted programs per config; three
 when speculative decoding adds its ``verify_step``):
 
 * **Block pool + block tables** — one shared device pool ``(layers,
-  num_blocks, block_size, kv_heads, head_dim)``; each slot owns a row of a
+  num_blocks, block_size, kv_heads * head_dim)``; each slot owns a row of a
   host-side block table mapping its logical positions to pool blocks. Decode
   gathers a slot's blocks into the dense per-layer view the model attention
-  already consumes (``pool[tables]`` + reshape), writes the new token column
-  back with one scatter, and prefill writes each bucket block with
-  ``lax.dynamic_update_slice``. Tables ride into the compiled programs as
+  already consumes (``pool[layer, tables]`` + reshape), writes the new token
+  column back with one scatter, and prefill writes its bucket's blocks with
+  one scatter more. Tables ride into the compiled programs as
   *traced operands* (values change, shapes don't), so a paged engine still
   dispatches exactly one prefill and one decode program per config.
 * **Admission by free blocks, not max_len** — a request needs
@@ -34,6 +34,22 @@ when speculative decoding adds its ``verify_step``):
   scales; quantized on write (prefill blocks and the decode column) and
   dequantized inside the compiled step right before attention. Halves-to-
   quarters pool HBM at a bounded, deterministic accuracy cost.
+
+The pool's layout, and who may slice it. A pool leaf is ``(layers,
+num_blocks, block_size, kv_heads * head_dim)``: the two head axes are one, so
+that a block is a ``(block_size, kv_heads * head_dim)`` tile the chip keeps
+row-major and unpadded whatever the head size (with ``head_dim`` 64 as a
+minor axis of its own the TPU lays the pool out with ``num_blocks`` minor:
+every kernel call then transposed a layer's slice, and every one-block write
+swept the pool; PERF.md, PR 28). Programs take the pool donated, update it in
+place and hand it back in the same layout. Inside a program nobody slices it
+by layer: a layer loop carries the pool whole (or closes over it when it only
+reads), and a layer reaches its part by index: :class:`PagedKVLayout`'s ops
+scatter and gather at ``[layer, block, offset]``, the Pallas kernels walk
+``tables + layer * num_blocks`` over the pool flattened to ``(layers *
+num_blocks, block_size, kv_heads * head_dim)``. Whole blocks move in and out
+at ``[:, ids]`` (prefill, spill, restore, transfer). A scan that took the pool
+as ``xs`` and gave it back as ``ys`` would copy it every step.
 
 Safety invariants (the reasons slot recycling cannot corrupt KV):
 
@@ -119,10 +135,27 @@ def kv_dequantize(q, scale, dtype):
 
 
 # --------------------------------------------------------------- device side
+def _merge_heads(x):
+    """``(..., kv_heads, head_dim)`` -> ``(..., kv_heads * head_dim)``, the
+    pool's lane axis."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _split_heads(x, head_dim: int):
+    """The inverse: ``(..., kv_heads * head_dim)`` -> ``(..., kv_heads,
+    head_dim)``."""
+    return x.reshape(*x.shape[:-1], -1, head_dim)
+
+
 class PagedKVLayout:
-    """Device-side view/commit ops over one layer's pool slice, closed over
-    the (traced) block tables. Built *inside* a jitted program each dispatch
-    — tables are operands, not constants, so table churn never recompiles.
+    """Device-side view/commit ops over the pool, closed over the (traced)
+    block tables. Built *inside* a jitted program each dispatch — tables are
+    operands, not constants, so table churn never recompiles.
+
+    Every op takes the whole pool ``(L, num_blocks, bs, kvh * hd)`` and the
+    (traced) ``layer`` it works on, and reaches that layer by index, so the
+    layer loop carries the pool whole (module docstring, "The pool's
+    layout"); ``layer=None`` takes one layer's ``(num_blocks, bs, kvh * hd)``.
 
     The model decode layers keep consuming a dense ``(B, max_len, kvh, hd)``
     cache: :meth:`view` gathers it from the pool (dequantizing int8),
@@ -130,11 +163,12 @@ class PagedKVLayout:
     and scatters it back (quantizing int8). Everything else in attention is
     untouched — one KV story for dense and paged."""
 
-    def __init__(self, tables, block_size: int, compute_dtype,
+    def __init__(self, tables, block_size: int, compute_dtype, head_dim: int,
                  attention_impl: str = "reference"):
         self.tables = tables  # (B, blocks_per_row) int32, traced
         self.block_size = block_size
         self.compute_dtype = compute_dtype
+        self.head_dim = head_dim
         # "reference": model gathers view() and commits after attending;
         # "pallas": model commits the new column first (commit_column) and
         # the fused flash-decode kernel walks the tables itself — no dense
@@ -142,52 +176,43 @@ class PagedKVLayout:
         self.attention_impl = attention_impl
 
     @jax.named_scope("kv.gather")
-    def view(self, layer_cache):
-        """Gather one layer's pool slice into the dense per-slot view:
-        ``(num_blocks, bs, kvh, hd)`` (or the int8 ``{"q","s"}`` pair) →
-        ``(B, blocks_per_row * bs, kvh, hd)``. Unallocated table entries
-        gather the null block — masked out of attention by ``k_pos <=
-        pos``."""
-        if isinstance(layer_cache, dict):
-            q = layer_cache["q"][self.tables]  # (B, bpr, bs, kvh, hd)
-            s = layer_cache["s"][self.tables]  # (B, bpr, bs)
-            dense = kv_dequantize(q, s, self.compute_dtype)
+    def view(self, pool, layer=None):
+        """Gather one layer of the pool into the dense per-slot view:
+        ``(L, num_blocks, bs, kvh * hd)`` at ``layer`` (or the int8
+        ``{"q","s"}`` pair) → ``(B, blocks_per_row * bs, kvh, hd)``.
+        Unallocated table entries gather the null block — masked out of
+        attention by ``k_pos <= pos``."""
+        at = (self.tables,) if layer is None else (layer, self.tables)
+        if isinstance(pool, dict):
+            dense = kv_dequantize(
+                _split_heads(pool["q"][at], self.head_dim), pool["s"][at],
+                self.compute_dtype,
+            )
         else:
-            dense = layer_cache[self.tables]
+            dense = _split_heads(pool[at], self.head_dim)
         b, bpr, bs, kvh, hd = dense.shape
         return dense.reshape(b, bpr * bs, kvh, hd).astype(self.compute_dtype)
 
-    @jax.named_scope("kv.scatter")
-    def commit(self, layer_cache, view, pos):
+    def commit(self, pool, view, pos, layer=None):
         """Scatter the one new column the decode layer wrote at ``pos``
-        back into the pool slice. ``pos`` is a traced (B,) vector (engine
+        back into the pool. ``pos`` is a traced (B,) vector (engine
         slots) or scalar (the fused generate scan). Ghost slots (retired /
         vacant) carry null-block table entries, so their unconditional
         masked-step writes land in the garbage sink."""
         if jnp.ndim(pos) == 0:
             pos = jnp.broadcast_to(pos, (self.tables.shape[0],))
-        col = jnp.take_along_axis(view, pos[:, None, None, None], axis=1)[:, 0]
-        blk = jnp.take_along_axis(
-            self.tables, (pos // self.block_size)[:, None], axis=1
-        )[:, 0]
-        off = pos % self.block_size
-        if isinstance(layer_cache, dict):
-            q, s = kv_quantize(col)
-            return {
-                "q": layer_cache["q"].at[blk, off].set(q),
-                "s": layer_cache["s"].at[blk, off].set(s),
-            }
-        return layer_cache.at[blk, off].set(col.astype(layer_cache.dtype))
+        col = jnp.take_along_axis(view, pos[:, None, None, None], axis=1)
+        return self.commit_column(pool, col, pos, layer)
 
     @jax.named_scope("kv.scatter")
-    def commit_column(self, layer_cache, col, pos):
+    def commit_column(self, pool, col, pos, layer=None):
         """Scatter one freshly-computed K (or V) column ``col`` (B, 1, kvh,
-        hd) at ``pos`` directly into the pool slice — the Pallas decode
-        path's commit-BEFORE-attend: the kernel then reads the column back
-        from the pool (store→load identity in f32; one bounded quantization
-        for int8), so no dense view is ever gathered. Same ghost-slot
-        safety as :meth:`commit`: released rows' table entries are the null
-        block, a garbage sink."""
+        hd) at ``pos`` directly into the pool at ``[layer, block, offset]``
+        — the Pallas decode path's commit-BEFORE-attend: the kernel then
+        reads the column back from the pool (store→load identity in f32; one
+        bounded quantization for int8), so no dense view is ever gathered.
+        Ghost slots (retired / vacant) carry null-block table entries, so
+        their unconditional masked-step writes land in the garbage sink."""
         if jnp.ndim(pos) == 0:
             pos = jnp.broadcast_to(pos, (self.tables.shape[0],))
         col = col[:, 0]
@@ -195,18 +220,19 @@ class PagedKVLayout:
             self.tables, (pos // self.block_size)[:, None], axis=1
         )[:, 0]
         off = pos % self.block_size
-        if isinstance(layer_cache, dict):
+        at = (blk, off) if layer is None else (layer, blk, off)
+        if isinstance(pool, dict):
             q, s = kv_quantize(col)
             return {
-                "q": layer_cache["q"].at[blk, off].set(q),
-                "s": layer_cache["s"].at[blk, off].set(s),
+                "q": pool["q"].at[at].set(_merge_heads(q)),
+                "s": pool["s"].at[at].set(s),
             }
-        return layer_cache.at[blk, off].set(col.astype(layer_cache.dtype))
+        return pool.at[at].set(_merge_heads(col).astype(pool.dtype))
 
     @jax.named_scope("kv.scatter")
-    def commit_window(self, layer_cache, window, pos, count):
+    def commit_window(self, pool, window, pos, count):
         """Scatter the first ``count[b]`` columns of a speculative-verify
-        window into the pool, stacked over layers: ``window`` is
+        window into the pool, every layer at once: ``window`` is
         ``(L, B, W, kvh, hd)`` holding the window K (or V) rows at positions
         ``pos .. pos+W-1``, ``count`` (B,) the per-slot accepted length.
         Rejected/padded columns (``j >= count``) and positions past the
@@ -226,13 +252,13 @@ class PagedKVLayout:
         )
         blk = jnp.where(valid, blk, _NULL_BLOCK)
         off = abs_pos % bs
-        if isinstance(layer_cache, dict):
+        if isinstance(pool, dict):
             q, s = kv_quantize(window)  # per-(layer, slot, position) scales
             return {
-                "q": layer_cache["q"].at[:, blk, off].set(q),
-                "s": layer_cache["s"].at[:, blk, off].set(s),
+                "q": pool["q"].at[:, blk, off].set(_merge_heads(q)),
+                "s": pool["s"].at[:, blk, off].set(s),
             }
-        return layer_cache.at[:, blk, off].set(window.astype(layer_cache.dtype))
+        return pool.at[:, blk, off].set(_merge_heads(window).astype(pool.dtype))
 
 
 # ----------------------------------------------------------- host spill tier
@@ -819,7 +845,7 @@ class PagedKVBackend(KVCacheBackend):
 
     # ------------------------------------------------------------ device side
     def init_device_state(self):
-        shape = (self._layers, self.pool_blocks, self.block_size, self._kvh, self._hd)
+        shape = (self._layers, self.pool_blocks, self.block_size, self._kvh * self._hd)
         if self.quantized:
             leaf = lambda: {
                 "q": jnp.zeros(shape, jnp.int8),
@@ -830,42 +856,37 @@ class PagedKVBackend(KVCacheBackend):
 
     def make_layout(self, tables):
         return PagedKVLayout(
-            tables, self.block_size, self._dtype,
+            tables, self.block_size, self._dtype, self._hd,
             attention_impl=self.attention_impl,
         )
 
     @jax.named_scope("kv.scatter")
     def prefill_write(self, cache, new_cache, slot, table_row):
-        """Per-block ``dynamic_update_slice`` writes of the bucketed prefill
-        KV into the slot's blocks. The loop bound is static
-        (``ceil(prompt_bucket / block_size)``), so this stays ONE compiled
-        program; rows whose allocation is shorter than the bucket carry
-        null-block table entries there, harmlessly absorbing the extra
-        writes. Shared (COW) prefix blocks are re-written with bitwise
-        identical content — see the module docstring invariants."""
-        bs = self.block_size
+        """One scatter of the bucketed prefill KV into the slot's blocks,
+        every layer at once (``[:, ids]``, as a restore writes them). The
+        bucket's block count is static (``ceil(prompt_bucket /
+        block_size)``), so this stays ONE compiled program; rows whose
+        allocation is shorter than the bucket carry null-block table entries
+        there, harmlessly absorbing the extra writes. Shared (COW) prefix
+        blocks are re-written with bitwise identical content — see the
+        module docstring invariants."""
+        n, bs = self.prefill_blocks, self.block_size
+        ids = table_row[:n]
         out = {}
         for which in ("k", "v"):
             pool = cache[which]
-            fresh = new_cache[which][:, 0]  # (L, max_len, kvh, hd)
-            for j in range(self.prefill_blocks):
-                blk = fresh[:, j * bs:(j + 1) * bs]  # (L, bs, kvh, hd)
-                bid = table_row[j]
-                if self.quantized:
-                    q, s = kv_quantize(blk)
-                    pool = {
-                        "q": lax.dynamic_update_slice(
-                            pool["q"], q[:, None], (0, bid, 0, 0, 0)
-                        ),
-                        "s": lax.dynamic_update_slice(
-                            pool["s"], s[:, None], (0, bid, 0)
-                        ),
-                    }
-                else:
-                    pool = lax.dynamic_update_slice(
-                        pool, blk[:, None].astype(pool.dtype), (0, bid, 0, 0, 0)
-                    )
-            out[which] = pool
+            fresh = new_cache[which][:, 0, : n * bs]  # (L, n * bs, kvh, hd)
+            fresh = fresh.reshape(fresh.shape[0], n, bs, *fresh.shape[2:])
+            if self.quantized:
+                q, s = kv_quantize(fresh)
+                out[which] = {
+                    "q": pool["q"].at[:, ids].set(_merge_heads(q)),
+                    "s": pool["s"].at[:, ids].set(s),
+                }
+            else:
+                out[which] = pool.at[:, ids].set(
+                    _merge_heads(fresh).astype(pool.dtype)
+                )
         return out
 
     def commit_window(self, cache, window_kv, tables, pos, count):
@@ -1183,8 +1204,8 @@ def pool_from_dense(cache, block_size: int, quantized: bool):
         pool = dense.reshape(L, b * nb, block_size, kvh, hd)
         if quantized:
             q, s = kv_quantize(pool)
-            return {"q": q, "s": s}
-        return pool
+            return {"q": _merge_heads(q), "s": s}
+        return _merge_heads(pool)
     k = relay(cache["k"])
     v = relay(cache["v"])
     b = cache["k"].shape[1]

@@ -33,6 +33,7 @@ from .llama import (
     _pallas_decode_override,
     _pallas_verify_override,
     _remat_policy,
+    _scan_layers_over_pool,
     _use_pallas_attention,
     _write_kv_at,
     _write_kv_window,
@@ -431,8 +432,9 @@ def gpt2_decode_step(config: GPT2Config, params, cache, token, pos, *,
     """One decode step: token (B, 1) at traced position ``pos`` (scalar, or
     (B,) per-row positions for continuous-batching slots) → (logits (B, V),
     new cache). Same contract as llama_decode_step, including the optional
-    paged ``kv_layout`` (per-layer pool slices gathered to a dense view
-    before the layer attends, new column committed back after)."""
+    paged ``kv_layout`` (the layer loop carries the pool whole; a layer's
+    blocks are gathered to a dense view before it attends and the new column
+    committed back after, or read in place by the Pallas kernel)."""
     cdt = config.compute_dtype
     x = params["wte"]["embedding"].astype(cdt)[token]
     wpe = params["wpe"]["embedding"].astype(cdt)
@@ -443,26 +445,31 @@ def gpt2_decode_step(config: GPT2Config, params, cache, token, pos, *,
 
     pallas = _use_pallas_attention(config, kv_layout)
 
-    def body(x, inputs):
-        lp, ck, cv = inputs
+    def paged_step(x, lp, ck, cv, layer):
         if pallas:
-            override = _pallas_decode_override(config, kv_layout, pos, ck, cv)
-            x, ck, cv = _gpt2_decode_layer(config, lp, x, None, None, pos,
-                                           attention_override=override)
-            return x, (ck, cv)
-        if kv_layout is not None:
-            ck_pool, cv_pool = ck, cv
-            ck, cv = kv_layout.view(ck), kv_layout.view(cv)
+            override = _pallas_decode_override(config, kv_layout, pos, ck, cv, layer)
+            return _gpt2_decode_layer(config, lp, x, None, None, pos,
+                                      attention_override=override)
+        x, vk, vv = _gpt2_decode_layer(
+            config, lp, x, kv_layout.view(ck, layer), kv_layout.view(cv, layer), pos
+        )
+        return x, kv_layout.commit(ck, vk, pos, layer), kv_layout.commit(cv, vv, pos, layer)
+
+    def dense_body(x, inputs):
+        lp, ck, cv = inputs
         x, ck, cv = _gpt2_decode_layer(config, lp, x, ck, cv, pos)
-        if kv_layout is not None:
-            ck = kv_layout.commit(ck_pool, ck, pos)
-            cv = kv_layout.commit(cv_pool, cv, pos)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    if kv_layout is not None:
+        x, new_cache = _scan_layers_over_pool(paged_step, x, cache, params["layers"])
+    else:
+        x, (new_k, new_v) = lax.scan(
+            dense_body, x, (params["layers"], cache["k"], cache["v"])
+        )
+        new_cache = {"k": new_k, "v": new_v}
     x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"], config.layer_norm_eps)
     logits = x @ params["wte"]["embedding"].astype(cdt).T
-    return logits[:, 0].astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits[:, 0].astype(jnp.float32), new_cache
 
 
 def _gpt2_verify_layer(config: GPT2Config, lp, x, cache_k, cache_v, pos,
@@ -524,19 +531,33 @@ def gpt2_verify_step(config: GPT2Config, params, cache, tokens, pos, *,
 
     pallas = _use_pallas_attention(config, kv_layout)
 
-    def body(x, inputs):
-        lp, ck, cv = inputs
+    def paged_body(x, inputs):
+        # the pool is only read here: a loop invariant the body closes over,
+        # addressed by layer like the decode step's (never sliced as xs)
+        lp, layer = inputs
+        ck, cv = cache["k"], cache["v"]
         if pallas:
-            override = _pallas_verify_override(config, kv_layout, pos, ck, cv)
+            override = _pallas_verify_override(config, kv_layout, pos, ck, cv, layer)
             x, wk, wv = _gpt2_verify_layer(config, lp, x, None, None, pos,
                                            attention_override=override)
-            return x, (wk, wv)
-        if kv_layout is not None:
-            ck, cv = kv_layout.view(ck), kv_layout.view(cv)
+        else:
+            x, wk, wv = _gpt2_verify_layer(
+                config, lp, x, kv_layout.view(ck, layer), kv_layout.view(cv, layer), pos
+            )
+        return x, (wk, wv)
+
+    def dense_body(x, inputs):
+        lp, ck, cv = inputs
         x, wk, wv = _gpt2_verify_layer(config, lp, x, ck, cv, pos)
         return x, (wk, wv)
 
-    x, (win_k, win_v) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    if kv_layout is not None:
+        layers = jnp.arange(config.num_hidden_layers, dtype=jnp.int32)
+        x, (win_k, win_v) = lax.scan(paged_body, x, (params["layers"], layers))
+    else:
+        x, (win_k, win_v) = lax.scan(
+            dense_body, x, (params["layers"], cache["k"], cache["v"])
+        )
     x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"], config.layer_norm_eps)
     logits = x @ params["wte"]["embedding"].astype(cdt).T
     return logits.astype(jnp.float32), {"k": win_k, "v": win_v}

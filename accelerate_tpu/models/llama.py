@@ -1554,49 +1554,79 @@ def _use_pallas_attention(config, kv_layout) -> bool:
     )
 
 
+def _sliding_flags(config) -> tuple:
+    """The per-layer ``sliding`` xs of the decode and verify scans: one
+    array, even layers local (HF layer_types), for alternating
+    sliding-window configs; nothing otherwise."""
+    if not config.alternating_sliding_window:
+        return ()
+    return ((jnp.arange(config.num_hidden_layers) % 2) == 0,)
+
+
+def _scan_layers_over_pool(layer_step, x, cache, layers, *flags):
+    """The decode layer loop of a paged cache: the pool rides in the carry
+    beside ``x``, whole, and the scan steps over ``(layer params, layer
+    index[, flags])`` — so the program's donated pool is updated in place
+    and handed back, never cut into per-layer slices and stacked again
+    (kvcache.py, "The pool's layout"). ``layer_step(x, layer_params, ck, cv,
+    layer, *flags)`` returns ``(x, ck, cv)`` with ``ck`` / ``cv`` the whole
+    pools."""
+    def body(carry, inputs):
+        x, ck, cv = carry
+        layer_params, layer, *rest = inputs
+        return layer_step(x, layer_params, ck, cv, layer, *rest), None
+
+    n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    (x, ck, cv), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (layers, jnp.arange(n_layers, dtype=jnp.int32), *flags),
+    )
+    return x, {"k": ck, "v": cv}
+
+
 def _pallas_attn_scale(config) -> float:
     return float(
         1.0 / np.sqrt(getattr(config, "query_pre_attn_scalar", None) or config.head_dim)
     )
 
 
-def _pallas_decode_override(config, kv_layout, pos, ck_pool, cv_pool):
+def _pallas_decode_override(config, kv_layout, pos, ck_pool, cv_pool, layer):
     """Decode-step attention override: commit the rope-rotated new K/V
-    column into the pool FIRST (``commit_column`` — no dense view), then
-    run the flash-decode kernel over the block tables. Store→load identity
-    makes this exact in f32; int8 pools pay one bounded quantization on
-    the current column (the same 4e-3·amax bound as every other committed
-    position)."""
+    column into the whole pool at ``layer`` FIRST (``commit_column`` — no
+    dense view), then run the flash-decode kernel over that layer's blocks
+    of it. Store→load identity makes this exact in f32; int8 pools pay one
+    bounded quantization on the current column (the same 4e-3·amax bound as
+    every other committed position)."""
     from ..ops.paged_decode import paged_flash_decode
 
     attn_scale = _pallas_attn_scale(config)
     softcap = getattr(config, "attn_logit_softcap", None)
 
     def override(q, k_new, v_new):
-        ck = kv_layout.commit_column(ck_pool, k_new, pos)
-        cv = kv_layout.commit_column(cv_pool, v_new, pos)
+        ck = kv_layout.commit_column(ck_pool, k_new, pos, layer)
+        cv = kv_layout.commit_column(cv_pool, v_new, pos, layer)
         p = pos if jnp.ndim(pos) != 0 else jnp.broadcast_to(pos, (q.shape[0],))
         if isinstance(ck, dict):
             out = paged_flash_decode(
                 q, ck["q"], cv["q"], kv_layout.tables, p,
                 k_scale=ck["s"], v_scale=cv["s"],
-                scale=attn_scale, softcap=softcap,
+                scale=attn_scale, softcap=softcap, layer=layer,
             )
         else:
             out = paged_flash_decode(
                 q, ck, cv, kv_layout.tables, p,
-                scale=attn_scale, softcap=softcap,
+                scale=attn_scale, softcap=softcap, layer=layer,
             )
         return out, ck, cv
 
     return override
 
 
-def _pallas_verify_override(config, kv_layout, pos, ck_pool, cv_pool):
+def _pallas_verify_override(config, kv_layout, pos, ck_pool, cv_pool, layer):
     """Verify-step attention override: the kernel walks committed history
-    in the pool (strictly ``k_pos < pos``) and attends the fresh window
-    K/V in-register — read-only on the pool, commit-after-accept stays
-    with the engine."""
+    in ``layer``'s blocks of the pool (strictly ``k_pos < pos``) and attends
+    the fresh window K/V in-register — read-only on the pool,
+    commit-after-accept stays with the engine."""
     from ..ops.paged_decode import paged_flash_verify
 
     attn_scale = _pallas_attn_scale(config)
@@ -1608,11 +1638,11 @@ def _pallas_verify_override(config, kv_layout, pos, ck_pool, cv_pool):
                 q, ck_pool["q"], cv_pool["q"], k_win, v_win,
                 kv_layout.tables, pos,
                 k_scale=ck_pool["s"], v_scale=cv_pool["s"],
-                scale=attn_scale, softcap=softcap,
+                scale=attn_scale, softcap=softcap, layer=layer,
             )
         return paged_flash_verify(
             q, ck_pool, cv_pool, k_win, v_win, kv_layout.tables, pos,
-            scale=attn_scale, softcap=softcap,
+            scale=attn_scale, softcap=softcap, layer=layer,
         )
 
     return override
@@ -1626,60 +1656,51 @@ def llama_decode_step(config: LlamaConfig, params, cache, token, pos, *,
     Returns (logits (B, V), new cache).
 
     ``kv_layout`` (a :class:`~accelerate_tpu.kvcache.PagedKVLayout`) swaps
-    the KV store for a paged block pool: ``cache`` leaves are per-layer pool
-    slices the scan carries, gathered into the dense per-slot view right
-    before the layer attends and committed back as one scattered column
-    after. ``None`` keeps the dense arena path byte-for-byte unchanged."""
+    the KV store for a paged block pool: ``cache`` leaves are the whole pool,
+    which the layer loop carries beside ``x`` and each layer reaches by its
+    index (:func:`_scan_layers_over_pool`) — its blocks gathered into the
+    dense per-slot view right before the layer attends and the new column
+    scattered back after, or the column committed first and the Pallas
+    kernel run over the pool in place. ``None`` keeps the dense arena path
+    byte-for-byte unchanged."""
     cdt = config.compute_dtype
     x = params["embed_tokens"]["embedding"].astype(cdt)[token]
     if config.scale_embeddings:
         x = x * jnp.asarray(config.hidden_size**0.5, dtype=cdt)
 
     pallas = _use_pallas_attention(config, kv_layout)
+    flags = _sliding_flags(config)
 
-    def layer_step(x, layer_params, ck, cv, sliding=None):
+    def paged_step(x, layer_params, ck, cv, layer, sliding=None):
         if pallas:
-            override = _pallas_decode_override(config, kv_layout, pos, ck, cv)
+            override = _pallas_decode_override(config, kv_layout, pos, ck, cv, layer)
             return _decode_layer(config, layer_params, x, None, None, pos,
                                  sliding=sliding, attention_override=override)
-        if kv_layout is not None:
-            ck_pool, cv_pool = ck, cv
-            ck, cv = kv_layout.view(ck), kv_layout.view(cv)
-        x, ck, cv = _decode_layer(config, layer_params, x, ck, cv, pos,
-                                  sliding=sliding)
-        if kv_layout is not None:
-            ck = kv_layout.commit(ck_pool, ck, pos)
-            cv = kv_layout.commit(cv_pool, cv, pos)
-        return x, ck, cv
-
-    if config.alternating_sliding_window:
-        L = config.num_hidden_layers
-        flags = (jnp.arange(L) % 2) == 0  # even layers local (HF layer_types)
-
-        def body(carry, inputs):
-            x = carry
-            layer_params, ck, cv, sliding = inputs
-            x, ck, cv = layer_step(x, layer_params, ck, cv, sliding=sliding)
-            return x, (ck, cv)
-
-        x, (new_k, new_v) = lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"], flags)
+        x, vk, vv = _decode_layer(
+            config, layer_params, x, kv_layout.view(ck, layer),
+            kv_layout.view(cv, layer), pos, sliding=sliding,
         )
-    else:
-        def body(carry, inputs):
-            x = carry
-            layer_params, ck, cv = inputs
-            x, ck, cv = layer_step(x, layer_params, ck, cv)
-            return x, (ck, cv)
+        return x, kv_layout.commit(ck, vk, pos, layer), kv_layout.commit(cv, vv, pos, layer)
 
-        x, (new_k, new_v) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    def dense_body(x, inputs):
+        layer_params, ck, cv, *sliding = inputs
+        x, ck, cv = _decode_layer(config, layer_params, x, ck, cv, pos, *sliding)
+        return x, (ck, cv)
+
+    if kv_layout is not None:
+        x, new_cache = _scan_layers_over_pool(paged_step, x, cache, params["layers"], *flags)
+    else:
+        x, (new_k, new_v) = lax.scan(
+            dense_body, x, (params["layers"], cache["k"], cache["v"], *flags)
+        )
+        new_cache = {"k": new_k, "v": new_v}
     x = rms_norm(x, params["final_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
     if config.tie_word_embeddings:
         logits = x @ params["embed_tokens"]["embedding"].astype(cdt).T
     else:
         logits = x @ params["lm_head"]["kernel"].astype(cdt)
     logits = _tanh_softcap(logits, config.final_logit_softcap)
-    return logits[:, 0].astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits[:, 0].astype(jnp.float32), new_cache
 
 
 def llama_verify_step(config: LlamaConfig, params, cache, tokens, pos, *,
@@ -1689,52 +1710,51 @@ def llama_verify_step(config: LlamaConfig, params, cache, tokens, pos, *,
     (``pos`` a traced (B,) vector). Returns (logits (B, W, V) f32,
     window KV {"k","v"}: (L, B, W, kvh, hd)).
 
-    The cache is consumed READ-ONLY (scan xs, not donated-through): nothing
-    is committed here. The caller decides the accepted prefix from the
-    logits and commits exactly that many window columns via the backend's
-    ``commit_window`` — so a rejected draft suffix never touches the
-    persistent arena/pool and there is no rollback path. With
-    ``kv_layout`` the per-layer pool slice is gathered into the dense view
-    first (same as decode), and the window attends a temporary copy of
-    that view."""
+    The cache is consumed READ-ONLY (the dense arena as scan xs, the paged
+    pool as a loop invariant each layer indexes): nothing is committed
+    here. The caller decides the accepted prefix from the logits and commits
+    exactly that many window columns via the backend's ``commit_window`` —
+    so a rejected draft suffix never touches the persistent arena/pool and
+    there is no rollback path. With ``kv_layout`` the layer's blocks of the
+    pool are gathered into the dense view first (same as decode), and the
+    window attends a temporary copy of that view."""
     cdt = config.compute_dtype
     x = params["embed_tokens"]["embedding"].astype(cdt)[tokens]
     if config.scale_embeddings:
         x = x * jnp.asarray(config.hidden_size**0.5, dtype=cdt)
 
     pallas = _use_pallas_attention(config, kv_layout)
+    flags = _sliding_flags(config)
 
-    def layer_verify(x, layer_params, ck, cv, sliding=None):
+    def paged_body(x, inputs):
+        # the pool is only read here: a loop invariant the body closes over,
+        # addressed by layer like the decode step's (never sliced as xs)
+        layer_params, layer, *sliding = inputs
+        ck, cv = cache["k"], cache["v"]
         if pallas:
-            override = _pallas_verify_override(config, kv_layout, pos, ck, cv)
-            return _verify_layer(config, layer_params, x, None, None, pos,
-                                 sliding=sliding, attention_override=override)
-        if kv_layout is not None:
-            ck, cv = kv_layout.view(ck), kv_layout.view(cv)
-        return _verify_layer(config, layer_params, x, ck, cv, pos,
-                             sliding=sliding)
+            override = _pallas_verify_override(config, kv_layout, pos, ck, cv, layer)
+            x, wk, wv = _verify_layer(config, layer_params, x, None, None, pos,
+                                      *sliding, attention_override=override)
+        else:
+            x, wk, wv = _verify_layer(
+                config, layer_params, x, kv_layout.view(ck, layer),
+                kv_layout.view(cv, layer), pos, *sliding,
+            )
+        return x, (wk, wv)
 
-    if config.alternating_sliding_window:
-        L = config.num_hidden_layers
-        flags = (jnp.arange(L) % 2) == 0  # even layers local (HF layer_types)
+    def dense_body(x, inputs):
+        layer_params, ck, cv, *sliding = inputs
+        x, wk, wv = _verify_layer(config, layer_params, x, ck, cv, pos, *sliding)
+        return x, (wk, wv)
 
-        def body(carry, inputs):
-            x = carry
-            layer_params, ck, cv, sliding = inputs
-            x, wk, wv = layer_verify(x, layer_params, ck, cv, sliding=sliding)
-            return x, (wk, wv)
-
-        x, (win_k, win_v) = lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"], flags)
-        )
+    if kv_layout is not None:
+        layers = jnp.arange(config.num_hidden_layers, dtype=jnp.int32)
+        xs = (params["layers"], layers, *flags)
+        x, (win_k, win_v) = lax.scan(paged_body, x, xs)
     else:
-        def body(carry, inputs):
-            x = carry
-            layer_params, ck, cv = inputs
-            x, wk, wv = layer_verify(x, layer_params, ck, cv)
-            return x, (wk, wv)
-
-        x, (win_k, win_v) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+        x, (win_k, win_v) = lax.scan(
+            dense_body, x, (params["layers"], cache["k"], cache["v"], *flags)
+        )
     x = rms_norm(x, params["final_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
     if config.tie_word_embeddings:
         logits = x @ params["embed_tokens"]["embedding"].astype(cdt).T

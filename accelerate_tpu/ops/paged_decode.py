@@ -99,13 +99,13 @@ def _online_softmax_update(g, s, v, acc_ref, m_ref, l_ref):
     m_ref[g] = m_cur
 
 
-def _load_kv_head(k_ref, v_ref, g, scales):
+def _load_kv_head(k_ref, v_ref, g, d, scales):
     """KV head ``g`` of the fetched pool block as two (bs, d) tiles. The
-    block holds every KV head — ``(1, bs, h_kv, d)``, the only tile of the
-    position-major pool whose last two dimensions the TPU lowering accepts
-    (they equal the array's) — so one head is a static strided read."""
-    k = k_ref[0, :, g, :]
-    v = v_ref[0, :, g, :]
+    block holds every KV head side by side on the lane axis — ``(1, bs,
+    h_kv * d)``, the pool's own row-major tile (kvcache.py, "The pool's
+    layout") — so one head is a static slice of lanes."""
+    k = k_ref[0, :, g * d:(g + 1) * d]
+    v = v_ref[0, :, g * d:(g + 1) * d]
     if scales is not None:
         ks_ref, vs_ref = scales  # (1, 1, bs, 1): this block's scale column
         k = k.astype(jnp.float32) * ks_ref[0, 0]
@@ -114,7 +114,7 @@ def _load_kv_head(k_ref, v_ref, g, scales):
 
 
 def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   block_size, h_kv, scale, softcap, quantized):
+                   block_size, h_kv, d, scale, softcap, quantized):
     if quantized:
         *scales, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -142,7 +142,7 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     def _compute():
         for g in range(h_kv):
             q = q_ref[0, g]  # (n_rep, d) — the kv head's whole GQA group
-            k, v = _load_kv_head(k_ref, v_ref, g, scales)
+            k, v = _load_kv_head(k_ref, v_ref, g, d, scales)
             s = _dot_f32(q, k, transpose_b=True) * scale  # (n_rep, bs), f32
             if softcap is not None:  # Gemma-2 tanh capping, pre-mask
                 s = softcap * jnp.tanh(s / softcap)
@@ -155,8 +155,37 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _flat_pools(pools, scales, block_tables, layer, h, d):
+    """The kernels' view of the pools: each ``(blocks, bs, h_kv * d)``, the
+    scales ``(blocks, bs)``, and block tables that index them. Returns
+    ``(pools, scales, tables, h_kv, bs)``.
+
+    One layer's pool ``(num_blocks, bs, ...)`` is taken as it is. With
+    ``layer`` (a traced scalar) a pool is every layer's, ``(L, num_blocks,
+    bs, ...)``: it is flattened over its two leading axes — no bytes move in
+    the row-major layout it lives in — and the layer is reached through the
+    tables, ``tables + layer * num_blocks``, so a layer loop that carries
+    the pool whole never slices it. A pool's head axes may come apart
+    ``(h_kv, d)`` or merged into one; the scales follow the pool's leading
+    axes."""
+    lead = 1 if layer is None else 2
+    shape = pools[0].shape
+    bs, lanes = shape[lead], math.prod(shape[lead + 1:])
+    if lanes % d != 0 or h % (lanes // d) != 0:
+        raise ValueError(
+            f"a pool of shape {shape} does not hold kv heads of {d} that "
+            f"divide {h} query heads"
+        )
+    tables = block_tables.astype(jnp.int32)
+    if layer is not None:
+        tables = tables + jnp.asarray(layer, jnp.int32) * shape[1]
+    pools = [pool.reshape(-1, bs, lanes) for pool in pools]
+    scales = [None if s is None else s.reshape(-1, bs) for s in scales]
+    return pools, scales, tables, lanes // d, bs
+
+
 def _gathered_scale_columns(scale, block_tables):
-    """Per-slot scale columns for the kernels: ``scale`` (num_blocks, bs) →
+    """Per-slot scale columns for the kernels: ``scale`` (blocks, bs) →
     (B, blocks_per_row, bs, 1), gathered through the tables by XLA. A
     ``(1, bs)`` row of the pool-shaped array is not a tile the TPU lowering
     accepts; this copy is 4 bytes a position beside the ``2 * h_kv * d`` the
@@ -175,6 +204,7 @@ def paged_flash_decode(
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
+    layer: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Single-token paged decode attention as a Pallas flash kernel.
@@ -184,6 +214,10 @@ def paged_flash_decode(
     ``k_pool``/``v_pool`` (num_blocks, block_size, h_kv, d) — int8 with
     ``k_scale``/``v_scale`` (num_blocks, block_size) — ``block_tables``
     (B, blocks_per_row) int32, ``pos`` (B,) int32. Returns (B, 1, h, d).
+    With ``layer``, a traced scalar, the pools (and scales) are every
+    layer's, stacked on a leading axis, and the kernel reads that layer's
+    blocks of them in place (:func:`_flat_pools`); the pool's two head axes
+    may be merged into one of ``h_kv * d``, the form the engine stores.
 
     HBM bytes per step are ``live_blocks * block_size * h_kv * d *
     itemsize * 2`` (+ scales) instead of the reference gather's
@@ -199,9 +233,9 @@ def paged_flash_decode(
     b, sq, h, d = q.shape
     if sq != 1:
         raise ValueError(f"paged_flash_decode takes one query token, got {sq}")
-    nb_pool, bs, h_kv, _ = k_pool.shape
-    if h % h_kv != 0:
-        raise ValueError(f"num heads {h} not divisible by kv heads {h_kv}")
+    (k_pool, v_pool), (k_scale, v_scale), block_tables, h_kv, bs = _flat_pools(
+        (k_pool, v_pool), (k_scale, v_scale), block_tables, layer, h, d
+    )
     n_rep = h // h_kv
     bpr = block_tables.shape[1]
     if scale is None:
@@ -211,7 +245,7 @@ def paged_flash_decode(
 
     qg = q.reshape(b, h_kv, n_rep, d)
     q_spec = pl.BlockSpec((1, h_kv, n_rep, d), lambda bb, j, t, p: (bb, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, h_kv, d), lambda bb, j, t, p: (t[bb, j], 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, h_kv * d), lambda bb, j, t, p: (t[bb, j], 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [qg, k_pool, v_pool]
     if quantized:
@@ -235,20 +269,20 @@ def paged_flash_decode(
     )
     out = pl.pallas_call(
         functools.partial(
-            _decode_kernel, block_size=bs, h_kv=h_kv, scale=scale,
+            _decode_kernel, block_size=bs, h_kv=h_kv, d=d, scale=scale,
             softcap=softcap, quantized=quantized,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h_kv, n_rep, d), q.dtype),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), *args)
+    )(block_tables, pos.astype(jnp.int32), *args)
     return out.reshape(b, 1, h, d)
 
 
 # ------------------------------------------------------------ verify kernel
 def _verify_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, wk_ref, wv_ref,
-                   *rest, block_size, h_kv, w, n_rep, scale, softcap, quantized):
+                   *rest, block_size, h_kv, d, w, n_rep, scale, softcap, quantized):
     if quantized:
         *scales, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -281,7 +315,7 @@ def _verify_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, wk_ref, wv_ref,
     @pl.when((j < nj - 1) & (j * block_size < p))
     def _history():
         for g in range(h_kv):
-            k, v = _load_kv_head(k_ref, v_ref, g, scales)
+            k, v = _load_kv_head(k_ref, v_ref, g, d, scales)
             s = _scores(q_ref[0, g], k)  # (rows, bs)
             k_pos = j * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(k_pos < p, s, NEG_INF)
@@ -320,6 +354,7 @@ def paged_flash_verify(
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
+    layer: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Speculative-verify window attention as a Pallas flash kernel.
@@ -331,12 +366,13 @@ def paged_flash_verify(
     (B, W, h_kv, d) operands attended causally in-register. Together that
     reproduces :func:`~accelerate_tpu.ops.attention.verify_attention`'s
     ``k_pos <= pos + q_idx`` mask without the reference path's
-    scatter-write of a temporary dense view. Returns (B, W, h, d).
+    scatter-write of a temporary dense view. ``layer`` addresses stacked
+    pools as in :func:`paged_flash_decode`. Returns (B, W, h, d).
     """
     b, w, h, d = q.shape
-    nb_pool, bs, h_kv, _ = k_pool.shape
-    if h % h_kv != 0:
-        raise ValueError(f"num heads {h} not divisible by kv heads {h_kv}")
+    (k_pool, v_pool), (k_scale, v_scale), block_tables, h_kv, bs = _flat_pools(
+        (k_pool, v_pool), (k_scale, v_scale), block_tables, layer, h, d
+    )
     n_rep = h // h_kv
     bpr = block_tables.shape[1]
     rows = n_rep * w
@@ -355,7 +391,7 @@ def paged_flash_verify(
 
     q_spec = pl.BlockSpec((1, h_kv, rows, d), lambda bb, j, t, p: (bb, 0, 0, 0))
     kv_spec = pl.BlockSpec(
-        (1, bs, h_kv, d), lambda bb, j, t, p: (_pool_block(bb, j, t), 0, 0, 0)
+        (1, bs, h_kv * d), lambda bb, j, t, p: (_pool_block(bb, j, t), 0, 0)
     )
     win_spec = pl.BlockSpec((1, w, h_kv, d), lambda bb, j, t, p: (bb, 0, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec, win_spec, win_spec]
@@ -383,14 +419,14 @@ def paged_flash_verify(
     )
     out = pl.pallas_call(
         functools.partial(
-            _verify_kernel, block_size=bs, h_kv=h_kv, w=w, n_rep=n_rep,
+            _verify_kernel, block_size=bs, h_kv=h_kv, d=d, w=w, n_rep=n_rep,
             scale=scale, softcap=softcap, quantized=quantized,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h_kv, rows, d), q.dtype),
         interpret=interpret,
         name="paged_verify",
-    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), *args)
+    )(block_tables, pos.astype(jnp.int32), *args)
     out = out.reshape(b, h_kv, n_rep, w, d).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, w, h, d)
 

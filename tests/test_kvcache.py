@@ -462,3 +462,109 @@ def test_generate_paged_backends_match_dense(model):
     np.testing.assert_array_equal(int8_a[:, :9], ids)
     with pytest.raises(ValueError, match="kv_backend"):
         generate(model, ids, max_new_tokens=4, kv_backend="dense8")
+
+
+# ------------------------------------- the pool goes through a program whole
+def _tiny_pallas_engine(family):
+    from accelerate_tpu.models.gpt2 import GPT2Config, create_gpt2
+
+    model = (create_gpt2(GPT2Config.tiny(), seed=0) if family == "gpt2"
+             else create_llama(LlamaConfig.tiny(), seed=0))
+
+    def engine(attention_impl):
+        return ContinuousBatchingEngine(
+            model, slots=2, max_len=32, prompt_bucket=16, kv_cache="paged",
+            block_size=8, attention_impl=attention_impl,
+        )
+
+    return model, engine
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_decode_program_never_slices_the_pool_by_layer(family):
+    """The structure PR 28 bought (kvcache.py, "The pool's layout"): in the
+    engine's decode program the layer loop carries the pool whole. No
+    operation cuts one layer's slice out of it, writes one back, transposes
+    or copies one, and the donated pool is the result's own buffer. On the
+    chip each of these was a copy that scaled with the pool, every layer of
+    every step."""
+    import re
+
+    model, engine = _tiny_pallas_engine(family)
+    eng = engine("pallas")
+    pool = eng._donated["cache"]["k"]
+    layers, blocks, block_size = pool.shape[:3]
+    assert pool.ndim == 4 and layers == model.config.num_hidden_layers
+    lowered = eng._decode_jit.lower(
+        eng._donated, eng._carried, model.params, eng._backend.device_tables()
+    )
+    # StableHLO: ops of the traced program whose result is a layer's slice
+    slice_type = rf"-> tensor<(1x)?{blocks}x{block_size}x[0-9x]*(bf16|f32|i8)>"
+    cuts = [
+        line.strip() for line in lowered.as_text().splitlines()
+        if re.search(r"dynamic_slice|dynamic_update_slice|transpose", line)
+        and re.search(slice_type, line)
+    ]
+    assert not cuts, cuts[:3]
+    # the compiled program: nothing at all of a layer slice's shape (the
+    # compiler's own copies included), and the pool aliased to the result
+    compiled = lowered.compile().as_text()
+    slice_shape = rf"= \w+\[(1,)?{blocks},{block_size},[0-9,]*\]"
+    made = [line.strip() for line in compiled.splitlines() if re.search(slice_shape, line)]
+    assert not made, made[:3]
+    header = compiled.split("\n", 1)[0]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", header).group(1)
+    # parameters 0 and 1 are the donated K and V pools (the first leaves of
+    # the first argument); each is some output's buffer
+    assert re.search(r"\(0, \{\}, may-alias\)", aliases), aliases
+    assert re.search(r"\(1, \{\}, may-alias\)", aliases), aliases
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_engine_pallas_vs_reference_paged_bitwise_on_a_fixed_script(family):
+    """The same requests in the same order through the paged engine with the
+    Pallas kernels and with the reference op: greedy and sampled rows, two
+    waves so that the second decodes into recycled blocks. Token for token
+    the same."""
+    _, engine = _tiny_pallas_engine(family)
+
+    def run(eng):
+        out = []
+        for wave in (1, 2):
+            prompts = _prompts(2, seed=wave)
+            occs = [
+                eng.insert(prompts[0], max_new_tokens=6, pad_token_id=0, tag=0),
+                eng.insert(prompts[1], max_new_tokens=9, pad_token_id=0, tag=1,
+                           temperature=0.8, top_k=5, top_p=0.9, seed=wave),
+            ]
+            eng.drain()
+            out += [occ.output_row().tolist() for occ in occs]
+        assert eng.stats()["programs"] == {"prefill_insert": 1, "decode_step": 1}
+        return out
+
+    pallas, reference = run(engine("pallas")), run(engine("reference"))
+    assert pallas == reference
+
+
+def test_alternating_sliding_window_paged_matches_dense_bitwise():
+    """Gemma-2-style alternating local/global layers fall back to the
+    reference paged path, whose layer loop carries the pool whole with the
+    per-layer ``sliding`` flag beside the layer index, in the decode and the
+    verify program: tokens equal the dense arena's."""
+    cfg = LlamaConfig.tiny(
+        compute_dtype=jnp.float32, sliding_window=8, alternating_sliding_window=True
+    )
+    model = create_llama(cfg, seed=0)
+    prompts = _prompts(2, lens=(5, 12), seed=0)
+    prompts[1] = prompts[1][:4] * 3  # repetitive: the n-gram drafter fires
+    outs = {}
+    for kv_cache in ("dense", "paged"):
+        eng = ContinuousBatchingEngine(
+            model, slots=2, max_len=32, prompt_bucket=16, kv_cache=kv_cache,
+            block_size=8, spec="ngram",
+        )
+        occs = [eng.insert(p, max_new_tokens=12, pad_token_id=0) for p in prompts]
+        eng.drain()
+        assert eng.stats()["programs"]["verify_step"] == 1
+        outs[kv_cache] = [occ.output_row().tolist() for occ in occs]
+    assert outs["paged"] == outs["dense"]

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from accelerate_tpu.engine import _sample_rows
+from accelerate_tpu.kvcache import PagedKVLayout
 from accelerate_tpu.ops.attention import paged_attention, verify_attention
 from accelerate_tpu.ops.paged_decode import (
     fused_sample,
@@ -173,3 +174,131 @@ def test_fused_sample_greedy_is_raw_argmax():
     assert np.array_equal(
         np.asarray(out), np.asarray(jnp.argmax(logits, axis=-1))
     )
+
+
+# ------------------------------------------------- layered addressing (PR 28)
+# The engine hands the kernels and PagedKVLayout's ops the whole pool
+# (L, num_blocks, bs, kvh * hd) and a traced layer; each must equal, bitwise,
+# the same call on that layer's slice.
+LAYERS = 3
+
+
+def _stacked(kind, n_rep, seed):
+    """``(q, k, v, scales or None, tables)``: stacked pools with the head axes
+    merged, as the engine stores them; ``kind`` is bf16 or int8."""
+    rng = np.random.default_rng(seed)
+    h = HKV * n_rep
+    q = jnp.asarray(rng.normal(size=(B, 1, h, D)), jnp.bfloat16)
+    shape = (LAYERS, NB, BS, HKV * D)
+    if kind == "int8":
+        k = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        scales = tuple(
+            jnp.asarray(rng.uniform(1e-3, 2e-2, size=shape[:3]), jnp.float32)
+            for _ in range(2)
+        )
+    else:
+        k = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+        v = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+        scales = None
+    tables = jnp.asarray(rng.integers(1, NB, size=(B, BPR)), jnp.int32)
+    return q, k, v, scales, tables
+
+
+def _scale_kwargs(scales, layer=None):
+    if scales is None:
+        return {}
+    ks, vs = scales
+    return {"k_scale": ks if layer is None else ks[layer],
+            "v_scale": vs if layer is None else vs[layer]}
+
+
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("n_rep", [1, 4])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_decode_over_stacked_pool_equals_layer_slice(kind, n_rep, layer):
+    q, k, v, scales, tables = _stacked(kind, n_rep, seed=20 + layer)
+    pos = jnp.asarray([0, 5, BPR * BS - 1], jnp.int32)
+    whole = jax.jit(
+        lambda layer: paged_flash_decode(
+            q, k, v, tables, pos, layer=layer, interpret=True, **_scale_kwargs(scales)
+        )
+    )(jnp.int32(layer))
+    one = paged_flash_decode(
+        q, k[layer], v[layer], tables, pos, interpret=True,
+        **_scale_kwargs(scales, layer),
+    )
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(one))
+    # the slice with its head axes apart, as chip_smoke.py and the kernel
+    # validation hand it over, is the same pool
+    apart = paged_flash_decode(
+        q, k[layer].reshape(NB, BS, HKV, D), v[layer].reshape(NB, BS, HKV, D),
+        tables, pos, interpret=True, **_scale_kwargs(scales, layer),
+    )
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(apart))
+
+
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("n_rep", [1, 4])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_verify_over_stacked_pool_equals_layer_slice(kind, n_rep, layer):
+    _, k, v, scales, tables = _stacked(kind, n_rep, seed=40 + layer)
+    rng = np.random.default_rng(60 + layer)
+    w, h = 3, HKV * n_rep
+    qw = jnp.asarray(rng.normal(size=(B, w, h, D)), jnp.bfloat16)
+    wk = jnp.asarray(rng.normal(size=(B, w, HKV, D)), jnp.bfloat16)
+    wv = jnp.asarray(rng.normal(size=(B, w, HKV, D)), jnp.bfloat16)
+    pos = jnp.asarray([0, 6, BPR * BS - 3], jnp.int32)
+    whole = jax.jit(
+        lambda layer: paged_flash_verify(
+            qw, k, v, wk, wv, tables, pos, layer=layer, interpret=True,
+            **_scale_kwargs(scales),
+        )
+    )(jnp.int32(layer))
+    one = paged_flash_verify(
+        qw, k[layer], v[layer], wk, wv, tables, pos, interpret=True,
+        **_scale_kwargs(scales, layer),
+    )
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(one))
+
+
+def _layout_pool(kind, seed):
+    _, k, _, scales, tables = _stacked(kind, 1, seed)
+    pool = {"q": k, "s": scales[0]} if kind == "int8" else k
+    layout = PagedKVLayout(tables, BS, jnp.bfloat16, D)
+    return layout, pool
+
+
+def _layer_of(pool, layer):
+    return jax.tree_util.tree_map(lambda a: a[layer], pool)
+
+
+def _assert_trees_equal(a, b):
+    for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b), strict=True):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("op", ["view", "commit", "commit_column"])
+def test_layout_op_with_layer_equals_per_slice_form(op, kind, layer):
+    layout, pool = _layout_pool(kind, seed=80 + layer)
+    rng = np.random.default_rng(90 + layer)
+    pos = jnp.asarray([0, 5, BPR * BS - 1], jnp.int32)
+    traced = jnp.int32(layer)
+    if op == "view":
+        whole = jax.jit(layout.view)(pool, traced)
+        assert whole.shape == (B, BPR * BS, HKV, D)
+        _assert_trees_equal(whole, jax.jit(layout.view)(_layer_of(pool, layer)))
+        return
+    if op == "commit":
+        new = jnp.asarray(rng.normal(size=(B, BPR * BS, HKV, D)), jnp.bfloat16)
+    else:
+        new = jnp.asarray(rng.normal(size=(B, 1, HKV, D)), jnp.bfloat16)
+    # both under jit: XLA folds the quantizer's division the same way in each
+    whole = jax.jit(getattr(layout, op))(pool, new, pos, traced)
+    one = jax.jit(getattr(layout, op))(_layer_of(pool, layer), new, pos)
+    _assert_trees_equal(_layer_of(whole, layer), one)
+    # and no other layer was touched
+    for other in set(range(LAYERS)) - {layer}:
+        _assert_trees_equal(_layer_of(whole, other), _layer_of(pool, other))

@@ -140,6 +140,39 @@ def test_paged_flash_decode_compiles(compile_for_chip, width, quantized):
     )
 
 
+# the engine's own call (PR 28): every layer's pool stacked, the two head axes
+# merged, the layer a traced scalar that offsets the block tables. GPT-2 large
+# as the serving cell runs it (32 slots of 1024 positions, 36 layers of 1025
+# blocks: 3 GB of pool that nothing may copy), and Mistral's grouped heads.
+WHOLE_POOLS = {
+    "gpt2_large_serve_closed32": ("gpt2_large", 32, 64, 36, 1025),
+    "mistral_7b_gqa": ("mistral_7b", 8, 128, 4, 512),
+}
+
+
+@pytest.mark.parametrize("case", list(WHOLE_POOLS))
+def test_paged_flash_decode_compiles_on_the_whole_pool(compile_for_chip, case):
+    width, slots, blocks_per_row, layers, pool_blocks = WHOLE_POOLS[case]
+    heads, kv_heads, head_dim, _ = WIDTHS[width]
+    pool = ((layers, pool_blocks, BLOCK_SIZE, kv_heads * head_dim), jnp.bfloat16)
+
+    def decode(q, k, v, tables, pos, layer):
+        return paged_flash_decode(q, k, v, tables, pos, layer=layer, interpret=False)
+
+    text = compile_for_chip(
+        decode, ((slots, 1, heads, head_dim), jnp.bfloat16), pool, pool,
+        ((slots, blocks_per_row), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32),
+    )
+    # the kernel reads the pool where it lies: the chip keeps this shape
+    # row-major, so flattening it is a bitcast and nothing of its size is made
+    flat = f"bf16[{layers * pool_blocks},{BLOCK_SIZE},{kv_heads * head_dim}]"
+    made = [
+        line for line in text.splitlines()
+        if f" = {flat}" in line and " bitcast(" not in line and " parameter(" not in line
+    ]
+    assert not made, made[:2]
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16_pool", "int8_pool"])
 @pytest.mark.parametrize("width", list(WIDTHS))
 def test_paged_flash_verify_compiles(compile_for_chip, width, quantized):
