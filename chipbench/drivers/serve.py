@@ -14,18 +14,21 @@ has its own.
 
 from __future__ import annotations
 
+import inspect
 import queue
 import time
 
 import numpy as np
 
-from chipbench import traffic, work
+from chipbench import traffic
 from chipbench import weights as weights_lib
-from chipbench.lib import BenchError, free_device_memory, load_module, percentile
+from chipbench.lib import BenchError, count, free_device_memory, load_module, percentile
 from chipbench.program import program_config
 
 DRAIN_TIMEOUT_S = 120.0
 POLL_S = 0.05
+# what this driver asks of the configuration's counts file
+COUNTS = ("prefill_flops", "decode_flops", "kv_bytes_per_token")
 
 
 class State:
@@ -102,6 +105,8 @@ def setup(ctx) -> State:
     from accelerate_tpu.utils.dataclasses import ServingConfig
 
     workload, config = ctx.workload, ctx.config
+    for function in COUNTS:  # a missing count ends the run here, not after its window
+        count(config, function)
     marks = [("start", time.perf_counter())]
     family, built = program_config(config)
     reference = load_module("reference", config["reference"])
@@ -109,7 +114,10 @@ def setup(ctx) -> State:
     state.spec = reference.weight_spec(config)
     state.dtype = built.param_dtype
 
-    model = getattr(family, f"create_{config['family']}")(built)
+    create = getattr(family, f"create_{config['family']}")
+    # shapes only, where the program can: its own initial weights are thrown away
+    shapes_only = {"abstract": True} if "abstract" in inspect.signature(create).parameters else {}
+    model = create(built, **shapes_only)
     model.params = None  # the program's own initial weights make room for those of the seed
     marks.append(("program_init", time.perf_counter()))
     model.params = weights_lib.nest(weights_lib.make_weights(state.spec, ctx.seed, state.dtype))
@@ -228,7 +236,7 @@ def window(ctx, state: State, seconds: float, hooks) -> dict:
     # the engine's own count of the window's tokens, to hold the clients' against:
     # one a live slot a decode step and one an insertion (live slots are polled,
     # so it is near, not exact); and what the cache held against what it reserves
-    per_token = work.kv_bytes_per_token_per_layer(ctx.config) * ctx.config["num_hidden_layers"]
+    per_token = count(ctx.config, "kv_bytes_per_token")(ctx.config)
     live_bytes = counters["live_tokens_mean"] * per_token
     pool_bytes = stats1["kv"].get("hbm_bytes") or 0
     out["facts"].update(
@@ -252,9 +260,8 @@ def account(ctx, records: list, start: float, end: float, counters: dict) -> dic
     prefill included; a request that failed counts as the worst."""
     seconds = end - start
     tokens = flops = 0.0
-    family = ctx.config["family"]
-    prefill_flops = getattr(work, f"{family}_prefill_flops")
-    decode_flops = getattr(work, f"{family}_decode_flops")
+    prefill_flops = count(ctx.config, "prefill_flops")
+    decode_flops = count(ctx.config, "decode_flops")
     for r in records:
         inside = max(0.0, min(r.done, end) - max(r.sent, start)) / (r.done - r.sent)
         if r.failed or inside <= 0.0:
@@ -296,6 +303,7 @@ def account(ctx, records: list, start: float, end: float, counters: dict) -> dic
             requests_sent=len(sent_inside),
         ),
         "facts": {
+            "model_flops": flops,
             "requests_back": len(back), "requests_per_s": len(good) / seconds,
             "tokens_of_requests_back_per_s": completed / seconds,
             "norm_latency_ms": {f"p{q}": percentile(latency, q) for q in (50, 75, 90, 95)},

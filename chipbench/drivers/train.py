@@ -15,9 +15,9 @@ import time
 
 import numpy as np
 
-from chipbench import traffic, work
+from chipbench import traffic
 from chipbench import weights as weights_lib
-from chipbench.lib import BenchError, free_device_memory, load_module, median
+from chipbench.lib import BenchError, count, free_device_memory, load_module, median
 from chipbench.program import program_config
 
 REFERENCE_STEPS = 3
@@ -37,6 +37,7 @@ def setup(ctx) -> State:
 
     workload, config = ctx.workload, ctx.config
     tr, opt = workload["traffic"], workload["optimizer"]
+    count(config, "train_flops_per_token")  # a missing count ends the run here, not after its window
     marks = [("start", time.perf_counter(), 0)]
 
     def mark(name):
@@ -230,7 +231,7 @@ def check(ctx, state: State) -> dict:
 
 def flops(ctx, result: dict) -> float:
     """Model FLOPs of the window's tokens, for ``mfu``."""
-    per_token = getattr(work, f"{ctx.config['family']}_train_flops_per_token")(
+    per_token = count(ctx.config, "train_flops_per_token")(
         ctx.config, ctx.workload["traffic"]["seq_len"]
     )
     return per_token * result["tokens"]

@@ -49,8 +49,8 @@ def load_benchmark() -> dict:
 
 def load_module(kind: str, name: str):
     """``chipbench/<kind>/<name>.py`` as a module, found by its file name: a
-    driver, a reference or a per-layer metric that a later PR drops in is
-    found the same way as those that are here."""
+    driver, a reference, a family's counts or a per-layer metric that a later
+    PR drops in is found the same way as those that are here."""
     path = _find(kind, name + ".py")
     module_name = f"chipbench_{kind}_{name}".replace(".", "_").replace("-", "_")
     if module_name in sys.modules:
@@ -67,6 +67,31 @@ def load_cell(workload_name: str) -> tuple:
     workload = load_json("workloads", workload_name + ".json")
     config = load_json("configs", workload["config"] + ".json")
     return workload, config
+
+
+def counts_name(config: dict) -> str:
+    """Which ``counts/<name>.py`` holds the configuration's counts: its ``counts``
+    key where it has one (a hybrid brings its own), else its ``family``."""
+    return config.get("counts") or config["family"]
+
+
+def find_count(config: dict, function: str):
+    """``function`` of the configuration's counts file, or None where the file
+    has no such function: what a per-layer metric's reader asks, which then
+    returns None itself."""
+    return getattr(load_module("counts", counts_name(config)), function, None)
+
+
+def count(config: dict, function: str):
+    """The same for whoever cannot go on without (a driver): a missing function
+    ends the run, never another family's formula in its place."""
+    found = find_count(config, function)
+    if found is None:
+        raise BenchError(
+            f"chipbench: chipbench/counts/{counts_name(config)}.py has no {function}(), "
+            f"which this cell asks of it"
+        )
+    return found
 
 
 def cell_metrics(benchmark: dict, workload_name: str) -> tuple:

@@ -1,8 +1,7 @@
 """Rows of the decode program that did useful work: the mean, over the
 ``engine.decode_step`` spans of the traced window, of the slots that were decoding
-over all slots, counted where the step is dispatched. (``slot_occupancy.serve``
-stands beside it: polled from outside, and counting slots that hold a finished
-request.)"""
+over all slots, counted where the step is dispatched (a slot that holds a
+finished request not yet retired is not decoding)."""
 
 from chipbench import hostspans
 

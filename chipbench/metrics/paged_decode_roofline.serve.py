@@ -3,22 +3,22 @@ live keys and values (the mean of ``engine.live_tokens()`` polled during the tra
 window, times bytes a token a layer, once for every call of the kernel, over the HBM
 peak; bytes bind, the FLOPs are a few per byte) over the kernel's summed device time."""
 
-from chipbench import trace, work
+from chipbench import lib, trace
 
 METRIC = {"name": "paged_decode_roofline.serve", "layer": "serving kernels", "unit": "%",
           "moves": "norm_latency_p50_ms", "source": "device_trace"}
 
-# no pallas_call of the program passes name=, so the trace names the kernel after
-# what wraps it (closed_call). What tells it from the sampler, the decode program's
-# other Pallas kernel, is its shape: block tables in (s32) and one row a head out
-# (bf16[slots,heads,1,head_dim]).
-KERNEL = r'= bf16\[\d+,\d+,1,\d+\]\S* custom-call\(s32\[.*custom_call_target="tpu_custom_call"'
+# the name the program gives its ``pallas_call`` (``name=``) is the custom call's
+# instruction name in the chip's trace, with the compiler's numbering behind it:
+# ``%paged_decode.9 = ...``. A model's own kernel is none of this metric's.
+KERNEL = r"^%?paged_decode[.\d]* = "
 
 
 def read(run):
     seconds, calls = trace.time_matching(run.summary, KERNEL)
     live = run.result["counters"]["live_tokens_mean"]
-    if not seconds or not live:
+    call_bytes = lib.find_count(run.ctx.config, "paged_decode_bytes")
+    if not seconds or not live or call_bytes is None:
         return None
-    least = calls * work.paged_decode_bytes(run.ctx.config, live) / run.ctx.peaks["hbm_bytes_per_s"]
+    least = calls * call_bytes(run.ctx.config, live) / run.ctx.peaks["hbm_bytes_per_s"]
     return 100.0 * least / seconds

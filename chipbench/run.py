@@ -198,9 +198,11 @@ def execute(args) -> dict:
     out = {"correct": correct, "attempted": int(result["attempted"]),
            "failed": int(result["failed"]), "metrics": metrics, "device": device}
     if args.trace:
+        from chipbench import hostspans
         from chipbench import trace as trace_lib
 
         summary = trace_lib.summarize(trace_lib.read_devices(trace_dir))
+        host_spans = hostspans.read(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"], device["window_s"] = summary.busy_s, summary.window_s
         run = Run(ctx, driver, result, summary)
@@ -209,6 +211,8 @@ def execute(args) -> dict:
             if value is not None:
                 metrics[entry["name"]] = {"value": float(value), "unit": entry["unit"]}
         out["breakdown"] = trace_lib.breakdown(summary)
+        if host_spans:  # a program without the bridge keeps unattributed_gap_<n>
+            out["breakdown"]["idle_gaps"] = hostspans.name_gaps(summary.gaps, host_spans)
         top = sorted(summary.op_self_s.items(), key=lambda kv: kv[1], reverse=True)[:40]
         say(trace_programs={k: [len(v), sum(v)] for k, v in summary.program_s.items()},
             trace_ops=[[trace_lib.short_name(name, 400), seconds, summary.op_count[name]]

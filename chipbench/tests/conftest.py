@@ -18,8 +18,10 @@ from chipbench import lib, run
 TINY = {
     "end_to_end": [
         {"name": "train_tokens_per_s_chip", "unit": "tokens/s/chip", "workloads": ["tiny-llama.train"]},
-        {"name": "serve_tokens_per_s", "unit": "tokens/s", "workloads": ["tiny-gpt2.serve"]},
-        {"name": "norm_latency_p50_ms", "unit": "ms", "workloads": ["tiny-gpt2.serve"]},
+        {"name": "serve_tokens_per_s", "unit": "tokens/s",
+         "workloads": ["tiny-gpt2.serve", "tiny-hybrid.serve"]},
+        {"name": "norm_latency_p50_ms", "unit": "ms",
+         "workloads": ["tiny-gpt2.serve", "tiny-hybrid.serve"]},
         {"name": "setup_s", "unit": "s"},
     ],
     "per_layer": [],

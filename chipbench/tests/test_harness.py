@@ -57,24 +57,35 @@ def test_percentile_matches_numpy():
 def test_work_counts_agree_with_a_hand_count():
     mistral = lib.load_json("configs", "mistral-7b-2l.json")
     gpt2 = lib.load_json("configs", "gpt2-large.json")
-    assert work.llama_params(mistral) == 698_372_096
-    assert work.gpt2_params(gpt2) == 774_030_080
+    llama_counts, gpt2_counts = lib.load_module("counts", "llama"), lib.load_module("counts", "gpt2")
+    # a configuration's counts are found by its family where it names no file of its own
+    assert lib.count(mistral, "params") is llama_counts.params
+    assert lib.count(gpt2, "params") is gpt2_counts.params
+    assert llama_counts.params(mistral) == 698_372_096
+    assert gpt2_counts.params(gpt2) == 774_030_080
     # one Mistral layer, a token: q 4096x4096, k and v 4096x1024, o 4096x4096,
     # gate, up and down 4096x14336, two FLOPs a weight
     layer = 2 * (4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336)
-    assert work.llama_layer_matmul_flops_per_token(mistral) == layer
+    assert llama_counts.layer_matmul_flops_per_token(mistral) == layer
     # causal attention at 2048 tokens: a query sees 1024.5 keys on average
     attention = 4 * 4096 * 1024.5
     head = 2 * 4096 * 32000 * 2047 / 2048
-    assert work.llama_train_flops_per_token(mistral, 2048) == pytest.approx(
+    assert llama_counts.train_flops_per_token(mistral, 2048) == pytest.approx(
         3 * (2 * (layer + attention) + head))
     assert work.mean_keys(8, window=4) == pytest.approx((1 + 2 + 3 + 4 + 4 * 4) / 8)
     # GPT-2 large, a decoded token with 200 keys in its cache
     per_layer = 2 * (4 * 1280 * 1280 + 2 * 1280 * 5120) + 4 * 1280 * 200
-    assert work.gpt2_decode_flops(gpt2, 200) == pytest.approx(36 * per_layer + 2 * 1280 * 50257)
+    assert gpt2_counts.decode_flops(gpt2, 200) == pytest.approx(36 * per_layer + 2 * 1280 * 50257)
     assert work.kv_bytes_per_token_per_layer(gpt2) == 2 * 20 * 64 * 2
-    flash = work.flash_train_work(mistral, 1, 2048)
+    flash = llama_counts.flash_train_work(mistral, 1, 2048)
     assert flash["flops"] == pytest.approx(2 * 3 * 2048 * attention)
+    # every layer of both families keeps keys and values: 8 KV heads of 128 and
+    # 20 heads of 64, keys and values, two bytes each
+    assert llama_counts.kv_layers(mistral) == 2 and gpt2_counts.kv_layers(gpt2) == 36
+    assert llama_counts.kv_bytes_per_token(mistral) == 2 * 4096
+    assert gpt2_counts.kv_bytes_per_token(gpt2) == 36 * 5120
+    # one call of the decode kernel reads one layer's keys and values of the live tokens
+    assert gpt2_counts.paged_decode_bytes(gpt2, 1000) == 1000 * 5120
 
 
 def test_trace_reduction_on_a_synthetic_trace():
@@ -117,6 +128,20 @@ def test_traffic_gives_every_seed_the_same_sizes_in_another_order():
             len(r.prompt) for r in b[:period])
         assert sorted(r.max_new_tokens for r in a[cycle:cycle + period]) == sorted(
             r.max_new_tokens for r in b[:period])
+    # and the same pairs: which prompt meets which output, and of which kind, is
+    # work too (what is live, and for how long), so no seed may deal it anew
+    pairs = [sorted((len(r.prompt), r.max_new_tokens, r.greedy) for r in pool[:period])
+             for pool in (a, b, a[period:])]
+    assert pairs[0] == pairs[1] == pairs[2]
+    standing = [sorted((len(r.prompt), r.max_new_tokens, r.greedy)
+                       for r in traffic.standing_requests(params, 50257, seed))
+                for seed in (1, 2**31 + 9)]
+    assert standing[0] == standing[1]
+    # the pairing leaves no order behind: long prompts meet short outputs and long ones
+    prompts, outputs, _ = traffic.paired_sizes(params, period)
+    product = float((prompts * outputs).sum()) / (period * prompts.mean() * outputs.mean())
+    assert product == pytest.approx(1.0, abs=0.01)
+    assert sorted(traffic.pairing(period)) == list(range(period))
     assert not (a[0].prompt == a[period].prompt).all()  # the same size, fresh ids
     assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
     assert all((x.prompt == y.prompt).all() and x.seed == y.seed for x, y in zip(a, again))

@@ -49,7 +49,6 @@ EXPECTED = {
     "decode_fill.serve": (SERVING, 100 * (3 / 4 + 4 / 4) / 2),
     "prefill_padding.serve": (SERVING, 100 * (1 - 128 / 640)),
     "kv_live_of_reserved.serve": (SERVING, 100 * (0.6 + 0.8) / 2),
-    "step_dispatch_ms_p50.train": (TRAINING, 2.0),
     "data_wait_share.train": (TRAINING, 100 * 0.004 / 0.400),
 }
 

@@ -182,8 +182,10 @@ def programs_matching(summary: TraceSummary, pattern: str) -> list:
 
 def breakdown(summary: TraceSummary) -> dict:
     """The contract's ``breakdown``: the ten operations that took most device
-    time, and the ten longest idle gaps. No span of the program is on the
-    profiler's clock yet, so a gap is named by where in the window it fell."""
+    time, and the ten longest idle gaps, longest first. A gap is named here by
+    its rank alone; where the trace holds the program's spans, ``run.py`` puts
+    ``hostspans.name_gaps`` in their place, which names each by what the host
+    was doing when the device fell idle (same gaps, same seconds)."""
     top = sorted(summary.op_self_s.items(), key=lambda kv: kv[1], reverse=True)[:10]
     return {
         "device_ops": [[short_name(name), seconds] for name, seconds in top],

@@ -2,12 +2,16 @@
 sharing of greedy and sampled requests, clients); this turns them and ``--seed``
 into rows to train on or requests to serve.
 
-Every seed gets the same set of sizes in another order: lengths are the
-stratified quantiles of the stated distribution, and the seed permutes them and
-draws the token ids. The sizes come round again after ``pool`` requests, so that
-a window, which takes as many requests as the system is fast, holds whole rounds
-of the same work whatever the seed; the token ids are fresh for every request (a
-prompt sent twice would be served from the prefix cache).
+Every seed gets the same requests in another order: lengths are the stratified
+quantiles of the stated distribution, each prompt length is paired with an output
+length and a kind (greedy or sampled) by a rule that takes no seed
+(``paired_sizes``), and the seed permutes the pairs and draws the token ids. Which
+prompt meets which output is part of the work (a long prompt that stays for a long
+output holds its keys and values for as long, and the decode kernel's time follows
+what is live), so it may not change with the seed. The pairs come round again after
+``pool`` requests, so that a window, which takes as many requests as the system is
+fast, holds whole rounds of the same work whatever the seed; the token ids are
+fresh for every request (a prompt sent twice would be served from the prefix cache).
 
 A closed loop that starts with every client on a fresh request is not in its
 steady state: nothing finishes for a while, then much at once. So the requests
@@ -62,15 +66,44 @@ def stratified_lengths(dist: dict, count: int) -> np.ndarray:
     return np.clip(np.rint(values), dist["min"], dist["max"]).astype(np.int64)
 
 
+def spread_evenly(count: int, share: float) -> np.ndarray:
+    """``count`` flags of which ``round(share * count)`` are set, as evenly
+    spaced as whole numbers allow (every other one for a half)."""
+    marks = np.arange(count + 1) * int(round(share * count)) // count
+    return marks[1:] > marks[:-1]
+
+
+def pairing(count: int) -> np.ndarray:
+    """A permutation of ``range(count)`` that takes no seed and leaves no order
+    behind: ``i -> stride * i mod count`` with the stride at the golden section
+    of ``count`` (the next below it that shares no factor with ``count``), so
+    that neighbours land far apart and no end of one list meets an end of the
+    other throughout."""
+    stride = max(1, int(count * (math.sqrt(5.0) - 1.0) / 2.0))
+    while math.gcd(stride, count) != 1:
+        stride -= 1
+    return (stride * np.arange(count)) % count
+
+
+def paired_sizes(params: dict, count: int) -> tuple:
+    """``(prompt_lens, output_lens, greedy)``, ``count`` of each and the same for
+    every seed: the two lists of stratified lengths, the prompts ascending,
+    the outputs as ``pairing`` deals them, and the greedy share spread evenly
+    over the pairs. (For the 32 sizes of ShareGPT's lengths the summed product of
+    prompt and output is 0.996 of what independent lengths give.)"""
+    prompt_lens = stratified_lengths(params["prompt_len"], count)
+    output_lens = stratified_lengths(params["output_len"], count)[pairing(count)]
+    return prompt_lens, output_lens, spread_evenly(count, params["greedy_share"])
+
+
 def request_pool(params: dict, vocab_size: int, seed: int) -> list:
     """The ``requests`` requests of a run, in the order in which clients take
-    them; their sizes and kinds repeat with the period ``pool``."""
+    them; the same ``pool`` pairs of sizes, in an order the seed draws, come
+    round with the period ``pool``."""
     period = params["pool"]
     rng = np.random.default_rng([int(seed), 23])
-    prompt_lens = rng.permutation(stratified_lengths(params["prompt_len"], period))
-    output_lens = rng.permutation(stratified_lengths(params["output_len"], period))
-    n_greedy = int(round(params["greedy_share"] * period))
-    greedy = rng.permutation(np.arange(period) < n_greedy)
+    order = rng.permutation(period)
+    prompt_lens, output_lens, greedy = (x[order] for x in paired_sizes(params, period))
     sampling = params["sampling"]
     requests = []
     for index in range(params["requests"]):
@@ -118,8 +151,9 @@ def standing_requests(params: dict, vocab_size: int, seed: int) -> list:
     outputs are what is left of such requests: the stratified quantiles, over the
     clients, of the mix's output tokens laid end to end; what such a request has
     made already rides in its prompt (as far as the longest prompt allows), so
-    that the cache is as full as the steady state has it. The same set for
-    every seed, dealt to the clients in an order the seed draws."""
+    that the cache is as full as the steady state has it. The same requests for
+    every seed (sizes paired as ``paired_sizes`` pairs them), dealt to the
+    clients in an order the seed draws."""
     clients, period = params["clients"], params["pool"]
     rng = np.random.default_rng([int(seed), 31])
     outputs = stratified_lengths(params["output_len"], period)
@@ -130,11 +164,14 @@ def standing_requests(params: dict, vocab_size: int, seed: int) -> list:
         which = int(np.searchsorted(ends, at, side="right"))
         left.append(max(1, int(np.ceil(ends[which] - at))))  # what is left of its request
         made.append(int(outputs[which]) - left[-1])
+    # which prompt carries which remainder, and of which kind, takes no seed either
+    deal = pairing(clients)
+    left, made = np.asarray(left)[deal], np.asarray(made)[deal]
+    prompt_lens = np.minimum(stratified_lengths(params["prompt_len"], clients) + made,
+                             params["prompt_len"]["max"])
+    greedy = spread_evenly(clients, params["greedy_share"])
     order = rng.permutation(clients)
-    left, made = np.asarray(left)[order], np.asarray(made)[order]
-    prompt_lens = rng.permutation(stratified_lengths(params["prompt_len"], clients))
-    prompt_lens = np.minimum(prompt_lens + made, params["prompt_len"]["max"])
-    greedy = rng.permutation(np.arange(clients) < int(round(params["greedy_share"] * clients)))
+    left, prompt_lens, greedy = left[order], prompt_lens[order], greedy[order]
     sampling = params["sampling"]
     requests = []
     for i in range(clients):
