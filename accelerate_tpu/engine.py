@@ -305,12 +305,6 @@ class ContinuousBatchingEngine:
         clock: Callable[[], float] = time.monotonic,
     ):
         from .kvcache import make_kv_backend
-        from .models.gpt2 import (
-            GPT2Config, gpt2_decode_step, gpt2_prefill_at, gpt2_verify_step,
-        )
-        from .models.llama import (
-            llama_decode_step, llama_prefill_at, llama_verify_step,
-        )
 
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -334,6 +328,17 @@ class ContinuousBatchingEngine:
             )
         self.model = model
         self.config = model.config
+        # the family's door: its step functions and what state a slot keeps
+        # (models/family.py). Nothing below asks which family it is.
+        self._family = self.config.serving_family()
+        if self._family.verify_step is None and (spec is not None or prefill_chunk is not None):
+            raise ValueError(
+                f"{'speculative decoding' if spec is not None else 'chunked prefill'} "
+                f"needs a verify_step, which {type(self.config).__name__}'s family "
+                "does not hand over: a window of tokens over the cache would have "
+                "to leave the family's recurrent state as of the accepted prefix "
+                "(the missing piece: a recurrent-state snapshot to rewind to)"
+            )
         self.slots = slots
         self.max_len = max_len
         self.prompt_bucket = prompt_bucket if prompt_bucket is not None else max(1, max_len // 2)
@@ -386,12 +391,6 @@ class ContinuousBatchingEngine:
             # any dispatch self._donated is rebound to the program's output
             # arrays, so this closure always sees the live pool
             self._backend.bind_cache_reader(lambda: self._donated["cache"])
-        if isinstance(self.config, GPT2Config):
-            self._prefill_at_fn, self._decode_fn = gpt2_prefill_at, gpt2_decode_step
-            self._verify_fn = gpt2_verify_step
-        else:
-            self._prefill_at_fn, self._decode_fn = llama_prefill_at, llama_decode_step
-            self._verify_fn = llama_verify_step
         self._key_width = jax.random.key_data(jax.random.key(0)).shape[-1]
 
         self.spec = spec
@@ -465,6 +464,7 @@ class ContinuousBatchingEngine:
         # K-programs-late trick as telemetry's DeferredReadbackRing, here
         # over (token, done) vectors instead of health verdicts
         self._ring: collections.deque = collections.deque()
+        self._step_counters: dict = {}  # tick -> a step's counters (_ride_along)
         self._tick = 0
         self.inserted = 0
         self.remote_prefills = 0
@@ -515,14 +515,13 @@ class ContinuousBatchingEngine:
         # churn — admissions, retirements, COW sharing — never recompiles,
         # preserving the exactly-two-programs discipline
         layout = self._backend.make_layout(tables)
-        if layout is None:
-            logits, cache = self._decode_fn(
-                self.config, params, cache, token[:, None], pos
-            )
-        else:
-            logits, cache = self._decode_fn(
-                self.config, params, cache, token[:, None], pos, kv_layout=layout
-            )
+        # a family with step counters returns them third; they ride the
+        # readback ring beside the tokens (no transfer or sync of their own).
+        # layout None: the family consumes the dense arena directly
+        logits, cache, *counters = self._family.decode_step(
+            self.config, params, cache, token[:, None], pos,
+            kv_layout=layout,
+        )
         pairs = jax.vmap(jax.random.split)(jax.random.wrap_key_data(key_data))
         next_kd = jax.random.key_data(pairs[:, 0])
         subs = pairs[:, 1]
@@ -553,7 +552,7 @@ class ContinuousBatchingEngine:
         new_pos = pos + emitting.astype(jnp.int32)
         new_donated = {"cache": cache, "pos": new_pos, "key": next_kd}
         new_carried = {**carried, "token": nxt, "done": new_done, "budget": budget}
-        return new_donated, new_carried
+        return new_donated, new_carried, (counters[0] if counters else None)
 
     def _verify_impl(self, donated, carried, params, tables, draft, draft_len):
         """The third jitted program: verify a fixed-k padded draft window
@@ -589,14 +588,10 @@ class ContinuousBatchingEngine:
         w = k + 1
         layout = self._backend.make_layout(tables)
         tokens = jnp.concatenate([token[:, None], draft], axis=1)  # (S, W)
-        if layout is None:
-            logits, win_kv = self._verify_fn(
-                self.config, params, cache, tokens, pos
-            )
-        else:
-            logits, win_kv = self._verify_fn(
-                self.config, params, cache, tokens, pos, kv_layout=layout
-            )
+        logits, win_kv = self._family.verify_step(
+            self.config, params, cache, tokens, pos,
+            kv_layout=layout,
+        )
         # logits: (S, W, V) f32 — logits[:, j] is the next-token dist after
         # consuming window token j (position pos+j)
         v = logits.shape[-1]
@@ -689,7 +684,7 @@ class ContinuousBatchingEngine:
         # occupant. Paged: per-block dynamic_update_slice writes into the
         # slot's table-row blocks (recycled blocks rely on the write-before-
         # attend invariant instead of a wipe — kvcache.py docstring).
-        logits, new_cache = self._prefill_at_fn(
+        logits, new_cache, *counters = self._family.prefill_at(
             self.config, params, prompt, self.max_len, (length - 1)[None]
         )
         keys = jax.random.split(jax.random.wrap_key_data(key_data), 2)
@@ -715,7 +710,7 @@ class ContinuousBatchingEngine:
             "eos": carried["eos"].at[slot].set(eos),
             "pad": carried["pad"].at[slot].set(pad),
         }
-        return new_donated, new_carried, t0, done0
+        return new_donated, new_carried, t0, done0, (counters[0] if counters else None)
 
     def _prefill_forward_impl(self, params, prompt, length, key_data, temp, top_k, top_p):
         # the arena-free half of _prefill_impl: same bucketed forward, same
@@ -723,7 +718,9 @@ class ContinuousBatchingEngine:
         # insert_prefilled is bitwise identical to a plain insert. Nothing
         # here reads or writes slot state, which is what makes it safe off
         # the single-controller decode thread.
-        logits, new_cache = self._prefill_at_fn(
+        # whatever state the family keeps rides in new_cache, its recurrent
+        # rows included: insert_prefilled commits it like any prefill's
+        logits, new_cache, *_counters = self._family.prefill_at(
             self.config, params, prompt, self.max_len, (length - 1)[None]
         )
         keys = jax.random.split(jax.random.wrap_key_data(key_data), 2)
@@ -785,14 +782,10 @@ class ContinuousBatchingEngine:
         cache = donated["cache"]
         pos = donated["pos"].at[slot].set(offset)
         layout = self._backend.make_layout(tables)
-        if layout is None:
-            logits, win_kv = self._verify_fn(
-                self.config, params, cache, tokens, pos
-            )
-        else:
-            logits, win_kv = self._verify_fn(
-                self.config, params, cache, tokens, pos, kv_layout=layout
-            )
+        logits, win_kv = self._family.verify_step(
+            self.config, params, cache, tokens, pos,
+            kv_layout=layout,
+        )
         count = jnp.zeros((self.slots,), jnp.int32).at[slot].set(chunk_len)
         cache = self._backend.commit_window(cache, win_kv, tables, pos, count)
         is_last = offset + chunk_len >= length
@@ -857,7 +850,7 @@ class ContinuousBatchingEngine:
                 out[w] = leaf.at[:, ids].set(
                     jnp.moveaxis(payload[w], 0, 1).astype(leaf.dtype)
                 )
-        return {**donated, "cache": out}
+        return {**donated, "cache": {**cache, **out}}
 
     def _record(self, name: str, sig: tuple) -> None:
         self._programs.setdefault(name, set()).add(sig)
@@ -1055,7 +1048,7 @@ class ContinuousBatchingEngine:
             "engine.prefill", trace_id=trace_id,
             slot=slot, prompt_len=len(prompt), bucket=self.prompt_bucket,
         ):
-            self._donated, self._carried, t0, d0 = self._prefill_jit(
+            self._donated, self._carried, t0, d0, counters = self._prefill_jit(
                 self._donated, self._carried, self.model.params,
                 jnp.asarray(padded), jnp.int32(len(prompt)), jnp.int32(slot), kd,
                 jnp.float32(temperature),
@@ -1075,6 +1068,7 @@ class ContinuousBatchingEngine:
         self.peak_live = max(self.peak_live, self.live_count())
         self._tick += 1
         self._ring.append((self._tick, "prefill", (occ, t0, d0)))
+        self._ride_along(counters)
         return occ
 
     # ------------------------------------------------------- chunked prefill
@@ -1472,7 +1466,7 @@ class ContinuousBatchingEngine:
             kv_live_tokens=self.live_tokens(),
             kv_reserved_tokens=self._backend.reserved_tokens(),
         ):
-            self._donated, self._carried = self._decode_jit(
+            self._donated, self._carried, counters = self._decode_jit(
                 self._donated, self._carried, self.model.params,
                 self._backend.device_tables(),
             )
@@ -1482,7 +1476,24 @@ class ContinuousBatchingEngine:
             (self._tick, "decode",
              (self._ring_occupants(), self._carried["token"], self._carried["done"]))
         )
+        self._ride_along(counters)
         return True
+
+    def _ride_along(self, counters) -> None:
+        """A step's counters (small device arrays, from a family that has
+        ``step_summary``) wait beside its ring entry, by its tick, and are read
+        where the entry is: no transfer or sync of their own."""
+        if counters is not None:
+            self._step_counters[self._tick] = counters
+
+    def _summarize_step(self, tick: int, span) -> None:
+        """Inside the readback span of the ring entry ``tick``: the step's
+        counters as scalars on that span."""
+        counters = self._step_counters.pop(tick, None)
+        if counters is not None and span is not tracing.NULL_SPAN:
+            # graft: sync-ok — read with the entry's tokens, the ring's readback point
+            for name, value in self._family.step_summary(jax.device_get(counters)).items():
+                span.set(name, value)
 
     def _ring_occupants(self) -> tuple:
         """Occupant snapshot for a decode/verify ring entry. A PREFILLING
@@ -1705,7 +1716,7 @@ class ContinuousBatchingEngine:
         while self._ring and (
             force or self._tick - self._ring[0][0] >= self.readback_lag
         ):
-            _, kind, payload = self._ring.popleft()
+            tick, kind, payload = self._ring.popleft()
             popped[kind] += 1
             if kind == "chunk":
                 continue  # no tokens — the last chunk's entry carries t0
@@ -1714,15 +1725,17 @@ class ContinuousBatchingEngine:
             # less the readbacks inside it is the host's own work
             if kind == "prefill":
                 occ, tok, done = payload
-                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())):
+                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())) as sp:
                     # graft: sync-ok — the ring IS the readback point (K programs late)
                     tok, done = int(np.asarray(tok)), bool(np.asarray(done))
+                    self._summarize_step(tick, sp)
                 self._absorb(occ, tok, done, retired)
             elif kind == "decode":
                 occs, toks, dones = payload
-                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())):
+                with tracing.span("engine.readback", kind=kind, popped=sum(popped.values())) as sp:
                     # graft: sync-ok — the ring IS the readback point (K programs late)
                     toks, dones = np.asarray(toks), np.asarray(dones)
+                    self._summarize_step(tick, sp)
                 for occ in occs:
                     if occ is None or occ.finished:
                         continue
@@ -1885,6 +1898,7 @@ class ContinuousBatchingEngine:
             self._reservations.clear()
             self._free = list(range(self.slots))
         self._ring.clear()
+        self._step_counters.clear()
         self._prefill_queue.clear()
         self._backend.reset()  # fresh pool + empty prefix registry/tables
         self._donated, self._carried = self._init_state()
@@ -1943,6 +1957,7 @@ class ContinuousBatchingEngine:
             "programs": programs,
             "program_count": sum(programs.values()),
             "kv": kv,
+            "recurrent_state_bytes": self._backend.recurrent_state_bytes(),
             "spec": {
                 "mode": self.spec or "off",
                 "draft_len": self.spec_draft_len,

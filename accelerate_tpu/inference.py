@@ -87,8 +87,8 @@ def generate(
     kv_block_size: int = 16,
 ):
     """Greedy (temperature=0) or sampled generation for the causal-LM
-    families (llama/mixtral/mistral, gpt2 — dispatched on the model's config
-    type).
+    families (llama/mixtral/mistral, gpt2, lfm2): the model's config hands
+    over its family's step functions (``config.serving_family()``).
 
     Prefill runs the full forward once; decode is a single compiled scan with
     a static-size KV cache. ``top_k``/``top_p`` (nucleus) filter the sampled
@@ -106,8 +106,6 @@ def generate(
     the decode scan always runs exactly ``max_new_tokens`` steps, so the
     output token count is unchanged.
     """
-    from .models.gpt2 import GPT2Config, gpt2_decode_step, gpt2_prefill
-    from .models.llama import llama_decode_step, llama_prefill
     from .kvcache import KV_BACKENDS, PagedKVLayout, pool_from_dense
 
     if kv_backend not in KV_BACKENDS:
@@ -118,10 +116,8 @@ def generate(
     if paged and kv_block_size < 1:
         raise ValueError(f"kv_block_size must be >= 1, got {kv_block_size}")
     config = model.config
-    if isinstance(config, GPT2Config):
-        prefill_fn, decode_fn = gpt2_prefill, gpt2_decode_step
-    else:
-        prefill_fn, decode_fn = llama_prefill, llama_decode_step
+    family = config.serving_family()
+    prefill_fn, decode_fn = family.prefill, family.decode_step
     input_ids = jnp.asarray(input_ids, dtype=jnp.int32)
     b, prompt_len = input_ids.shape
     total_len = prompt_len + max_new_tokens
@@ -190,7 +186,8 @@ def generate(
         def _run(params, input_ids, key, temp, p_threshold, eos_id, pad_id):
             # prefill: ONE full forward fills the cache (O(S) matmul work
             # vs O(S²) for token-by-token decode over the prompt)
-            logits, cache = prefill_fn(config, params, input_ids, total_len)
+            # a family with step counters returns them third: unused here
+            logits, cache, *_ = prefill_fn(config, params, input_ids, total_len)
             if paged:
                 # re-lay as a block pool with identity tables: decode now
                 # exercises the engine's gather/commit ops inside this same
@@ -199,7 +196,7 @@ def generate(
                     cache, kv_block_size, quantized=kv_backend == "paged_int8"
                 )
                 kv_layout = PagedKVLayout(
-                    tables, kv_block_size, config.compute_dtype, config.head_dim
+                    tables, kv_block_size, config.compute_dtype, family.head_dim
                 )
             else:
                 kv_layout = None
@@ -216,7 +213,7 @@ def generate(
                 if eos_on:
                     token = jnp.where(done, pad_id, token)
                     done = done | (token == eos_id)
-                logits, cache = decode_fn(
+                logits, cache, *_ = decode_fn(
                     config, params, cache, token[:, None], t, kv_layout=kv_layout
                 )
                 return (cache, logits, key, done, wasted), token

@@ -64,6 +64,21 @@ Safety invariants (the reasons slot recycling cannot corrupt KV):
   blocks only cover positions ``< floor(prompt_len/bs)*bs``, so shared
   content is never written after registration — COW without copies.
 
+Two kinds of state. A family says how many of its layers keep keys and values
+and how large a head is (``config.serving_family()``, models/family.py): the
+arena's and the pool's leading axis is that count, not the model's depth. A
+family whose other layers keep a fixed-size recurrent state a sequence (a short
+convolution's last inputs) gets a third leaf beside ``"k"`` and ``"v"``:
+``"recurrent"``, ``(recurrent layers, slots, *state shape)``, one row a slot
+whatever the backend. ``prefill_write`` stores what the prefill took at the
+prompt's true last position, the decode step advances it, and ``release``
+clears nothing: the next occupant's prefill overwrites the row before anything
+reads it. A prefix-cache hit shares blocks of keys and values only; the sharer's
+prefill still runs over its whole prompt (shared blocks are re-written with the
+same bytes), so its recurrent state is its own. ``hbm_bytes``, ``live`` and
+``reserved`` tokens count keys and values; ``stats()["recurrent_state_bytes"]``
+reports the recurrent rows beside them.
+
 Backends:
 
 ``dense``       today's arena semantics behind the same interface
@@ -619,12 +634,31 @@ class PagedBlockPool:
 
 
 # ------------------------------------------------------------------- backends
+def _recurrent_shape(family, slots: int) -> Optional[tuple]:
+    """``(recurrent layers, slots, *state shape)``, or None for a family all of
+    whose state is keys and values."""
+    if not family.recurrent_layers:
+        return None
+    return (family.recurrent_layers, slots, *family.recurrent_shape)
+
+
+def _write_recurrent(cache, new_cache, slot) -> dict:
+    """The ``"recurrent"`` leaf with ``slot``'s row replaced by the prefill's
+    (``(layers, 1, *state shape)``); nothing where the family keeps none."""
+    if "recurrent" not in cache:
+        return {}
+    fresh = new_cache["recurrent"][:, 0].astype(cache["recurrent"].dtype)
+    return {"recurrent": cache["recurrent"].at[:, slot].set(fresh)}
+
+
 class KVCacheBackend:
     """Interface both inference paths program against. Device methods
     (``init_device_state``, ``make_layout``, ``prefill_write``) are called
     inside jitted programs; host methods manage admission and the table."""
 
     kind: str = "abstract"
+    # (recurrent layers, slots, *state shape) of a family that keeps such rows
+    _recurrent: Optional[tuple] = None
 
     # device side -----------------------------------------------------------
     def init_device_state(self):
@@ -680,6 +714,18 @@ class KVCacheBackend:
     def stats(self) -> dict:
         raise NotImplementedError
 
+    def recurrent_state_bytes(self) -> int:
+        """Bytes of the per-slot recurrent state (0 where the family's only
+        state is keys and values); not part of :meth:`hbm_bytes`."""
+        if self._recurrent is None:
+            return 0
+        return int(np.prod(self._recurrent)) * jnp.dtype(self._dtype).itemsize
+
+    def _init_recurrent(self) -> dict:
+        if self._recurrent is None:
+            return {}
+        return {"recurrent": jnp.zeros(self._recurrent, self._dtype)}
+
 
 class DenseKVBackend(KVCacheBackend):
     """Today's arena semantics behind the backend interface: one dense
@@ -692,8 +738,9 @@ class DenseKVBackend(KVCacheBackend):
         self.config = config
         self.slots = slots
         self.max_len = max_len
-        kvh = getattr(config, "num_key_value_heads", None) or config.num_attention_heads
-        self._shape = (config.num_hidden_layers, slots, max_len, kvh, config.head_dim)
+        family = config.serving_family()
+        self._shape = (family.kv_layers, slots, max_len, family.kv_heads, family.head_dim)
+        self._recurrent = _recurrent_shape(family, slots)
         self._dtype = config.compute_dtype
         # tables are inert for dense; a constant (slots, 1) zero array keeps
         # the engine's program signatures uniform across backends
@@ -703,6 +750,7 @@ class DenseKVBackend(KVCacheBackend):
         return {
             "k": jnp.zeros(self._shape, self._dtype),
             "v": jnp.zeros(self._shape, self._dtype),
+            **self._init_recurrent(),
         }
 
     def make_layout(self, tables):
@@ -713,12 +761,15 @@ class DenseKVBackend(KVCacheBackend):
         # full-row dynamic_update_slice: zeros beyond the bucket wipe every
         # stale byte of the slot's previous occupant
         return {
-            which: lax.dynamic_update_slice(
-                cache[which],
-                new_cache[which].astype(cache[which].dtype),
-                (0, slot, 0, 0, 0),
-            )
-            for which in ("k", "v")
+            **{
+                which: lax.dynamic_update_slice(
+                    cache[which],
+                    new_cache[which].astype(cache[which].dtype),
+                    (0, slot, 0, 0, 0),
+                )
+                for which in ("k", "v")
+            },
+            **_write_recurrent(cache, new_cache, slot),
         }
 
     @jax.named_scope("kv.scatter")
@@ -730,10 +781,13 @@ class DenseKVBackend(KVCacheBackend):
         idx = jnp.where(valid, idx, self.max_len)  # pushed OOB -> dropped
         rows = jnp.arange(self.slots)[:, None]
         return {
-            which: cache[which].at[:, rows, idx].set(
-                window_kv[which].astype(cache[which].dtype), mode="drop"
-            )
-            for which in ("k", "v")
+            **cache,
+            **{
+                which: cache[which].at[:, rows, idx].set(
+                    window_kv[which].astype(cache[which].dtype), mode="drop"
+                )
+                for which in ("k", "v")
+            },
         }
 
     def device_tables(self):
@@ -764,6 +818,7 @@ class DenseKVBackend(KVCacheBackend):
             "hbm_bytes": self.hbm_bytes(),
             "hbm_bytes_live": self.hbm_bytes(),
             "reserved_tokens": self.reserved_tokens(),
+            "recurrent_state_bytes": self.recurrent_state_bytes(),
         }
 
 
@@ -808,9 +863,11 @@ class PagedKVBackend(KVCacheBackend):
                 f"{block_size})"
             )
         self.pool_blocks = pool_blocks
-        kvh = getattr(config, "num_key_value_heads", None) or config.num_attention_heads
-        self._kvh, self._hd = kvh, config.head_dim
-        self._layers = config.num_hidden_layers
+        family = config.serving_family()
+        self._kvh, self._hd = family.kv_heads, family.head_dim
+        # the pool's leading axis: the layers that keep keys and values
+        self._layers = family.kv_layers
+        self._recurrent = _recurrent_shape(family, slots)
         self._dtype = config.compute_dtype
         self.kind = "paged_int8" if quantized else "paged"
         self.attention_impl = attention_impl
@@ -836,6 +893,14 @@ class PagedKVBackend(KVCacheBackend):
         self._prefetched: Dict[bytes, Any] = {}
         self.prefetch_hits = 0
         if host_tier_bytes > 0:
+            if self._recurrent is not None:
+                raise ValueError(
+                    "kv_host_tier_bytes cannot serve a family with recurrent "
+                    "state: a restored prefix skips the prompt forward that "
+                    "state comes from, and the host tier keeps no snapshot of "
+                    "it per prefix (the missing piece: recurrent-state "
+                    "snapshots keyed like the blocks)"
+                )
             self.host_tier = HostKVTier(
                 host_tier_bytes, self.host_block_bytes()
             )
@@ -851,8 +916,9 @@ class PagedKVBackend(KVCacheBackend):
                 "q": jnp.zeros(shape, jnp.int8),
                 "s": jnp.zeros(shape[:3], jnp.float32),
             }
-            return {"k": leaf(), "v": leaf()}
-        return {"k": jnp.zeros(shape, self._dtype), "v": jnp.zeros(shape, self._dtype)}
+            return {"k": leaf(), "v": leaf(), **self._init_recurrent()}
+        return {"k": jnp.zeros(shape, self._dtype), "v": jnp.zeros(shape, self._dtype),
+                **self._init_recurrent()}
 
     def make_layout(self, tables):
         return PagedKVLayout(
@@ -872,7 +938,7 @@ class PagedKVBackend(KVCacheBackend):
         module docstring invariants."""
         n, bs = self.prefill_blocks, self.block_size
         ids = table_row[:n]
-        out = {}
+        out = _write_recurrent(cache, new_cache, slot)
         for which in ("k", "v"):
             pool = cache[which]
             fresh = new_cache[which][:, 0, : n * bs]  # (L, n * bs, kvh, hd)
@@ -892,8 +958,11 @@ class PagedKVBackend(KVCacheBackend):
     def commit_window(self, cache, window_kv, tables, pos, count):
         layout = self.make_layout(tables)
         return {
-            which: layout.commit_window(cache[which], window_kv[which], pos, count)
-            for which in ("k", "v")
+            **cache,
+            **{
+                which: layout.commit_window(cache[which], window_kv[which], pos, count)
+                for which in ("k", "v")
+            },
         }
 
     # -------------------------------------------------------------- host side
@@ -1151,6 +1220,7 @@ class PagedKVBackend(KVCacheBackend):
             "hbm_bytes": self.hbm_bytes(),
             "hbm_bytes_live": self.hbm_bytes_live(),
             "reserved_tokens": self.reserved_tokens(),
+            "recurrent_state_bytes": self.recurrent_state_bytes(),
             **self.pool.stats(),
         }
         if self.host_tier is not None:
@@ -1197,7 +1267,8 @@ def pool_from_dense(cache, block_size: int, quantized: bool):
     block pool with identity tables — the bridge that lets static
     ``generate()`` run its decode scan through the same
     :class:`PagedKVLayout` gather/commit ops as the engine (one KV story,
-    bitwise parity in f32). ``total_len`` must divide by ``block_size``."""
+    bitwise parity in f32). ``total_len`` must divide by ``block_size``. A leaf
+    that is not keys or values (a family's recurrent state) passes through."""
     def relay(dense):
         L, b, total, kvh, hd = dense.shape
         nb = total // block_size
@@ -1211,4 +1282,4 @@ def pool_from_dense(cache, block_size: int, quantized: bool):
     b = cache["k"].shape[1]
     nb = cache["k"].shape[2] // block_size
     tables = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
-    return {"k": k, "v": v}, tables
+    return {**cache, "k": k, "v": v}, tables

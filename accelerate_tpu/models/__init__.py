@@ -3,3 +3,5 @@ from .bert import BertConfig, create_bert, bert_apply, bert_classification_loss,
 from .gpt2 import GPT2Config, create_gpt2, gpt2_apply, gpt2_loss, init_gpt2_params
 from .t5 import T5Config, create_t5, t5_apply, t5_loss, init_t5_params
 from .resnet import ResNetConfig, create_resnet, resnet_apply, resnet_classification_loss
+from .lfm2 import Lfm2Config, create_lfm2, lfm2_apply, lfm2_loss, init_lfm2_params
+from .family import ServingFamily

@@ -79,6 +79,18 @@ class GPT2Config:
     def intermediate_size(self) -> int:
         return 4 * self.hidden_size
 
+    def serving_family(self):
+        """What the serving path asks of a family (models/family.py): every
+        layer keeps keys and values, one head a query head."""
+        from .family import ServingFamily
+
+        return ServingFamily(
+            prefill=gpt2_prefill, prefill_at=gpt2_prefill_at,
+            decode_step=gpt2_decode_step, verify_step=gpt2_verify_step,
+            kv_layers=self.num_hidden_layers, kv_heads=self.num_attention_heads,
+            head_dim=self.head_dim,
+        )
+
     @classmethod
     def gpt2_small(cls, **overrides) -> "GPT2Config":
         return cls(**overrides)

@@ -144,6 +144,18 @@ class LlamaConfig:
             return None
         return tuple(sorted(self.rope_scaling.items()))
 
+    def serving_family(self):
+        """What the serving path asks of a family (models/family.py): every
+        layer keeps keys and values, nothing else is kept."""
+        from .family import ServingFamily
+
+        return ServingFamily(
+            prefill=llama_prefill, prefill_at=llama_prefill_at,
+            decode_step=llama_decode_step, verify_step=llama_verify_step,
+            kv_layers=self.num_hidden_layers, kv_heads=self.num_key_value_heads,
+            head_dim=self.head_dim,
+        )
+
     @classmethod
     def llama2_7b(cls, **overrides) -> "LlamaConfig":
         return cls(**{**dict(

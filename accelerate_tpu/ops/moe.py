@@ -11,6 +11,11 @@ to all-to-alls when the expert dim is sharded over the ``ep`` mesh axis, and
 the MXU stays busy on the expert FFN matmuls. Capacity bounds make every
 shape static (XLA requirement); overflow tokens are dropped (standard Switch
 behavior) and counted in the aux metrics.
+
+Beside it, :func:`dropless_moe` is the expert layer a server needs: sigmoid
+scores, every token reaches the experts it chose whatever the rest of the
+batch chose, rows sorted by expert into one grouped matmul
+(``jax.lax.ragged_dot``), and it is told which experts it holds.
 """
 
 from __future__ import annotations
@@ -22,7 +27,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Routing", "route_topk", "moe_ffn", "load_balancing_loss", "router_z_loss"]
+__all__ = [
+    "Routing", "route_topk", "moe_ffn", "load_balancing_loss", "router_z_loss",
+    "route_sigmoid_topk", "dropless_moe",
+]
 
 
 class Routing(NamedTuple):
@@ -143,3 +151,100 @@ def moe_ffn(
     # combine: (N,E,C) × (E,C,D) → (N,D)
     out = jnp.einsum("nec,ecd->nd", routing.combine.astype(compute_dtype), expert_out)
     return out.reshape(b, s, d), aux
+
+
+# ------------------------------------------------------------ dropless experts
+def route_sigmoid_topk(x, router_kernel, expert_bias, num_selected: int, *,
+                       norm_topk: bool = True, scale: float = 1.0):
+    """Sigmoid routing in float32: ``s = sigmoid(x @ W_r)``; the chosen experts
+    are the top-k of ``s + expert_bias`` (the bias takes part in the choice
+    only); the weights are ``s`` of the chosen, renormalised over them
+    (``/ (sum + 1e-6)``) when ``norm_topk``, times ``scale``. ``x`` (N, D) ->
+    ``(experts (N, k) int32, weights (N, k) float32)``. A row's routing depends
+    on that row alone."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    choose_by = scores if expert_bias is None else scores + expert_bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(choose_by, num_selected)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * scale
+
+
+def dropless_moe(
+    x: jax.Array,
+    router_kernel: jax.Array,
+    expert_bias: Optional[jax.Array],
+    w1: jax.Array,
+    w3: jax.Array,
+    w2: jax.Array,
+    *,
+    num_selected: int,
+    first: int = 0,
+    layer=None,
+    norm_topk: bool = True,
+    scale: float = 1.0,
+    compute_dtype=jnp.bfloat16,
+) -> tuple[jax.Array, jax.Array]:
+    """SwiGLU experts without capacity and without drops: every row reaches the
+    ``num_selected`` experts it chose.
+
+    ``x`` (N, D); ``router_kernel`` (D, E) and ``expert_bias`` (E,) span ALL
+    ``E`` experts; ``w1``/``w3`` (G, D, I) and ``w2`` (G, I, D) are the ``G``
+    experts held here, experts ``first .. first + G - 1`` (default: all of
+    them). Routing runs over all ``E``; the result is the part the held experts
+    give, so the shares of a layer split over chips add up to the whole layer.
+    With ``layer`` (an int or a traced scalar) the weights are every layer's,
+    stacked on a leading axis, ``(L, G, ...)``: they are handed to the grouped
+    matmul whole, as ``L * G`` groups of which only this layer's hold rows,
+    because a slice of a stacked operand of a kernel is a copy of it (PERF.md,
+    PR 30).
+
+    The (row, expert) pairs are sorted by expert, held experts first, and the
+    three matmuls are ``jax.lax.ragged_dot`` over the groups, which the TPU
+    compiler lowers to one grouped-matmul kernel each. A row's result is
+    computed from that row alone (its dot products, then its k parts summed
+    in the order of its own choice): it does not depend on what else is in
+    the batch.
+
+    The router reads ``x`` as it comes (hand it float32 and no near-tie is
+    decided by rounding); the experts read it in ``compute_dtype`` and sum in
+    float32. Returns ``(out (N, D) in x's dtype, rows (E,) int32)``: ``rows``
+    counts the rows every one of the ``E`` experts got, held here or not."""
+    n, d = x.shape
+    e = router_kernel.shape[1]
+    stacked = layer is not None
+    g = w1.shape[1] if stacked else w1.shape[0]
+    k = num_selected
+    experts, weights = route_sigmoid_topk(
+        x, router_kernel, expert_bias, k, norm_topk=norm_topk, scale=scale
+    )
+    flat = experts.reshape(n * k)
+    rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
+    # held experts become 0..g-1 and sort first; the others follow, in no group
+    local = (flat - first) % e
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.roll(rows, -first)[:g]  # the held experts' rows, in their local order
+    if stacked:
+        n_layers = w1.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * g,), jnp.int32), sizes, (layer * g,)
+        )
+        w1, w3, w2 = (w.reshape(n_layers * g, *w.shape[2:]) for w in (w1, w3, w2))
+    xs = x.astype(compute_dtype)[order // k]  # (N * k, D), sorted by expert
+
+    def grouped(lhs, rhs):
+        return jax.lax.ragged_dot(
+            lhs, rhs.astype(compute_dtype), sizes, preferred_element_type=jnp.float32
+        )
+
+    hidden = (jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)).astype(compute_dtype)
+    parts = grouped(hidden, w2)  # (N * k, D) float32
+    # rows past the last group (experts held elsewhere) were never computed
+    parts = jnp.where((local[order] < g)[:, None], parts, 0.0)
+    parts = parts[jnp.argsort(order)].reshape(n, k, d)  # back to (row, choice)
+    out = jnp.sum(parts * weights[:, :, None], axis=1)
+    return out.astype(x.dtype), rows

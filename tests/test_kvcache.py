@@ -568,3 +568,84 @@ def test_alternating_sliding_window_paged_matches_dense_bitwise():
         assert eng.stats()["programs"]["verify_step"] == 1
         outs[kv_cache] = [occ.output_row().tolist() for occ in occs]
     assert outs["paged"] == outs["dense"]
+
+
+# ------------------------------------------------------ two kinds of state
+def _lfm2_backends(slots=4, max_len=32):
+    from accelerate_tpu.models.lfm2 import Lfm2Config
+
+    config = Lfm2Config.tiny()  # 2 attention and 3 conv layers of 5, bf16 compute
+    kw = dict(config=config, slots=slots, max_len=max_len, prompt_bucket=16, block_size=8)
+    return config, {kind: make_kv_backend(kind, **kw) for kind in ("dense", "paged", "paged_int8")}
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged", "paged_int8"])
+def test_backend_allocates_both_kinds_of_state(kind):
+    """The family says what a slot keeps: keys and values over its attention
+    layers only (not the model's depth), and a recurrent row a slot over its
+    convolution layers, counted beside the keys and values, not among them."""
+    config, backends = _lfm2_backends()
+    backend = backends[kind]
+    state = backend.init_device_state()
+    assert set(state) == {"k", "v", "recurrent"}
+    assert state["recurrent"].shape == (3, 4, 2, config.hidden_size)
+    assert state["recurrent"].dtype == jnp.bfloat16
+    keys = state["k"]["q"] if kind == "paged_int8" else state["k"]
+    assert keys.shape[0] == config.attention_layers == 2
+    recurrent = 3 * 4 * 2 * config.hidden_size * 2
+    assert backend.recurrent_state_bytes() == recurrent == backend.stats()["recurrent_state_bytes"]
+    per_position = 2 * config.num_key_value_heads * config.head_dim  # two layers' heads
+    if kind == "dense":
+        assert backend.hbm_bytes() == 2 * 4 * 32 * per_position * 2
+    elif kind == "paged":
+        assert backend.hbm_bytes() == 2 * (4 * 4 + 1) * 8 * per_position * 2
+    # a family whose only state is keys and values has no third leaf and no bytes
+    plain = make_kv_backend(kind, config=LlamaConfig.tiny(), slots=4, max_len=32,
+                            prompt_bucket=16, block_size=8)
+    assert set(plain.init_device_state()) == {"k", "v"}
+    assert plain.recurrent_state_bytes() == 0 == plain.stats()["recurrent_state_bytes"]
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_prefill_write_stores_the_recurrent_row_and_release_clears_nothing(kind):
+    """``prefill_write`` replaces the slot's recurrent row and no other;
+    ``release`` leaves it (the next prefill overwrites it), and a released slot
+    taken again holds the new occupant's state."""
+    config, backends = _lfm2_backends()
+    backend = backends[kind]
+    cache = backend.init_device_state()
+
+    def prefilled(value):
+        heads = (config.num_key_value_heads, config.head_dim)
+        return {
+            "k": jnp.full((2, 1, 32, *heads), value, jnp.bfloat16),
+            "v": jnp.full((2, 1, 32, *heads), value, jnp.bfloat16),
+            "recurrent": jnp.full((3, 1, 2, config.hidden_size), value, jnp.bfloat16),
+        }
+
+    row, _ = backend.acquire(2, np.arange(10, dtype=np.int32), 6)
+    cache = backend.prefill_write(cache, prefilled(1.0), jnp.int32(2), jnp.asarray(row))
+    assert float(cache["recurrent"][:, 2].min()) == 1.0
+    assert float(jnp.abs(cache["recurrent"][:, jnp.array([0, 1, 3])]).max()) == 0.0
+    backend.release(2)
+    row, _ = backend.acquire(2, np.arange(5, dtype=np.int32) + 50, 4)
+    cache = backend.prefill_write(cache, prefilled(3.0), jnp.int32(2), jnp.asarray(row))
+    assert float(cache["recurrent"][:, 2].min()) == float(cache["recurrent"][:, 2].max()) == 3.0
+    if kind == "paged":
+        assert backend.reserved_tokens() == 16  # 9 positions in blocks of 8: keys and values only
+
+
+def test_engine_stats_count_keys_and_values_and_report_recurrent_bytes():
+    from accelerate_tpu.models.lfm2 import Lfm2Config, create_lfm2
+
+    model = create_lfm2(Lfm2Config.tiny(), seed=0)
+    eng = ContinuousBatchingEngine(model, slots=2, max_len=32, prompt_bucket=16,
+                                   kv_cache="paged", block_size=8)
+    eng.insert(list(range(1, 12)), max_new_tokens=4, pad_token_id=0)
+    stats = eng.stats()
+    assert stats["recurrent_state_bytes"] == 3 * 2 * 2 * 64 * 2
+    assert stats["kv"]["hbm_bytes"] == 2 * (2 * 4 + 1) * 8 * (2 * 2 * 16) * 2
+    assert stats["kv"]["live_tokens"] == 11 and stats["kv"]["reserved_tokens"] == 16
+    eng.drain()
+    released = eng.stats()
+    assert released["kv"]["live_tokens"] == 0 and released["recurrent_state_bytes"] == 3 * 2 * 2 * 64 * 2

@@ -219,3 +219,88 @@ def test_router_z_loss():
     np.testing.assert_allclose(
         losses[1.0] - losses[0.0], 2 * (losses[0.5] - losses[0.0]), rtol=1e-4
     )
+
+
+# ------------------------------------------------------------ the dropless layer
+def _dropless_case(rows=40, d=16, i=24, e=8, seed=0):
+    from accelerate_tpu.ops.moe import dropless_moe, route_sigmoid_topk
+
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (rows, d))
+    router = jax.random.normal(ks[1], (d, e)) / np.sqrt(d)
+    bias = 0.1 * jax.random.normal(ks[2], (e,))
+    w1 = jax.random.normal(ks[3], (e, d, i)) / np.sqrt(d)
+    w3 = jax.random.normal(ks[4], (e, d, i)) / np.sqrt(d)
+    w2 = jax.random.normal(ks[5], (e, i, d)) / np.sqrt(i)
+
+    def masked_loop(x, bias):
+        chosen, weights = route_sigmoid_topk(x, router, bias, 2)
+        out = jnp.zeros_like(x)
+        for expert in range(e):
+            weight = jnp.sum(jnp.where(chosen == expert, weights, 0.0), axis=-1)
+            out = out + weight[:, None] * ((jax.nn.silu(x @ w1[expert]) * (x @ w3[expert])) @ w2[expert])
+        return out
+
+    def layer(x, bias, **kw):
+        return dropless_moe(x, router, bias, w1, w3, w2, num_selected=2,
+                            compute_dtype=jnp.float32, **kw)
+
+    return x, bias, router, (w1, w3, w2), masked_loop, layer
+
+
+@pytest.mark.parametrize("imbalance", ["drawn", "all_rows_on_one_expert", "one_row"])
+def test_dropless_moe_loses_no_row_at_any_imbalance(imbalance):
+    """No capacity, no drop: every row gets all of its experts' parts, when the
+    rows spread as drawn, when a bias sends every row to the same two experts
+    (a group of every row, six empty groups), and for a single row."""
+    x, bias, _, _, masked_loop, layer = _dropless_case()
+    if imbalance == "all_rows_on_one_expert":
+        bias = jnp.zeros_like(bias).at[jnp.array([2, 5])].set(10.0)
+    if imbalance == "one_row":
+        x = x[:1]
+    out, rows = layer(x, bias)
+    assert float(jnp.abs(out - masked_loop(x, bias)).max()) < 1e-5
+    assert int(rows.sum()) == 2 * x.shape[0]
+    if imbalance == "all_rows_on_one_expert":
+        assert rows.tolist() == [0, 0, 40, 0, 0, 40, 0, 0]
+    assert float(jnp.abs(out).min(axis=-1).max()) > 0  # no row came back empty
+
+
+def test_dropless_moe_row_does_not_depend_on_the_batch():
+    """What drops cost a server: with them a row's result depends on who else is
+    in the batch. Here a row among strangers, among other strangers and at
+    another place in the batch comes out bit for bit the same (batches of one
+    size: a backend may pick another matmul for another shape), and alone to
+    rounding."""
+    x, bias, _, _, _, layer = _dropless_case()
+    whole, _ = layer(x, bias)
+    strangers = jax.random.normal(jax.random.key(9), x.shape).at[7].set(x[7])
+    among_others, _ = layer(strangers, bias)
+    shuffled, _ = layer(x[::-1], bias)
+    alone, _ = layer(x[7:8], bias)
+    assert np.array_equal(np.asarray(whole[7]), np.asarray(among_others[7]))
+    assert np.array_equal(np.asarray(whole[::-1]), np.asarray(shuffled))
+    assert float(jnp.abs(whole[7:8] - alone).max()) < 1e-6
+
+
+@pytest.mark.parametrize("shares", [2, 4, 8])
+def test_dropless_moe_held_shares_add_up(shares):
+    """Told which experts it holds (``first``, and as many as its weights), the
+    layer routes over all and computes its own experts' part; the parts of
+    every share add up to the whole, also when the weights are every layer's,
+    stacked, and reached by ``layer``."""
+    from accelerate_tpu.ops.moe import dropless_moe
+
+    x, bias, router, (w1, w3, w2), masked_loop, layer = _dropless_case(seed=1)
+    whole, rows = layer(x, bias)
+    held = 8 // shares
+    total = 0.0
+    for share in range(shares):
+        cut = slice(share * held, (share + 1) * held)
+        stacked = [jnp.stack([jnp.zeros_like(w[cut]), w[cut], jnp.ones_like(w[cut])]) for w in (w1, w3, w2)]
+        part, part_rows = dropless_moe(x, router, bias, *stacked, layer=1, first=share * held,
+                                       num_selected=2, compute_dtype=jnp.float32)
+        assert np.array_equal(np.asarray(part_rows), np.asarray(rows))
+        total = total + part
+    assert float(jnp.abs(total - whole).max()) < 1e-5
+    assert float(jnp.abs(whole - masked_loop(x, bias)).max()) < 1e-5

@@ -31,6 +31,7 @@ WIDTHS = {
     "gpt2_large": (20, 20, 64, 50257),
     "mistral_7b": (32, 8, 128, 32000),
     "qwen2_7b": (28, 4, 128, 152064),
+    "lfm2_8b_a1b": (32, 8, 64, 65536),
 }
 SLOTS, BLOCK_SIZE, BLOCKS_PER_ROW, POOL_BLOCKS, WINDOW = 8, 16, 128, 512, 5
 
@@ -147,6 +148,9 @@ def test_paged_flash_decode_compiles(compile_for_chip, width, quantized):
 WHOLE_POOLS = {
     "gpt2_large_serve_closed32": ("gpt2_large", 32, 64, 36, 1025),
     "mistral_7b_gqa": ("mistral_7b", 8, 128, 4, 512),
+    # LFM2's serving cell: the pool spans its 3 attention layers of 13, rows of
+    # 8 x 64 = 512 lanes, 32 slots of 1536 positions
+    "lfm2_13l_serve_closed32": ("lfm2_8b_a1b", 32, 96, 3, 3073),
 }
 
 
@@ -195,7 +199,8 @@ def test_paged_flash_verify_compiles(compile_for_chip, width, quantized):
 # fast memory than the 16 MB a kernel gets unasked (and 15 s of compiling)
 @pytest.mark.parametrize(
     "width,rows",
-    [("gpt2_large", 8), ("gpt2_large", 5), ("mistral_7b", 8), ("qwen2_7b", 8)],
+    [("gpt2_large", 8), ("gpt2_large", 5), ("mistral_7b", 8), ("qwen2_7b", 8),
+     ("lfm2_8b_a1b", 32)],
 )
 def test_fused_sample_compiles(compile_for_chip, width, rows):
     vocab = WIDTHS[width][3]
@@ -205,3 +210,36 @@ def test_fused_sample_compiles(compile_for_chip, width, rows):
         functools.partial(fused_sample, interpret=False),
         logits, logits, knob(jnp.float32), knob(jnp.int32), knob(jnp.float32),
     )
+
+
+# LFM2's expert layer at its published widths: 12 expert layers of 32 experts,
+# 8.5 GB that nothing may copy. 128 rows (32 slots x 4 experts) is the decode
+# step's shape, 2048 (a bucket of 512) the prefill's.
+@pytest.mark.parametrize("tokens", [32, 512], ids=["decode_32_slots", "prefill_512"])
+def test_dropless_moe_compiles_on_the_stacked_experts(compile_for_chip, tokens):
+    import re
+
+    from accelerate_tpu.ops.moe import dropless_moe
+
+    layers, experts, hidden, width = 12, 32, 2048, 1792
+
+    def layer(x, router, bias, w1, w3, w2, index):
+        return dropless_moe(x, router, bias, w1, w3, w2, layer=index, num_selected=4)
+
+    up = ((layers, experts, hidden, width), jnp.bfloat16)
+    text = compile_for_chip(
+        layer, ((tokens, hidden), jnp.bfloat16), ((hidden, experts), jnp.bfloat16),
+        ((experts,), jnp.bfloat16), up, up, ((layers, experts, width, hidden), jnp.bfloat16),
+        ((), jnp.int32),
+    )
+    # the chip's compiler makes one grouped-matmul kernel of each ragged_dot,
+    # named as chipbench/metrics/moe_experts_roofline.serve.py looks for it
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3
+    # and is handed the whole stack as layers x experts groups: a layer's slice
+    # of it as a kernel's operand would be a copy of 235 MB
+    stack = rf"= bf16\[({layers},{experts}|{layers * experts}|{experts}),({hidden},{width}|{width},{hidden})\]"
+    made = [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(stack, line) and " bitcast(" not in line and " parameter(" not in line
+    ]
+    assert not made, made[:2]
