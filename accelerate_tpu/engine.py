@@ -75,6 +75,7 @@ from jax import lax
 
 from . import perfwatch, tracing
 from .logging import get_logger
+from .ops.paged_decode import decode_walked_positions
 from .utils.fault import (
     EngineCapacityError,
     EngineInvariantError,
@@ -134,6 +135,15 @@ class SlotOccupant:
         out[: len(self.prompt)] = self.prompt
         out[len(self.prompt) : len(self.prompt) + len(self.tokens)] = self.tokens
         return out
+
+
+def _held_positions(occ: Optional[SlotOccupant]) -> int:
+    """Positions of keys and values a slot holds that still count: the prompt
+    (as far as its chunks have got) and the tokens emitted; 0 for a slot that
+    is vacant or whose request has finished."""
+    if occ is None or occ.finished:
+        return 0
+    return (occ.prefill_pos if occ.prefilling else len(occ.prompt)) + len(occ.tokens)
 
 
 @dataclass
@@ -1465,6 +1475,7 @@ class ContinuousBatchingEngine:
             decoding=self._decoding_count(), slots=self.slots,
             kv_live_tokens=self.live_tokens(),
             kv_reserved_tokens=self._backend.reserved_tokens(),
+            kv_walked_tokens=self.walked_tokens(),
         ):
             self._donated, self._carried, counters = self._decode_jit(
                 self._donated, self._carried, self.model.params,
@@ -1907,10 +1918,22 @@ class ContinuousBatchingEngine:
     def live_tokens(self) -> int:
         """Positions actually holding useful KV right now: each live
         occupant's prompt + emitted tokens (host-side, no device sync)."""
+        return sum(_held_positions(o) for o in self._occupants)
+
+    def walked_tokens(self) -> int:
+        """Positions the paged decode kernel computes on, for one layer, in a
+        step dispatched now: the terms of :meth:`live_tokens`, each rounded up
+        to the kernel's chunk, and one chunk for every slot that holds nothing
+        (vacant or finished: it rides masked and still costs its chunk; a
+        retired slot's device position stays where it was until the next
+        insert, which this does not see). 0 where that kernel does not run:
+        without a block pool, or with the reference attention over one."""
+        block_size = getattr(self._backend, "block_size", 0)
+        if not block_size or self.attention_impl != "pallas":
+            return 0
         return sum(
-            (o.prefill_pos if o.prefilling else len(o.prompt)) + len(o.tokens)
+            decode_walked_positions(_held_positions(o), block_size)
             for o in self._occupants
-            if o is not None and not o.finished
         )
 
     def stats(self) -> dict:

@@ -968,7 +968,12 @@ class PagedKVBackend(KVCacheBackend):
     # -------------------------------------------------------------- host side
     def device_tables(self):
         if self._device_tables_cache is None:
-            self._device_tables_cache = jnp.asarray(self.pool.tables)
+            # a copy of its own: on the CPU client `jnp.asarray` takes a
+            # 64-byte-aligned numpy array without copying it, and a step
+            # still in flight would then read the rows that the next
+            # `acquire` or `release` writes (a vacant slot scattering its
+            # keys through a row installed after the step was dispatched)
+            self._device_tables_cache = jnp.asarray(self.pool.tables.copy())
         return self._device_tables_cache
 
     def acquire(self, slot, prompt, budget, defer_register: bool = False):

@@ -7,27 +7,44 @@ table entry, live or null, is materialized and, for int8 pools, dequantized
 in full) rather than with live tokens. The kernels here walk each slot's
 block table *inside* the kernel instead:
 
-* **`paged_flash_decode`** — one query token per slot. Grid ``(slots,
-  blocks_per_row)``; the block tables and per-slot positions ride in as
-  scalar-prefetch operands so every kv tile's BlockSpec index map resolves
-  ``tables[slot, j]`` directly — the DMA fetches pool block
-  ``tables[slot, j]`` whole (all KV heads: one contiguous copy, and the one
-  tile of a position-major pool the TPU lowering accepts), nothing else.
-  Blocks wholly past a slot's position are *skipped* (``@pl.when``), never
-  partially weighted — exactly the contract documented on
-  ``paged_attention`` (masked scores softmax to an exp-underflow-exact 0.0,
-  so skipping == computing). For a live slot the skipped tail *is* the
-  row's null-block padding (allocation covers every position ``<= pos``),
-  so released/unallocated entries are never read as real context. int8
-  pools dequantize per fetched tile from the per-(block, position) scales —
-  only live blocks' scales are ever applied. Online softmax (acc/m/l VMEM
-  scratch, init at j==0, finalize at the last block) with the grouped-GQA
-  layout: q is ``(h_kv, n_rep, d)`` and the kernel loops the KV heads of
-  the fetched block, so KV is read once per *group*, never repeated
-  ``n_rep``×.
+* **`paged_flash_decode`** — one query token per slot. Grid ``(slots,)``;
+  the pools come in whole and stay in HBM (``memory_space=pl.ANY``), the
+  block tables and per-slot positions ride in as scalar-prefetch operands,
+  and the kernel walks a slot's own ``ceil((pos + 1) / T)`` chunks of ``T``
+  positions (``decode_chunk_positions``: the blocks that fill a 128-lane
+  score tile) in a loop. A chunk's live blocks are copied by
+  ``pltpu.make_async_copy`` — pool block ``tables[slot, j]`` whole (all KV
+  heads: one contiguous copy) — into one of two buffers, so that the next
+  chunk, or the next slot's first, is in flight while this one is computed.
+  Blocks wholly past a slot's position are *neither fetched nor weighted*
+  — exactly the contract documented on ``paged_attention`` (masked scores
+  softmax to an exp-underflow-exact 0.0, so skipping == computing): a dead
+  grid step no longer exists, and what a dead block's rows of the buffer
+  still hold from an earlier chunk is read as zeros (0 x a stale NaN would
+  be a NaN in ``P x V``). For a live slot the skipped tail *is* the row's
+  null-block padding (allocation covers every position ``<= pos``), so
+  released/unallocated entries are never read as real context. A chunk's
+  heads are computed together with the positions on the lane axis: the
+  query is ``(rows, h_kv * d)``, one row a query head and zero outside the
+  lanes of its own KV head, so ``S = Q . K^T`` is one matmul into a
+  ``(rows, T)`` f32 tile, the online softmax (running max and sum per row)
+  works on whole tiles, and ``P x V`` is one matmul into a ``(rows, h_kv *
+  d)`` f32 accumulator of which row ``r`` is read on its own head's lanes
+  only. That spends ``h_kv`` times the FLOPs on an MXU that was idle and is
+  the same algorithm for every ``n_rep`` and ``head_dim``: KV is read once
+  per *group*, never repeated ``n_rep``×. int8 pools stay int8 in HBM and in
+  the buffers; their per-(block, position) scales go on the score and weight
+  columns (int8 values are exact in bf16) — only live columns' scales are
+  ever applied.
 
-* **`paged_flash_verify`** — the W-token speculative-verify window. Same
-  table walk over committed history, masked *strictly* ``k_pos < pos``
+* **`paged_flash_verify`** — the W-token speculative-verify window. It
+  keeps the walk the decode kernel had before: grid ``(slots,
+  blocks_per_row + 1)``, the table walk in the kv tiles' BlockSpec index
+  map (one block a grid step, a dead block's step skipped by ``@pl.when``
+  but still paid), a loop over the KV heads of the fetched block with the
+  grouped-GQA layout (q is ``(h_kv, n_rep * W, d)``), online softmax in
+  acc/m/l VMEM scratch, int8 dequantized per fetched tile. Committed
+  history is masked *strictly* ``k_pos < pos``
   (the window's own columns are NOT in the pool — the engine commits only
   the accepted prefix afterwards); one extra grid step attends the window
   K/V operands causally (``k_idx <= q_idx``), reproducing
@@ -71,7 +88,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
 
-__all__ = ["paged_flash_decode", "paged_flash_verify", "fused_sample"]
+__all__ = [
+    "paged_flash_decode", "paged_flash_verify", "fused_sample",
+    "decode_chunk_positions", "decode_walked_positions",
+]
 
 
 def _dot_f32(a, b, transpose_b=False):
@@ -113,46 +133,169 @@ def _load_kv_head(k_ref, v_ref, g, d, scales):
     return k, v
 
 
-def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   block_size, h_kv, d, scale, softcap, quantized):
+# One chunk of the decode kernel's walk is this many positions: the lane
+# width of a score tile. A chunk is `decode_chunk_positions(block_size)`
+# positions, a whole number of blocks; the engine's `kv_walked_tokens` counter
+# is reckoned from the same two functions, so the two cannot drift.
+_CHUNK_LANES = 128
+
+
+def decode_chunk_positions(block_size: int) -> int:
+    """Positions the decode kernel folds into its softmax at a time: the
+    blocks that fill a 128-lane score tile (one block where a block is
+    wider)."""
+    return max(_CHUNK_LANES // block_size, 1) * block_size
+
+
+def decode_walked_positions(live: int, block_size: int) -> int:
+    """Positions `paged_flash_decode` computes on for a slot that holds
+    ``live`` positions (its ``pos + 1``): whole chunks, and one chunk for a
+    slot that holds nothing (a vacant slot rides at position 0)."""
+    chunk = decode_chunk_positions(block_size)
+    return max(-(-live // chunk), 1) * chunk
+
+
+def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
+                   block_size, chunk_blocks, blocks_per_row, n_rep, d, scale,
+                   softcap, quantized):
     if quantized:
-        *scales, o_ref, acc_ref, m_ref, l_ref = rest
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, acc_ref, base_ref = rest
     else:
-        scales = None
-        o_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, kbuf, vbuf, sem, acc_ref, base_ref = rest
+    bs, C = block_size, chunk_blocks
+    T = C * bs
+    rows, lanes = acc_ref.shape
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
+    nb = pl.num_programs(0)
     p = pos_ref[b]
+    # chunks that hold a position <= pos; never past the row's table, and at
+    # least one: a slot that walked none (pos < 0) would start no copy for
+    # the slot after it, which then waits for ever
+    n = jnp.clip(p // T, 0, (blocks_per_row - 1) // C) + 1
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def each_live_block(slot_b, chunk, buf, fn):
+        """``fn(copy_k, copy_v, jj, live)`` for each block of ``chunk`` of
+        slot ``slot_b``'s row; a block is live when its first position is
+        <= the slot's position (and it is in the table at all)."""
+        pp = pos_ref[slot_b]
 
-    # Block j holds positions [j*bs, (j+1)*bs): skip it entirely once its
-    # first position is past the query — the paged_attention contract (a
-    # masked block's softmax weight is exactly 0, so skip == compute). For
-    # live slots every surviving j is a real allocated block (allocation
-    # covers all positions <= pos), so the skipped tail IS the row's
-    # null-block padding. Block 0 (positions <= pos always non-empty at
-    # j==0 since pos >= 0) guarantees l > 0 at finalize.
-    @pl.when(j * block_size <= p)
-    def _compute():
-        for g in range(h_kv):
-            q = q_ref[0, g]  # (n_rep, d) — the kv head's whole GQA group
-            k, v = _load_kv_head(k_ref, v_ref, g, d, scales)
-            s = _dot_f32(q, k, transpose_b=True) * scale  # (n_rep, bs), f32
-            if softcap is not None:  # Gemma-2 tanh capping, pre-mask
-                s = softcap * jnp.tanh(s / softcap)
-            k_pos = j * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos <= p, s, NEG_INF)
-            _online_softmax_update(g, s, v, acc_ref, m_ref, l_ref)
+        def block(jj, carry):
+            j = chunk * C + jj
+            live = jnp.logical_and(j * bs <= pp, j < blocks_per_row)
+            blk = tables_ref[slot_b, jnp.minimum(j, blocks_per_row - 1)]
+            fn(
+                pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[buf, jj], sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[buf, jj], sem.at[1, buf]),
+                jj, live,
+            )
+            return carry
 
-    @pl.when(j == nb - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        # unrolled: rolled, the descriptors' scalar work reads 7-12% slower a call
+        lax.fori_loop(0, C, block, 0, unroll=True)
+
+    def start(slot_b, chunk, buf):
+        def fn(copy_k, copy_v, jj, live):
+            @pl.when(live)
+            def _():
+                copy_k.start()
+                copy_v.start()
+        each_live_block(slot_b, chunk, buf, fn)
+
+    def wait(slot_b, chunk, buf):
+        def fn(copy_k, copy_v, jj, live):
+            @pl.when(live)
+            def _():
+                copy_k.wait()  # graft: wait-ok — a DMA semaphore in the kernel, not a thread
+                copy_v.wait()  # graft: wait-ok
+
+            # A block past the slot's position is never fetched: its rows of
+            # the buffer hold whatever an earlier chunk left there. Its
+            # scores are masked below, but a weight of exactly 0 times a
+            # stale NaN is a NaN in P x V: its value rows read as zeros.
+            @pl.when(jnp.logical_not(live))
+            def _():
+                vbuf[buf, jj] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+        each_live_block(slot_b, chunk, buf, fn)
+
+    @pl.when(b == 0)
+    def _first():
+        base_ref[0] = 0
+        start(0, 0, 0)
+
+    base = base_ref[0]  # chunks walked by the slots before this one
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk_tiles(buf):
+        """The chunk's keys and values as two (T, lanes) tiles in the
+        operands' dtype (an int8 pool's blocks are widened as they are read;
+        their scales go on the score and weight columns)."""
+        def tile(ref):
+            blocks = [ref[buf, jj] for jj in range(C)]
+            if quantized:
+                blocks = [x.astype(jnp.float32).astype(q_ref.dtype) for x in blocks]
+            return blocks[0] if C == 1 else jnp.concatenate(blocks, axis=0)
+        return tile(kbuf), tile(vbuf)
+
+    def body(i, carry):
+        m_prev, l_prev = carry
+        # the next chunk, or the next slot's first, is in flight into the
+        # other buffer while this one is computed
+        buf = (base + i) % 2
+
+        @pl.when(i + 1 < n)
+        def _next_chunk():
+            start(b, i + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(i + 1 == n, b + 1 < nb))
+        def _next_slot():
+            start(b + 1, 0, 1 - buf)
+
+        wait(b, i, buf)
+        k, v = chunk_tiles(buf)
+        # row r is query head r against its own kv head's keys: q's row is
+        # zero outside that head's lanes
+        s = _dot_f32(q_ref[0], k, transpose_b=True) * scale  # (rows, T), f32
+        if quantized:
+            s = s * ks_ref[0, i]
+        if softcap is not None:  # Gemma-2 tanh capping, pre-mask
+            s = softcap * jnp.tanh(s / softcap)
+        live = i * T + lax.broadcasted_iota(jnp.int32, s.shape, 1) <= p
+        s = jnp.where(live, s, NEG_INF)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        pexp = jnp.exp(s - m_cur)
+        l_cur = alpha * l_prev + jnp.sum(pexp, axis=-1, keepdims=True)
+        if quantized:  # select, not multiply: a dead block's scale is anything
+            pexp = jnp.where(live, pexp * vs_ref[0, i], 0.0)
+        acc_ref[...] = acc_ref[...] * alpha + _dot_f32(pexp.astype(v.dtype), v)
+        return m_cur, l_cur
+
+    # chunk 0 holds position 0 <= pos, so l > 0 at the end
+    m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+    _, l = lax.fori_loop(0, n, body, (m0, jnp.zeros((rows, 1), jnp.float32)))
+    base_ref[0] = base + n
+
+    # Row r of the accumulator is right on its own kv head's lanes only.
+    # Heads narrower than a 128-lane tile are picked a whole tile at a time
+    # (a select on aligned lanes), then each row takes its own head's part of
+    # that tile; slicing every head's lanes out on its own costs a lane
+    # rotation a head (1.4 us a slot at 20 heads of 64; my chip run, PR 31).
+    width = 128 if 128 % d == 0 and lanes % 128 == 0 else d
+    per = width // d  # heads a tile
+    row = lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+
+    def rows_of(first_head, heads):
+        return jnp.logical_and(row >= first_head * n_rep, row < (first_head + heads) * n_rep)
+
+    tile = jnp.zeros((rows, width), jnp.float32)
+    later = [jnp.zeros((rows, width), jnp.bool_)] * (per - 1)  # rows of a tile's 2nd, 3rd.. head
+    for t in range(lanes // width):
+        tile = jnp.where(rows_of(t * per, per), acc_ref[:, t * width:(t + 1) * width], tile)
+        later = [jnp.logical_or(m, rows_of(t * per + s, 1)) for s, m in enumerate(later, 1)]
+    out = tile[:, :d]
+    for s, m in enumerate(later, 1):
+        out = jnp.where(m[:, :d], tile[:, s * d:(s + 1) * d], out)
+    o_ref[0] = (out / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _flat_pools(pools, scales, block_tables, layer, h, d):
@@ -193,6 +336,18 @@ def _gathered_scale_columns(scale, block_tables):
     return scale[block_tables][..., None]
 
 
+def _gathered_scale_rows(scale, block_tables, n_chunks, chunk):
+    """Per-slot scale rows for the decode kernel: ``scale`` (blocks, bs) →
+    (B, n_chunks, 1, chunk), a chunk's positions along the lanes as its
+    scores have them, gathered through the tables by XLA (4 bytes a position
+    beside the ``2 * h_kv * d`` the kernel reads for it; only live columns
+    are ever applied)."""
+    b = block_tables.shape[0]
+    flat = scale[block_tables].reshape(b, -1)
+    flat = jnp.pad(flat, ((0, 0), (0, n_chunks * chunk - flat.shape[1])))
+    return flat.reshape(b, n_chunks, 1, chunk)
+
+
 def paged_flash_decode(
     q: jax.Array,
     k_pool: jax.Array,
@@ -221,10 +376,13 @@ def paged_flash_decode(
 
     HBM bytes per step are ``live_blocks * block_size * h_kv * d *
     itemsize * 2`` (+ scales) instead of the reference gather's
-    ``B * blocks_per_row * block_size * ...`` materialization: the table
-    walk happens in the BlockSpec index map, so only addressed blocks are
-    DMA'd, dead tail blocks are compute-skipped, and int8 stays int8 in HBM
-    (dequantized per tile in VMEM). ``scale`` defaults to ``1/sqrt(d)``;
+    ``B * blocks_per_row * block_size * ...`` materialization: the kernel
+    copies a slot's live blocks itself, a chunk of
+    :func:`decode_chunk_positions` positions at a time, dead tail blocks are
+    neither fetched nor computed on, and int8 stays int8 in HBM (widened per
+    chunk in VMEM). Precision: operands in the pool's dtype, scores, running
+    max, sum and accumulator in float32, ``P`` rounded to the values' dtype
+    before the second matmul. ``scale`` defaults to ``1/sqrt(d)``;
     the model path passes its ``query_pre_attn_scalar`` override.
     ``softcap`` is the static Gemma-2 tanh cap. Sliding-window masking is
     NOT supported — callers with a sliding-window config must use the
@@ -238,46 +396,66 @@ def paged_flash_decode(
     )
     n_rep = h // h_kv
     bpr = block_tables.shape[1]
+    lanes = h_kv * d
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     interpret = _resolve_interpret(interpret)
     quantized = k_scale is not None
+    chunk = decode_chunk_positions(bs)
+    chunk_blocks = chunk // bs
+    n_chunks = -(-bpr // chunk_blocks)
 
-    qg = q.reshape(b, h_kv, n_rep, d)
-    q_spec = pl.BlockSpec((1, h_kv, n_rep, d), lambda bb, j, t, p: (bb, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, h_kv * d), lambda bb, j, t, p: (t[bb, j], 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [qg, k_pool, v_pool]
+    # one row a query head, zero outside the lanes of its own kv head, padded
+    # to whole sublane tiles of the operand's dtype. The query is laid flat
+    # first (row i: each group's i-th head on its group's lanes) and then
+    # repeated over the kv heads and selected, so that no head's d lanes have
+    # to be moved into a row of h_kv * d by a relayout
+    sublanes = 8 * max(4 // q.dtype.itemsize, 1)
+    rows = -(-h // sublanes) * sublanes
+    qi = q.reshape(b, h_kv, n_rep, d).transpose(0, 2, 1, 3).reshape(b, 1, n_rep, lanes)
+    own = jnp.repeat(jnp.eye(h_kv, dtype=bool), d, axis=1)  # (h_kv, lanes)
+    qx = jnp.where(own[None, :, None, :], qi, 0).reshape(b, h, lanes)
+    qx = jnp.pad(qx, ((0, 0), (0, rows - h), (0, 0)))
+
+    q_spec = pl.BlockSpec((1, rows, lanes), lambda bb, t, p: (bb, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, pool_spec, pool_spec]
+    args = [qx, k_pool, v_pool]
     if quantized:
-        s_spec = pl.BlockSpec((1, 1, bs, 1), lambda bb, j, t, p: (bb, j, 0, 0))
+        s_spec = pl.BlockSpec((1, n_chunks, 1, chunk), lambda bb, t, p: (bb, 0, 0, 0))
         in_specs += [s_spec, s_spec]
         args += [
-            _gathered_scale_columns(k_scale, block_tables),
-            _gathered_scale_columns(v_scale, block_tables),
+            _gathered_scale_rows(k_scale, block_tables, n_chunks, chunk),
+            _gathered_scale_rows(v_scale, block_tables, n_chunks, chunk),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, bpr),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=pl.BlockSpec((1, rows, d), lambda bb, t, p: (bb, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h_kv, n_rep, d), jnp.float32),
-            pltpu.VMEM((h_kv, n_rep, 1), jnp.float32),
-            pltpu.VMEM((h_kv, n_rep, 1), jnp.float32),
+            pltpu.VMEM((2, chunk_blocks, bs, lanes), k_pool.dtype),
+            pltpu.VMEM((2, chunk_blocks, bs, lanes), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((rows, lanes), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _decode_kernel, block_size=bs, h_kv=h_kv, d=d, scale=scale,
-            softcap=softcap, quantized=quantized,
+            _decode_kernel, block_size=bs, chunk_blocks=chunk_blocks,
+            blocks_per_row=bpr, n_rep=n_rep, d=d, scale=scale, softcap=softcap,
+            quantized=quantized,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h_kv, n_rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        # the walk carries its buffers from one slot to the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
     )(block_tables, pos.astype(jnp.int32), *args)
-    return out.reshape(b, 1, h, d)
+    return out[:, :h].reshape(b, 1, h, d)
 
 
 # ------------------------------------------------------------ verify kernel

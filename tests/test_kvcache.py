@@ -440,6 +440,59 @@ def test_paged_pool_hbm_is_smaller_and_stats_track_live_tokens(model, get_engine
     assert eng.peak_live == 1
 
 
+@pytest.mark.parametrize(
+    "kv_cache,attention_impl", [("paged", "pallas"), ("paged", "reference"), ("dense", "reference")]
+)
+def test_walked_tokens_count_whole_chunks_of_the_decode_kernel(model, kv_cache, attention_impl):
+    """`kv_walked_tokens` of the `engine.decode_step` span: each slot's live
+    positions rounded up to the decode kernel's chunk (the kernel's own
+    function), a chunk for the vacant slot; nothing where that kernel does not
+    run: the reference attention over a pool, a cache without one."""
+    from accelerate_tpu.ops.paged_decode import decode_chunk_positions, decode_walked_positions
+
+    eng = ContinuousBatchingEngine(
+        model, slots=2, max_len=64, prompt_bucket=16, kv_cache=kv_cache,
+        block_size=8, attention_impl=attention_impl,
+    )
+    chunk = decode_chunk_positions(8) if attention_impl == "pallas" else 0
+    assert eng.walked_tokens() == 2 * chunk  # both vacant
+    occ = eng.insert(_prompts(1, seed=31)[0], max_new_tokens=6, pad_token_id=0)
+    live = len(occ.prompt) + len(occ.tokens)
+    assert eng.live_tokens() == live
+    if attention_impl == "pallas":
+        assert eng.walked_tokens() == decode_walked_positions(live, 8) + chunk
+    else:
+        assert eng.walked_tokens() == 0
+    eng.drain()
+    assert eng.walked_tokens() == 2 * chunk
+
+
+def test_device_tables_are_a_copy_of_the_hosts_rows(model):
+    """The tables a step is dispatched with stay as they were when the next
+    admission writes its row. On the CPU client `jnp.asarray` takes a numpy
+    array that lies on a 64-byte boundary without copying it (one process in
+    a few gets such tables from the allocator), and a step still in flight
+    then read the new row: a vacant slot scattered its keys into the shared
+    prefix blocks of the request beside it, which decoded other tokens
+    (`test_lfm2.py::test_a_prompt_sent_twice...`, 4 of 8 such trials; PR 31)."""
+    backend = make_kv_backend(
+        "paged", config=model.config, slots=4, max_len=48, prompt_bucket=16, block_size=4,
+    )
+    tables = backend.pool.tables
+    raw = np.zeros(tables.nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    backend.pool.tables = (
+        raw[start:start + tables.nbytes].view(tables.dtype).reshape(tables.shape)
+    )
+    prompt = _prompts(1, lens=(11,), seed=5)[0]
+    backend.acquire(0, prompt, 8)
+    dispatched = backend.device_tables()
+    seen = np.array(dispatched)
+    backend.acquire(1, prompt, 8)  # shares the first's two whole blocks
+    np.testing.assert_array_equal(np.asarray(dispatched), seen)
+    assert np.asarray(backend.device_tables())[1, 0] == seen[0, 0] != seen[1, 0]
+
+
 # --------------------------------------------------------- static generate()
 def test_generate_paged_backends_match_dense(model):
     rng = np.random.default_rng(29)

@@ -19,6 +19,8 @@ from accelerate_tpu.engine import _sample_rows
 from accelerate_tpu.kvcache import PagedKVLayout
 from accelerate_tpu.ops.attention import paged_attention, verify_attention
 from accelerate_tpu.ops.paged_decode import (
+    decode_chunk_positions,
+    decode_walked_positions,
     fused_sample,
     paged_flash_decode,
     paged_flash_verify,
@@ -108,6 +110,141 @@ def test_decode_int8_with_zero_scale_blocks():
             q, kq, vq, tables, pos, k_scale=ks, v_scale=vs, interpret=True
         ),
     )
+
+
+# ------------------------------------------------ the walk's own edges (PR 31)
+# The decode kernel walks a slot's live blocks a chunk of T positions at a
+# time, every head a row of one matmul. At real widths: blocks of 16, so T is
+# 128 and a chunk 8 blocks; rows of 20 blocks, which 8 does not divide.
+WALK_BS, WALK_BPR, WALK_LAYERS = 16, 20, 3
+T = decode_chunk_positions(WALK_BS)
+# (query heads, kv heads, head_dim): GPT-2 large, LFM2, and a quarter of Mistral
+WALK_HEADS = {"20x20x64": (20, 20, 64), "32x8x64": (32, 8, 64), "8x2x128": (8, 2, 128)}
+# what the kernel is allowed against the reference in float32 on the same
+# stored values: exact arithmetic's order apart in f32; P rounded to bf16 (and
+# an int8 pool's weights times their scales) otherwise
+WALK_ATOL = {"f32": 2e-5, "bf16": 2e-2, "int8": 4e-2}
+
+
+def _walk_case(kind, heads, positions, seed, *, vacant=(), poisoned=()):
+    """Stacked pools ``(L, blocks, bs, h_kv * d)`` as the engine stores them,
+    one row of blocks a slot. A slot owns the blocks that hold its positions
+    ``<= pos``; ``vacant`` slots own nothing and their table is all null
+    (block 0); ``poisoned`` slots own blocks of NaN. Every block no healthy
+    slot owns, block 0 among them, is NaN (for int8: its scales are), and
+    every table entry past a slot's live blocks points at one. Returns the
+    operands and a float32 reference pool with the poison washed out."""
+    h, h_kv, d = heads
+    rng = np.random.default_rng(seed)
+    b = len(positions)
+    blocks = 1 + b * WALK_BPR
+    shape = (WALK_LAYERS, blocks, WALK_BS, h_kv * d)
+    tables = np.zeros((b, WALK_BPR), np.int32)
+    healthy = np.zeros(blocks, bool)
+    for slot, pos in enumerate(positions):
+        if slot in vacant:
+            continue
+        n_live = pos // WALK_BS + 1
+        own = 1 + slot * WALK_BPR + rng.permutation(WALK_BPR)
+        tables[slot, :n_live] = own[:n_live]
+        # dead entries: some other slot's row, never this one's live blocks
+        tables[slot, n_live:] = 1 + ((slot + 1) % b) * WALK_BPR + rng.integers(
+            0, WALK_BPR, WALK_BPR - n_live)
+        if slot not in poisoned:
+            healthy[own[:n_live]] = True
+    dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.bfloat16}[kind]
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), dtype)
+    if kind == "int8":
+        k = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        scales = [rng.uniform(1e-3, 2e-2, size=shape[:3]).astype(np.float32) for _ in range(2)]
+        for s in scales:
+            s[:, ~healthy] = np.nan
+        scales = tuple(jnp.asarray(s) for s in scales)
+        ref = [jnp.nan_to_num(x.astype(jnp.float32) * s[..., None]) for x, s in zip((k, v), scales)]
+    else:
+        k, v = (rng.normal(size=shape).astype(np.float32) for _ in range(2))
+        k[:, ~healthy] = np.nan
+        v[:, ~healthy] = np.nan
+        k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+        scales = None
+        ref = [jnp.nan_to_num(x.astype(jnp.float32)) for x in (k, v)]
+    return q, k, v, scales, jnp.asarray(tables), jnp.asarray(positions, jnp.int32), ref
+
+
+def _walk_reference(q, ref, tables, pos, layer, heads):
+    _, h_kv, d = heads
+    kr, vr = (x[layer].reshape(x.shape[1], WALK_BS, h_kv, d) for x in ref)
+    return paged_attention(q.astype(jnp.float32), kr, vr, tables, pos)
+
+
+@pytest.mark.parametrize("layer", [0, 1, WALK_LAYERS - 1], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("heads", list(WALK_HEADS))
+def test_decode_walk_edges_match_reference(heads, kind, layer):
+    # pos at 0, at a chunk's last position, at the next chunk's first and
+    # second, at the row's last (a chunk of 4 blocks, not 8), and a vacant
+    # slot with an all-null table beside that full one
+    positions = [0, T - 1, T, T + 1, WALK_BPR * WALK_BS - 1, 0]
+    q, k, v, scales, tables, pos, ref = _walk_case(
+        kind, WALK_HEADS[heads], positions, seed=100 + layer, vacant={5})
+    out = jax.jit(
+        lambda layer: paged_flash_decode(
+            q, k, v, tables, pos, layer=layer, interpret=True, **_scale_kwargs(scales))
+    )(jnp.int32(layer))
+    assert out.shape == q.shape and out.dtype == q.dtype
+    live = slice(0, 5)  # the vacant slot reads the null block: poison by design here
+    want = _walk_reference(q, ref, tables, pos, layer, WALK_HEADS[heads])
+    _assert_close(want[live], out[live].astype(jnp.float32), atol=WALK_ATOL[kind])
+
+
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("heads", list(WALK_HEADS))
+def test_decode_never_reads_what_a_slot_does_not_own(heads, kind):
+    # Slots 0 and 2 hold NaN in their own blocks (a request that diverged);
+    # every block nobody healthy owns is NaN and every dead table entry points
+    # at one. Slots 1 and 3 walk right after them, into buffers their chunks
+    # left full of NaN: short rows, a part chunk, a chunk with dead blocks.
+    positions = [2 * T + 40, 5, T + 70, T + 12]
+    q, k, v, scales, tables, pos, ref = _walk_case(
+        kind, WALK_HEADS[heads], positions, seed=7, poisoned={0, 2})
+    out = paged_flash_decode(
+        q, k, v, tables, pos, layer=jnp.int32(1), interpret=True, **_scale_kwargs(scales)
+    ).astype(jnp.float32)
+    want = _walk_reference(q, ref, tables, pos, 1, WALK_HEADS[heads])
+    for slot in (1, 3):
+        assert np.isfinite(np.asarray(out[slot])).all()
+        _assert_close(want[slot], out[slot], atol=WALK_ATOL[kind])
+    for slot in (0, 2):  # and the poison is real: its owners do read it
+        assert np.isnan(np.asarray(out[slot])).any()
+
+
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_decode_slot_at_a_negative_position_reads_nothing(kind):
+    # No caller passes one, but the op is public: such a slot walks one chunk
+    # with no live block in it, gives zeros as the walk over the grid did, and
+    # still starts the next slot's first copies (first of the grid and between
+    # two slots: a slot that walked no chunk would leave its successor waiting
+    # on copies nobody started).
+    positions = [-1, T + 1, -1, 5]
+    q, k, v, scales, tables, pos, ref = _walk_case(
+        kind, WALK_HEADS["32x8x64"], [max(p, 0) for p in positions], seed=11, vacant={0, 2})
+    pos = jnp.asarray(positions, jnp.int32)
+    out = paged_flash_decode(
+        q, k, v, tables, pos, layer=jnp.int32(2), interpret=True, **_scale_kwargs(scales)
+    ).astype(jnp.float32)
+    want = _walk_reference(q, ref, tables, jnp.maximum(pos, 0), 2, WALK_HEADS["32x8x64"])
+    for slot in (1, 3):
+        _assert_close(want[slot], out[slot], atol=WALK_ATOL[kind])
+    for slot in (0, 2):
+        assert not np.asarray(out[slot]).any()
+
+
+@pytest.mark.parametrize("block_size,chunk", [(4, 128), (16, 128), (128, 128), (256, 256)])
+def test_walked_positions_are_whole_chunks(block_size, chunk):
+    assert decode_chunk_positions(block_size) == chunk
+    walked = [decode_walked_positions(n, block_size) for n in (0, 1, chunk, chunk + 1, 3 * chunk)]
+    assert walked == [chunk, chunk, chunk, 2 * chunk, 3 * chunk]
 
 
 @pytest.mark.parametrize("pos_vals", [(0, 6), (3, BPR * BS - 3)])

@@ -13,6 +13,7 @@ keeps it, so the tests compile in their own process and live in this one file.
 """
 
 import functools
+import re
 
 import pytest
 
@@ -175,6 +176,15 @@ def test_paged_flash_decode_compiles_on_the_whole_pool(compile_for_chip, case):
         if f" = {flat}" in line and " bitcast(" not in line and " parameter(" not in line
     ]
     assert not made, made[:2]
+    # one kernel a layer, under the name the benchmark's readers look for, and
+    # everything of size inside it (PR 31): the walk's two chunk buffers, the
+    # accumulator and the query rows fit the 16 MB a kernel gets unasked
+    calls = re.findall(r"^\s*%paged_decode[.\d]* = .*$", text, flags=re.M)
+    assert len(calls) == 1, calls
+    assert "vmem_limit_bytes" not in calls[0]
+    scoped = re.search(r'"used_scoped_memory_configs":\[([^\]]*)\]', calls[0])
+    sizes = [int(n) for n in re.findall(r'"size":"(\d+)"', scoped.group(1))]
+    assert sizes and sum(sizes) < 16 << 20, sizes
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16_pool", "int8_pool"])
@@ -217,8 +227,6 @@ def test_fused_sample_compiles(compile_for_chip, width, rows):
 # step's shape, 2048 (a bucket of 512) the prefill's.
 @pytest.mark.parametrize("tokens", [32, 512], ids=["decode_32_slots", "prefill_512"])
 def test_dropless_moe_compiles_on_the_stacked_experts(compile_for_chip, tokens):
-    import re
-
     from accelerate_tpu.ops.moe import dropless_moe
 
     layers, experts, hidden, width = 12, 32, 2048, 1792

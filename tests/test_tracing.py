@@ -572,6 +572,7 @@ def test_continuous_server_tick_nests_dispatch_readback_and_reply(tracer):
         assert by_id[sp.parent].name == "serving.tick"
         assert 1 <= sp.attrs["decoding"] <= sp.attrs["slots"] == 2
         assert 0 < sp.attrs["kv_live_tokens"] <= sp.attrs["kv_reserved_tokens"]
+        assert sp.attrs["kv_walked_tokens"] == 0  # a dense cache: no paged kernel walks it
     prefill = named("engine.prefill")
     assert len(prefill) == len(prompts)
     assert sorted(sp.attrs["prompt_len"] for sp in prefill) == [2, 3, 5]
