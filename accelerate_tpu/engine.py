@@ -381,8 +381,9 @@ class ContinuousBatchingEngine:
         ):
             # the paged flash kernels walk the FULL live block table; a
             # sliding-window mask would need per-block skip logic the kernel
-            # doesn't implement — downgrade up-front (the model-side
-            # _use_pallas_attention check is the belt-and-braces twin)
+            # doesn't implement — downgrade up-front and say so (the cache
+            # seam, kvcache._kernel_attends, applies the same rule where the
+            # attend path is chosen)
             warnings.warn(
                 "attention_impl='pallas' does not support sliding-window "
                 "configs; falling back to the reference paged attention op",

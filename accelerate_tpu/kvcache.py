@@ -64,6 +64,20 @@ Safety invariants (the reasons slot recycling cannot corrupt KV):
   blocks only cover positions ``< floor(prompt_len/bs)*bs``, so shared
   content is never written after registration — COW without copies.
 
+The seam to a model's step. A family's block (models/llama.py, gpt2.py,
+lfm2.py) computes norms, projections and rope and hands the rotated ``q, k, v``
+of a layer to :func:`attend_step` (one new position a row: write, attend,
+return the updated store) or :func:`attend_window` (a window over history with
+the store read-only: verify, chunked prefill); :func:`scan_layers` is the
+layer loop of the families whose layers are stacked. Behind these three, and
+nowhere else: whether the store is the dense arena, the pool or the int8
+``{"q", "s"}`` pool; whether the pool's blocks are gathered to a dense view,
+attended (``ops/attention.py::cache_attention``) and the column committed back,
+or the column committed first and the Pallas kernel run over the pool in place;
+that a sliding window downgrades the kernel to the reference; what rides a
+loop's carry. A family that changes what a cache holds changes this file and
+its own block.
+
 Two kinds of state. A family says how many of its layers keep keys and values
 and how large a head is (``config.serving_family()``, models/family.py): the
 arena's and the pool's leading axis is that count, not the model's depth. A
@@ -89,6 +103,7 @@ Backends:
 from __future__ import annotations
 
 import collections
+import functools
 import queue
 import threading
 import time
@@ -102,6 +117,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .logging import get_logger
+from .ops.attention import cache_attention
 
 logger = get_logger(__name__)
 
@@ -113,6 +129,9 @@ __all__ = [
     "PagedKVLayout",
     "HostKVTier",
     "make_kv_backend",
+    "attend_step",
+    "attend_window",
+    "scan_layers",
     "kv_quantize",
     "kv_dequantize",
     "KV_BACKENDS",
@@ -172,11 +191,12 @@ class PagedKVLayout:
     layer loop carries the pool whole (module docstring, "The pool's
     layout"); ``layer=None`` takes one layer's ``(num_blocks, bs, kvh * hd)``.
 
-    The model decode layers keep consuming a dense ``(B, max_len, kvh, hd)``
+    The reference attend path consumes a dense ``(B, max_len, kvh, hd)``
     cache: :meth:`view` gathers it from the pool (dequantizing int8),
-    :meth:`commit` extracts the single new column the layer wrote at ``pos``
-    and scatters it back (quantizing int8). Everything else in attention is
-    untouched — one KV story for dense and paged."""
+    :meth:`commit` extracts the single new column written at ``pos`` and
+    scatters it back (quantizing int8). Its callers are :func:`attend_step`
+    and :func:`attend_window`, the seam a model's step goes through — one KV
+    story for dense and paged."""
 
     def __init__(self, tables, block_size: int, compute_dtype, head_dim: int,
                  attention_impl: str = "reference"):
@@ -184,10 +204,10 @@ class PagedKVLayout:
         self.block_size = block_size
         self.compute_dtype = compute_dtype
         self.head_dim = head_dim
-        # "reference": model gathers view() and commits after attending;
-        # "pallas": model commits the new column first (commit_column) and
-        # the fused flash-decode kernel walks the tables itself — no dense
-        # view is ever materialized (ops/paged_decode.py)
+        # "reference": attend_step gathers view() and commits after
+        # attending; "pallas": it commits the new column first
+        # (commit_column) and the fused flash-decode kernel walks the tables
+        # itself — no dense view is ever materialized (ops/paged_decode.py)
         self.attention_impl = attention_impl
 
     @jax.named_scope("kv.gather")
@@ -274,6 +294,177 @@ class PagedKVLayout:
                 "s": pool["s"].at[:, blk, off].set(s),
             }
         return pool.at[:, blk, off].set(_merge_heads(window).astype(pool.dtype))
+
+
+# ------------------------------------------------- the seam to a model's step
+def _write_at(cache, kv, pos):
+    """Write one new position's K (or V) rows into a (B, max_len, H, D)
+    cache. Scalar ``pos`` writes every row at the same position (the fused
+    generate scan); a (B,) ``pos`` scatters each row at its own position
+    (continuous-batching slots, each mid-way through its own sequence)."""
+    kv = kv.astype(cache.dtype)
+    if jnp.ndim(pos) == 0:
+        return lax.dynamic_update_slice(cache, kv, (0, pos, 0, 0))
+    return jax.vmap(
+        lambda c, n, p: lax.dynamic_update_slice(c, n, (p, 0, 0))
+    )(cache, kv, pos)
+
+
+def _write_window(cache, kv, pos):
+    """Write a W-position window of K (or V) rows into a (B, S_cache, H, D)
+    cache at per-row start positions ``pos`` (B,). Unlike :func:`_write_at`'s
+    ``dynamic_update_slice`` (which CLAMPS start indices, silently shifting an
+    overhanging write onto live columns), this scatters each position
+    independently and DROPS any that fall past the cache length — required
+    for verify windows whose padded tail can legally overhang the arena."""
+    kv = kv.astype(cache.dtype)
+    w = kv.shape[1]
+
+    def one(c, n, p):
+        idx = p + jnp.arange(w, dtype=jnp.int32)
+        return c.at[idx].set(n, mode="drop")
+
+    return jax.vmap(one)(cache, kv, pos)
+
+
+def _kernel_attends(layout, window) -> bool:
+    """Whether the Pallas kernels (ops/paged_decode.py) attend: opted in on
+    the layout, and no sliding window, which they do not mask (the engine
+    downgrades such a config up front and says so; this is the same rule
+    where the choice is made)."""
+    return layout is not None and layout.attention_impl == "pallas" and window is None
+
+
+def _run_kernel(kernel, q, k_pool, v_pool, *operands, **kw):
+    """An int8 pool is a ``{"q", "s"}`` pair: values and per-position scales."""
+    if isinstance(k_pool, dict):
+        return kernel(q, k_pool["q"], v_pool["q"], *operands,
+                      k_scale=k_pool["s"], v_scale=v_pool["s"], **kw)
+    return kernel(q, k_pool, v_pool, *operands, **kw)
+
+
+def _attend_dense(write, layout, store, layer, q, k, v, pos, **attention):
+    """The reference attend: each of ``store``'s two as one layer's dense
+    ``(B, S, kv_heads, head_dim)`` (gathered from the pool, the arena's slice,
+    or with ``layer`` None the arena's layer as handed in), ``k`` / ``v``
+    written into it by ``write``, and the one attention over it. Returns
+    ``(out, dense keys, dense values)``."""
+    def dense(which, new):
+        if layout is not None:
+            view = layout.view(which, layer)
+        else:
+            view = which if layer is None else which[layer]
+        return write(view, new, pos)
+
+    dense_k, dense_v = dense(store[0], k), dense(store[1], v)
+    return cache_attention(q, dense_k, dense_v, pos, **attention), dense_k, dense_v
+
+
+def attend_step(layout, store, layer, q, k, v, pos, *, scale=None, softcap=None,
+                window=None, sliding=None):
+    """One new position a row: write ``k`` / ``v`` (B, 1, kv_heads, head_dim,
+    rotated) at ``pos`` (a traced scalar or (B,)) and attend ``q`` over
+    positions ``<= pos``. Returns ``(out (B, 1, heads, head_dim), store)``.
+
+    ``store`` is the ``(keys, values)`` pair and ``layer`` the index into it:
+    with a ``layout`` the whole pools; with ``layout=None`` the whole dense
+    arena ``(layers, B, S, kv_heads, head_dim)``, or with ``layer=None`` one
+    layer's own ``(B, S, kv_heads, head_dim)``. Three paths: the arena is
+    written and attended; the pool's blocks are gathered to a dense view,
+    attended, and the new column scattered back; or the column is committed
+    FIRST and the kernel walks the block tables over the pool in place (store
+    then load is exact in float; an int8 pool pays the one bounded
+    quantization every committed position pays). ``scale``, ``softcap``,
+    ``window``, ``sliding``: :func:`~accelerate_tpu.ops.attention.cache_attention`'s."""
+    keys, values = store
+    if _kernel_attends(layout, window):
+        from .ops.paged_decode import paged_flash_decode
+
+        keys = layout.commit_column(keys, k, pos, layer)
+        values = layout.commit_column(values, v, pos, layer)
+        rows = pos if jnp.ndim(pos) else jnp.broadcast_to(pos, q.shape[:1])
+        out = _run_kernel(paged_flash_decode, q, keys, values, layout.tables, rows,
+                          scale=scale, softcap=softcap, layer=layer)
+        return out.astype(q.dtype), (keys, values)
+    out, dense_k, dense_v = _attend_dense(
+        _write_at, layout, store, layer, q, k, v, pos,
+        scale=scale, softcap=softcap, window=window, sliding=sliding,
+    )
+    if layout is not None:
+        return out, (layout.commit(keys, dense_k, pos, layer),
+                     layout.commit(values, dense_v, pos, layer))
+    if layer is None:
+        return out, (dense_k, dense_v)
+    return out, (keys.at[layer].set(dense_k), values.at[layer].set(dense_v))
+
+
+def attend_window(layout, store, layer, q, k, v, pos, *, scale=None, softcap=None,
+                  window=None, sliding=None):
+    """A window of W positions a row at ``pos .. pos+W-1`` (``pos`` (B,)) over
+    history plus the window itself, causally: speculative verify and chunked
+    prefill. ``store`` (as :func:`attend_step`'s) is READ-ONLY: the window's
+    keys and values go into a temporary copy of the dense view (or straight
+    to the kernel, which reads history ``k_pos < pos`` from the pool and
+    attends the window in registers), positions past the cache's length are
+    dropped, never clamped onto a live column. Returns ``(out, (k, v))``: the
+    window's own keys and values, of which the caller commits the prefix it
+    accepts (``commit_window``); what it rejects never existed."""
+    keys, values = store
+    if _kernel_attends(layout, window):
+        from .ops.paged_decode import paged_flash_verify
+
+        out = _run_kernel(paged_flash_verify, q, keys, values, k, v, layout.tables, pos,
+                          scale=scale, softcap=softcap, layer=layer)
+        return out.astype(q.dtype), (k, v)
+    out, _, _ = _attend_dense(
+        _write_window, layout, store, layer, q, k, v, pos,
+        scale=scale, softcap=softcap, window=window, sliding=sliding,
+    )
+    return out, (k, v)
+
+
+def scan_layers(attend_op, layout, block, x, cache, layers, *flags):
+    """The layer loop of a step over stacked ``layers``. ``block(x,
+    layer_params, attend, *flags)`` returns ``(x, kept)``, where ``attend`` is
+    ``attend_op`` (:func:`attend_step` or :func:`attend_window`) bound to the
+    layout, the store and the layer, and ``kept`` is what it returned second.
+    Returns ``(x, {"k", "v"})`` of what was kept: the new cache, or the
+    windows' keys and values stacked by layer.
+
+    The dense arena rides the scan as ``xs`` (and back as ``ys``), a layer a
+    step. A pool never does: it goes whole, in the carry beside ``x`` when the
+    step writes it (the program's donated pool is updated in place and handed
+    back) or closed over when it is only read, and the scan steps over
+    ``(layer params, layer index[, flags])`` (module docstring, "The pool's
+    layout")."""
+    if layout is None:
+        def body(x, inputs):
+            layer_params, keys, values, *rest = inputs
+            attend = functools.partial(attend_op, None, (keys, values), None)
+            return block(x, layer_params, attend, *rest)
+
+        x, (k, v) = lax.scan(body, x, (layers, cache["k"], cache["v"], *flags))
+        return x, {"k": k, "v": v}
+    n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    xs = (layers, jnp.arange(n_layers, dtype=jnp.int32), *flags)
+    if attend_op is attend_window:
+        def body(x, inputs):
+            layer_params, layer, *rest = inputs
+            attend = functools.partial(attend_op, layout, (cache["k"], cache["v"]), layer)
+            return block(x, layer_params, attend, *rest)
+
+        x, (k, v) = lax.scan(body, x, xs)
+        return x, {"k": k, "v": v}
+
+    def body(carry, inputs):
+        x, *store = carry
+        layer_params, layer, *rest = inputs
+        attend = functools.partial(attend_op, layout, tuple(store), layer)
+        x, (keys, values) = block(x, layer_params, attend, *rest)
+        return (x, keys, values), None
+
+    (x, k, v), _ = lax.scan(body, (x, cache["k"], cache["v"]), xs)
+    return x, {"k": k, "v": v}
 
 
 # ----------------------------------------------------------- host spill tier

@@ -24,22 +24,12 @@ from jax import lax
 
 from jax.ad_checkpoint import checkpoint_name
 
+from ..kvcache import attend_step, attend_window, scan_layers
 from ..model import Model
 from ..ops.attention import dispatch_attention
 from ..parallel.sharding import constrain_activation, replicate_over_fsdp
 from .bert import _apply_dense, _dense, layer_norm
-from .llama import (
-    _ce_from_hidden,
-    _pallas_decode_override,
-    _pallas_verify_override,
-    _remat_policy,
-    _scan_layers_over_pool,
-    _use_pallas_attention,
-    _write_kv_at,
-    _write_kv_window,
-    llama_ce_denominator,
-    llama_loss,
-)
+from .llama import _ce_from_hidden, _remat_policy, llama_ce_denominator, llama_loss
 
 __all__ = [
     "GPT2Config",
@@ -379,7 +369,7 @@ def _gpt2_prefill_stack(config: GPT2Config, params, input_ids, max_len: int):
 
 
 def _gpt2_head(config: GPT2Config, params, x):
-    """Final layer norm + tied LM head on (B, D) rows → f32 (B, V)."""
+    """Final layer norm + tied LM head on (..., D) rows → f32 (..., V)."""
     cdt = config.compute_dtype
     x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"], config.layer_norm_eps)
     return (x @ params["wte"]["embedding"].astype(cdt).T).astype(jnp.float32)
@@ -400,99 +390,13 @@ def gpt2_prefill_at(config: GPT2Config, params, input_ids, max_len: int, last_in
     return _gpt2_head(config, params, x_last), cache
 
 
-def _gpt2_decode_layer(config: GPT2Config, lp, x, cache_k, cache_v, pos,
-                       attention_override=None):
-    """One block, one new position; updates the (B, max_len, h, hd) caches.
-    ``pos`` is a traced scalar (lockstep batch) or (B,) vector (per-row
-    positions — continuous-batching slots), same contract as llama's
-    ``_decode_layer`` including the Pallas ``attention_override`` hook
-    (takes the new-position q/k/v, owns the KV commit, returns the
-    attended output plus updated caches)."""
-    cdt = config.compute_dtype
-    b, s, d = x.shape  # s == 1
-    h, hd = config.num_attention_heads, config.head_dim
-
-    y = layer_norm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"], config.layer_norm_eps)
-    q = _apply_dense(lp["attn"]["c_attn_q"], y, cdt).reshape(b, s, h, hd)
-    k = _apply_dense(lp["attn"]["c_attn_k"], y, cdt).reshape(b, s, h, hd)
-    v = _apply_dense(lp["attn"]["c_attn_v"], y, cdt).reshape(b, s, h, hd)
-    if attention_override is not None:
-        attn, cache_k, cache_v = attention_override(q, k, v)
-        attn = attn.astype(cdt)
-    else:
-        cache_k = _write_kv_at(cache_k, k, pos)
-        cache_v = _write_kv_at(cache_v, v, pos)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q * (1.0 / np.sqrt(hd)), cache_k.astype(cdt)
-        ).astype(jnp.float32)
-        k_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 3)
-        pos_b = pos if jnp.ndim(pos) == 0 else pos[:, None, None, None]
-        scores = jnp.where(k_pos <= pos_b, scores, -1e6)
-        weights = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", weights.astype(cdt), cache_v.astype(cdt))
-    attn = _apply_dense(lp["attn"]["c_proj"], attn.reshape(b, s, d), cdt)
-    x = x + attn
-
-    y = layer_norm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"], config.layer_norm_eps)
-    y = jax.nn.gelu(_apply_dense(lp["mlp"]["c_fc"], y, cdt), approximate=True)
-    y = _apply_dense(lp["mlp"]["c_proj"], y, cdt)
-    return x + y, cache_k, cache_v
-
-
-def gpt2_decode_step(config: GPT2Config, params, cache, token, pos, *,
-                     kv_layout=None):
-    """One decode step: token (B, 1) at traced position ``pos`` (scalar, or
-    (B,) per-row positions for continuous-batching slots) → (logits (B, V),
-    new cache). Same contract as llama_decode_step, including the optional
-    paged ``kv_layout`` (the layer loop carries the pool whole; a layer's
-    blocks are gathered to a dense view before it attends and the new column
-    committed back after, or read in place by the Pallas kernel)."""
-    cdt = config.compute_dtype
-    x = params["wte"]["embedding"].astype(cdt)[token]
-    wpe = params["wpe"]["embedding"].astype(cdt)
-    if jnp.ndim(pos) == 0:
-        x = x + jnp.take(wpe, pos, axis=0)[None, None]
-    else:
-        x = x + jnp.take(wpe, pos, axis=0)[:, None]
-
-    pallas = _use_pallas_attention(config, kv_layout)
-
-    def paged_step(x, lp, ck, cv, layer):
-        if pallas:
-            override = _pallas_decode_override(config, kv_layout, pos, ck, cv, layer)
-            return _gpt2_decode_layer(config, lp, x, None, None, pos,
-                                      attention_override=override)
-        x, vk, vv = _gpt2_decode_layer(
-            config, lp, x, kv_layout.view(ck, layer), kv_layout.view(cv, layer), pos
-        )
-        return x, kv_layout.commit(ck, vk, pos, layer), kv_layout.commit(cv, vv, pos, layer)
-
-    def dense_body(x, inputs):
-        lp, ck, cv = inputs
-        x, ck, cv = _gpt2_decode_layer(config, lp, x, ck, cv, pos)
-        return x, (ck, cv)
-
-    if kv_layout is not None:
-        x, new_cache = _scan_layers_over_pool(paged_step, x, cache, params["layers"])
-    else:
-        x, (new_k, new_v) = lax.scan(
-            dense_body, x, (params["layers"], cache["k"], cache["v"])
-        )
-        new_cache = {"k": new_k, "v": new_v}
-    x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"], config.layer_norm_eps)
-    logits = x @ params["wte"]["embedding"].astype(cdt).T
-    return logits[:, 0].astype(jnp.float32), new_cache
-
-
-def _gpt2_verify_layer(config: GPT2Config, lp, x, cache_k, cache_v, pos,
-                       attention_override=None):
-    """One block over a W-token speculative-verify window at positions
-    ``pos .. pos+W-1`` (``pos`` a traced (B,) vector). Same read-only-cache
-    contract as llama's ``_verify_layer``: the window's K/V go into a
-    temporary scatter-written copy for the causal attend (or straight to
-    the Pallas ``attention_override``, which attends them in-register),
-    and the raw window K/V are returned for the caller's accepted-prefix
-    commit."""
+def _gpt2_step_block(config: GPT2Config, lp, x, attend):
+    """One block over a window of W new positions a row, ``x`` (B, W, D); W = 1
+    is a decode step. Positions are learned and already in ``x``.
+    ``attend(q, k, v) -> (attn, kept)`` is the cache seam, same contract as
+    llama's ``_step_block``: it owns the write, the attend path and the
+    attention, and ``kept`` (the updated store, or the window's keys and
+    values) is returned beside the new ``x``."""
     cdt = config.compute_dtype
     b, w, d = x.shape
     h, hd = config.num_attention_heads, config.head_dim
@@ -501,78 +405,51 @@ def _gpt2_verify_layer(config: GPT2Config, lp, x, cache_k, cache_v, pos,
     q = _apply_dense(lp["attn"]["c_attn_q"], y, cdt).reshape(b, w, h, hd)
     k = _apply_dense(lp["attn"]["c_attn_k"], y, cdt).reshape(b, w, h, hd)
     v = _apply_dense(lp["attn"]["c_attn_v"], y, cdt).reshape(b, w, h, hd)
-    win_k, win_v = k, v
-    if attention_override is not None:
-        attn = attention_override(q, k, v).astype(cdt)
-    else:
-        cache_k = _write_kv_window(cache_k, k, pos)
-        cache_v = _write_kv_window(cache_v, v, pos)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q * (1.0 / np.sqrt(hd)), cache_k.astype(cdt)
-        ).astype(jnp.float32)
-        k_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 3)
-        q_idx = lax.broadcasted_iota(jnp.int32, scores.shape, 2)
-        pos_b = pos[:, None, None, None]
-        scores = jnp.where(k_pos <= pos_b + q_idx, scores, -1e6)
-        weights = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", weights.astype(cdt), cache_v.astype(cdt))
+    attn, kept = attend(q, k, v)
     attn = _apply_dense(lp["attn"]["c_proj"], attn.reshape(b, w, d), cdt)
     x = x + attn
 
     y = layer_norm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"], config.layer_norm_eps)
     y = jax.nn.gelu(_apply_dense(lp["mlp"]["c_fc"], y, cdt), approximate=True)
     y = _apply_dense(lp["mlp"]["c_proj"], y, cdt)
-    return x + y, win_k, win_v
+    return x + y, kept
+
+
+def _gpt2_serving_step(config: GPT2Config, params, cache, tokens, pos, kv_layout, attend_op):
+    """Embed -> the layer loop over :func:`_gpt2_step_block` -> the head, for
+    a window ``tokens`` (B, W) at positions ``pos .. pos+W-1``. Learned
+    positions use a clamping ``jnp.take``: a padded window position past
+    ``max_position_embeddings`` clamps harmlessly, its logits are discarded
+    by the engine's length mask."""
+    cdt = config.compute_dtype
+    x = params["wte"]["embedding"].astype(cdt)[tokens]
+    at = pos[..., None] + jnp.arange(tokens.shape[1], dtype=pos.dtype)  # (W,) or (B, W)
+    x = x + jnp.take(params["wpe"]["embedding"].astype(cdt), at, axis=0)
+
+    def block(x, lp, attend):
+        return _gpt2_step_block(config, lp, x, functools.partial(attend, pos=pos))
+
+    x, kept = scan_layers(attend_op, kv_layout, block, x, cache, params["layers"])
+    return _gpt2_head(config, params, x), kept
+
+
+def gpt2_decode_step(config: GPT2Config, params, cache, token, pos, *,
+                     kv_layout=None):
+    """One decode step: token (B, 1) at traced position ``pos`` (scalar, or
+    (B,) per-row positions for continuous-batching slots) -> (logits (B, V),
+    new cache). Same contract as llama_decode_step, including the optional
+    paged ``kv_layout``."""
+    logits, cache = _gpt2_serving_step(config, params, cache, token, pos, kv_layout, attend_step)
+    return logits[:, 0], cache
 
 
 def gpt2_verify_step(config: GPT2Config, params, cache, tokens, pos, *,
                      kv_layout=None):
     """Speculative-verify forward: ``tokens`` (B, W) at positions
-    ``pos .. pos+W-1`` → (logits (B, W, V) f32, window KV (L, B, W, h, hd)).
+    ``pos .. pos+W-1`` -> (logits (B, W, V) f32, window KV (L, B, W, h, hd)).
     Same contract as :func:`~.llama.llama_verify_step`: the cache is
-    read-only here; the caller commits the accepted prefix. Learned
-    positions use a clamping ``jnp.take`` (matching decode) — padded
-    window positions past ``max_position_embeddings`` clamp harmlessly
-    because their logits are discarded by the engine's length mask."""
-    cdt = config.compute_dtype
-    b, w = tokens.shape
-    x = params["wte"]["embedding"].astype(cdt)[tokens]
-    wpe = params["wpe"]["embedding"].astype(cdt)
-    abs_pos = pos[:, None] + jnp.arange(w, dtype=pos.dtype)[None, :]  # (B, W)
-    x = x + jnp.take(wpe, abs_pos, axis=0)
-
-    pallas = _use_pallas_attention(config, kv_layout)
-
-    def paged_body(x, inputs):
-        # the pool is only read here: a loop invariant the body closes over,
-        # addressed by layer like the decode step's (never sliced as xs)
-        lp, layer = inputs
-        ck, cv = cache["k"], cache["v"]
-        if pallas:
-            override = _pallas_verify_override(config, kv_layout, pos, ck, cv, layer)
-            x, wk, wv = _gpt2_verify_layer(config, lp, x, None, None, pos,
-                                           attention_override=override)
-        else:
-            x, wk, wv = _gpt2_verify_layer(
-                config, lp, x, kv_layout.view(ck, layer), kv_layout.view(cv, layer), pos
-            )
-        return x, (wk, wv)
-
-    def dense_body(x, inputs):
-        lp, ck, cv = inputs
-        x, wk, wv = _gpt2_verify_layer(config, lp, x, ck, cv, pos)
-        return x, (wk, wv)
-
-    if kv_layout is not None:
-        layers = jnp.arange(config.num_hidden_layers, dtype=jnp.int32)
-        x, (win_k, win_v) = lax.scan(paged_body, x, (params["layers"], layers))
-    else:
-        x, (win_k, win_v) = lax.scan(
-            dense_body, x, (params["layers"], cache["k"], cache["v"])
-        )
-    x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"], config.layer_norm_eps)
-    logits = x @ params["wte"]["embedding"].astype(cdt).T
-    return logits.astype(jnp.float32), {"k": win_k, "v": win_v}
+    read-only here; the caller commits the accepted prefix."""
+    return _gpt2_serving_step(config, params, cache, tokens, pos, kv_layout, attend_window)
 
 
 def upgrade_legacy_state(tree: dict) -> dict:

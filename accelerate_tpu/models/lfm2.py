@@ -39,19 +39,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..kvcache import attend_step
 from ..model import Model
 from ..ops.attention import dispatch_attention
 from ..ops.moe import dropless_moe
 from .family import ServingFamily
-from .llama import (
-    _pallas_decode_override,
-    _use_pallas_attention,
-    _write_kv_at,
-    apply_rope,
-    apply_rope_at,
-    llama_loss,
-    rms_norm,
-)
+from .llama import apply_rope, apply_rope_at, llama_loss, rms_norm
 
 __all__ = [
     "Lfm2Config",
@@ -295,26 +288,6 @@ class _SequenceView:
         }
 
 
-def _attend_cache(q, cache_k, cache_v, pos):
-    """One query a row over a dense ``(B, S, kv_heads, head_dim)`` cache, keys
-    at positions ``<= pos`` (a traced scalar or a (B,) vector)."""
-    b, s, h, hd = q.shape
-    kvh = cache_k.shape[2]
-    grouped = (q * (1.0 / np.sqrt(hd))).reshape(b, s, kvh, h // kvh, hd)
-    scores = jnp.einsum(
-        "bqgrd,bkgd->bgrqk", grouped, cache_k.astype(q.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 4)
-    pos_b = pos if jnp.ndim(pos) == 0 else pos[:, None, None, None, None]
-    weights = jax.nn.softmax(jnp.where(k_pos <= pos_b, scores, -1e6), axis=-1)
-    out = jnp.einsum(
-        "bgrqk,bkgd->bqgrd", weights.astype(q.dtype), cache_v.astype(q.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(b, s, h, hd).astype(q.dtype)
-
-
 class _StepView:
     """One new position a row, at ``pos``, over a cache: ``"k"`` / ``"v"`` hold
     the attention layers' keys and values (a dense ``(A, B, max_len, kv_heads,
@@ -333,24 +306,12 @@ class _StepView:
         return window
 
     def attend(self, index: int, q, k, v):
-        config, pos, layout = self.config, self.pos, self.kv_layout
-        q = apply_rope_at(q, pos, config.rope_theta)
-        k = apply_rope_at(k, pos, config.rope_theta)
-        if layout is None:
-            layer_k = _write_kv_at(self.k[index], k, pos)
-            layer_v = _write_kv_at(self.v[index], v, pos)
-            self.k, self.v = self.k.at[index].set(layer_k), self.v.at[index].set(layer_v)
-            return _attend_cache(q, layer_k, layer_v, pos)
-        if _use_pallas_attention(config, layout):
-            # the column is committed first, then the kernel walks the tables
-            override = _pallas_decode_override(config, layout, pos, self.k, self.v, index)
-            out, self.k, self.v = override(q, k, v)
-            return out.astype(q.dtype)
-        view_k = _write_kv_at(layout.view(self.k, index), k, pos)
-        view_v = _write_kv_at(layout.view(self.v, index), v, pos)
-        self.k = layout.commit(self.k, view_k, pos, index)
-        self.v = layout.commit(self.v, view_v, pos, index)
-        return _attend_cache(q, view_k, view_v, pos)
+        theta = self.config.rope_theta
+        q, k = apply_rope_at(q, self.pos, theta), apply_rope_at(k, self.pos, theta)
+        out, (self.k, self.v) = attend_step(
+            self.kv_layout, (self.k, self.v), index, q, k, v, self.pos
+        )
+        return out
 
     def cache(self) -> dict:
         recurrent = jnp.stack(self.recurrent) if self.recurrent else self.before

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..kvcache import attend_step, attend_window, scan_layers
 from ..model import Model
 from ..parallel.sharding import (
     constrain_activation,
@@ -1198,172 +1199,37 @@ def init_kv_cache(config: LlamaConfig, batch_size: int, max_len: int, dtype=None
     return {"k": jnp.zeros(shape, dtype=dtype), "v": jnp.zeros(shape, dtype=dtype)}
 
 
-def _decode_layer(config: LlamaConfig, layer_params, x, cache_k, cache_v, pos,
-                  sliding=None, attention_override=None):
-    """One block, one new position; returns updated (cache_k, cache_v).
-    ``pos`` is a traced scalar (whole batch at one position — the fused
-    generate scan) or a traced (B,) vector (per-row positions — the
-    continuous-batching engine's slot decode). ``sliding``: None = uniform
-    config.sliding_window behavior; a traced bool applies the window only
-    when true (Gemma-2 alternating layers — the flag rides the decode scan
-    as a per-layer xs array). ``attention_override``: the Pallas paged
-    path — a callable ``(q, k_new, v_new) -> (attn, cache_k, cache_v)``
-    receiving the rope-rotated projections; it owns both the KV store
-    write and the attention (cache_k/cache_v operands are then whatever
-    the override's store carries, e.g. pool slices — never touched
-    here)."""
-    h, kvh, hd = config.num_attention_heads, config.num_key_value_heads, config.head_dim
-    b, s, d = x.shape  # s == 1
-    cdt = config.compute_dtype
+def _step_block(config: LlamaConfig, layer_params, x, pos, attend):
+    """One block over a window of W new positions a row: ``x`` (B, W, D) at
+    positions ``pos .. pos+W-1``. W = 1 is a decode step (``pos`` a traced
+    scalar, the whole batch in lockstep as in the fused generate scan, or a
+    traced (B,) vector, each continuous-batching slot at its own position);
+    W > 1 is a speculative-verify or prefill-chunk window (``pos`` (B,)).
 
-    residual = x
-    y = rms_norm(x, layer_params["input_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
-    def _dproj(name):
-        p = layer_params["attn"][name]
-        out = y @ p["kernel"].astype(cdt)
-        if "bias" in p:
-            out = out + p["bias"].astype(cdt)
-        return out
-
-    q = _dproj("q_proj").reshape(b, s, h, hd)
-    k = _dproj("k_proj").reshape(b, s, kvh, hd)
-    v = _dproj("v_proj").reshape(b, s, kvh, hd)
-    q = apply_rope_at(q, pos, config.rope_theta, config._rope_scaling_key())
-    k = apply_rope_at(k, pos, config.rope_theta, config._rope_scaling_key())
-    if attention_override is not None:
-        # Pallas paged path: the override commits the new column into the
-        # pool FIRST, then the flash-decode kernel reads it back along the
-        # block-table walk — same k_pos <= pos semantics, no dense view.
-        attn, cache_k, cache_v = attention_override(q, k, v)
-        attn = attn.astype(cdt)
-    else:
-        cache_k = _write_kv_at(cache_k, k, pos)
-        cache_v = _write_kv_at(cache_v, v, pos)
-        # attend over positions 0..pos (mask the tail). GQA attends GROUPED: q
-        # is reshaped (B, 1, Hkv, n_rep, hd) and each kv head broadcasts over
-        # its n_rep query heads inside the einsum — the cache is never
-        # physically tiled n_rep×, so decode reads Hkv heads of KV, not H.
-        n_rep = h // kvh
-        attn_scale = 1.0 / np.sqrt(config.query_pre_attn_scalar or hd)
-        qg = (q * attn_scale).reshape(b, s, kvh, n_rep, hd)
-        scores = jnp.einsum(
-            "bqgrd,bkgd->bgrqk", qg, cache_k.astype(cdt),
-            preferred_element_type=jnp.float32,  # G402: f32 score accumulation
-        )
-        scores = _tanh_softcap(scores, config.attn_logit_softcap)  # pre-mask
-        k_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 4)
-        pos_b = pos if jnp.ndim(pos) == 0 else pos[:, None, None, None, None]
-        scores = jnp.where(k_pos <= pos_b, scores, -1e6)
-        if config.sliding_window is not None:
-            in_window = pos_b - k_pos < config.sliding_window
-            if sliding is not None:  # per-layer alternating flag (traced)
-                in_window = jnp.logical_or(jnp.logical_not(sliding), in_window)
-            scores = jnp.where(in_window, scores, -1e6)
-        weights = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum(
-            "bgrqk,bkgd->bqgrd", weights.astype(cdt), cache_v.astype(cdt),
-            preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
-        ).astype(cdt)
-    attn = attn.reshape(b, s, h * hd) @ layer_params["attn"]["o_proj"]["kernel"].astype(cdt)
-    if config.post_block_norms:
-        attn = rms_norm(attn, layer_params["attn_out_norm"]["scale"],
-                        config.rms_norm_eps, config.rms_norm_offset)
-    x = residual + attn
-
-    residual = x
-    y = rms_norm(x, layer_params["post_attn_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
-    if config.num_experts > 1:
-        from ..ops.moe import moe_ffn
-
-        y, _aux = moe_ffn(
-            y,
-            layer_params["mlp"]["router"]["kernel"],
-            layer_params["mlp"]["experts"]["w_gate"],
-            layer_params["mlp"]["experts"]["w_up"],
-            layer_params["mlp"]["experts"]["w_down"],
-            num_selected=config.num_experts_per_tok,
-            capacity_factor=config.expert_capacity_factor,
-            compute_dtype=cdt,
-        )
-    else:
-        gate = y @ layer_params["mlp"]["gate_proj"]["kernel"].astype(cdt)
-        up = y @ layer_params["mlp"]["up_proj"]["kernel"].astype(cdt)
-        y = _mlp_act(config, gate) * up
-        y = y @ layer_params["mlp"]["down_proj"]["kernel"].astype(cdt)
-    if config.post_block_norms:
-        y = rms_norm(y, layer_params["mlp_out_norm"]["scale"],
-                     config.rms_norm_eps, config.rms_norm_offset)
-    return residual + y, cache_k, cache_v
-
-
-def _verify_layer(config: LlamaConfig, layer_params, x, cache_k, cache_v, pos,
-                  sliding=None, attention_override=None):
-    """One block over a W-token speculative-verify window: ``x`` is
-    (B, W, D) — the carried token plus k draft tokens — at positions
-    ``pos .. pos+W-1`` (``pos`` a traced (B,) vector). The cache operands
-    are READ-ONLY: the window's K/V are scatter-written into a temporary
-    copy so the window can attend itself causally, and the raw rotated
-    per-position K/V are returned so the caller can commit only the
-    accepted prefix afterwards — "rewind" is simply not committing.
-    Padded window positions that land past the cache length are dropped by
-    the scatter (``mode='drop'``), never clamped onto a live column; their
-    queries produce garbage logits that the engine's length mask discards,
-    and their keys sit strictly after every valid query's causal horizon."""
+    ``attend(q, k, v) -> (attn, kept)`` is the block's only contact with the
+    cache: :func:`~accelerate_tpu.kvcache.attend_step` or ``attend_window``
+    bound to the store, the layer and this config's attention arguments,
+    handed the rope-rotated projections. It owns the write, the choice of
+    attend path and the attention; ``kept`` (the updated store, or the
+    window's keys and values) is returned beside the new ``x``."""
     h, kvh, hd = config.num_attention_heads, config.num_key_value_heads, config.head_dim
     b, w, d = x.shape
     cdt = config.compute_dtype
 
     residual = x
     y = rms_norm(x, layer_params["input_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
-    def _dproj(name):
+
+    def proj(name, heads):
         p = layer_params["attn"][name]
         out = y @ p["kernel"].astype(cdt)
         if "bias" in p:
             out = out + p["bias"].astype(cdt)
-        return out
+        return out.reshape(b, w, heads, hd)
 
-    q = _dproj("q_proj").reshape(b, w, h, hd)
-    k = _dproj("k_proj").reshape(b, w, kvh, hd)
-    v = _dproj("v_proj").reshape(b, w, kvh, hd)
-    q = apply_rope_window(q, pos, config.rope_theta, config._rope_scaling_key())
-    k = apply_rope_window(k, pos, config.rope_theta, config._rope_scaling_key())
-    win_k, win_v = k, v
-    if attention_override is not None:
-        # Pallas paged path: the kernel reads committed history from the
-        # pool (strictly k_pos < pos) and attends the fresh window columns
-        # in-register — nothing is scatter-written, matching this layer's
-        # read-only cache contract exactly.
-        attn = attention_override(q, k, v).astype(cdt)
-    else:
-        cache_k = _write_kv_window(cache_k, k, pos)
-        cache_v = _write_kv_window(cache_v, v, pos)
-        # Causal over past + window: query j (absolute position pos+j)
-        # attends k_pos <= pos+j. Same grouped-GQA einsum as _decode_layer —
-        # per-(q, k) score elements are independent dot products, so the
-        # q_idx=0 row of this window reproduces the single-token decode
-        # scores bitwise.
-        n_rep = h // kvh
-        attn_scale = 1.0 / np.sqrt(config.query_pre_attn_scalar or hd)
-        qg = (q * attn_scale).reshape(b, w, kvh, n_rep, hd)
-        scores = jnp.einsum(
-            "bqgrd,bkgd->bgrqk", qg, cache_k.astype(cdt),
-            preferred_element_type=jnp.float32,  # G402: f32 score accumulation
-        )
-        scores = _tanh_softcap(scores, config.attn_logit_softcap)  # pre-mask
-        k_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 4)
-        q_idx = lax.broadcasted_iota(jnp.int32, scores.shape, 3)
-        pos_b = pos[:, None, None, None, None]
-        scores = jnp.where(k_pos <= pos_b + q_idx, scores, -1e6)
-        if config.sliding_window is not None:
-            in_window = (pos_b + q_idx) - k_pos < config.sliding_window
-            if sliding is not None:  # per-layer alternating flag (traced)
-                in_window = jnp.logical_or(jnp.logical_not(sliding), in_window)
-            scores = jnp.where(in_window, scores, -1e6)
-        weights = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum(
-            "bgrqk,bkgd->bqgrd", weights.astype(cdt), cache_v.astype(cdt),
-            preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
-        ).astype(cdt)
+    q, k, v = proj("q_proj", h), proj("k_proj", kvh), proj("v_proj", kvh)
+    q = apply_rope_at(q, pos, config.rope_theta, config._rope_scaling_key())
+    k = apply_rope_at(k, pos, config.rope_theta, config._rope_scaling_key())
+    attn, kept = attend(q, k, v)
     attn = attn.reshape(b, w, h * hd) @ layer_params["attn"]["o_proj"]["kernel"].astype(cdt)
     if config.post_block_norms:
         attn = rms_norm(attn, layer_params["attn_out_norm"]["scale"],
@@ -1393,7 +1259,7 @@ def _verify_layer(config: LlamaConfig, layer_params, x, cache_k, cache_v, pos,
     if config.post_block_norms:
         y = rms_norm(y, layer_params["mlp_out_norm"]["scale"],
                      config.rms_norm_eps, config.rms_norm_offset)
-    return residual + y, win_k, win_v
+    return residual + y, kept
 
 
 def repeat_kv_cache(c, n_rep):
@@ -1409,68 +1275,17 @@ def repeat_kv_cache(c, n_rep):
     return jnp.broadcast_to(c[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(b, s, h * n_rep, d)
 
 
-def _write_kv_at(cache, kv, pos):
-    """Write one new position's K (or V) rows into a (B, max_len, H, D)
-    cache. Scalar ``pos`` writes every row at the same position (the fused
-    generate scan); a (B,) ``pos`` scatters each row at its own position
-    (continuous-batching slots, each mid-way through its own sequence)."""
-    kv = kv.astype(cache.dtype)
-    if jnp.ndim(pos) == 0:
-        return lax.dynamic_update_slice(cache, kv, (0, pos, 0, 0))
-    return jax.vmap(
-        lambda c, n, p: lax.dynamic_update_slice(c, n, (p, 0, 0))
-    )(cache, kv, pos)
-
-
 def apply_rope_at(x, pos, theta, scaling=None):
-    """RoPE for a traced decode position: scalar ``pos`` rotates the whole
-    batch at one position; a (B,) ``pos`` rotates each row at its own
-    (continuous-batching slots)."""
-    b, s, h, d = x.shape
-    freqs = jnp.asarray(_rope_freqs(d, theta, scaling), dtype=jnp.float32)
-    if jnp.ndim(pos) == 0:
-        angles = pos.astype(jnp.float32) * freqs  # (d/2,)
-        cos = jnp.cos(angles)[None, None, None, :]
-        sin = jnp.sin(angles)[None, None, None, :]
-    else:
-        angles = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (B, d/2)
-        cos = jnp.cos(angles)[:, None, None, :]
-        sin = jnp.sin(angles)[:, None, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    y1 = x1 * cos - x2 * sin
-    y2 = x2 * cos + x1 * sin
-    return jnp.stack([y1, y2], axis=-1).reshape(b, s, h, d).astype(x.dtype)
-
-
-def _write_kv_window(cache, kv, pos):
-    """Write a W-position window of K (or V) rows into a (B, S_cache, H, D)
-    cache at per-row start positions ``pos`` (B,). Unlike
-    :func:`_write_kv_at`'s ``dynamic_update_slice`` (which CLAMPS start
-    indices, silently shifting an overhanging write onto live columns),
-    this scatters each position independently and DROPS any that fall past
-    the cache length — required for verify windows whose padded tail can
-    legally overhang the arena."""
-    kv = kv.astype(cache.dtype)
-    w = kv.shape[1]
-
-    def one(c, n, p):
-        idx = p + jnp.arange(w, dtype=jnp.int32)
-        return c.at[idx].set(n, mode="drop")
-
-    return jax.vmap(one)(cache, kv, pos)
-
-
-def apply_rope_window(x, pos, theta, scaling=None):
-    """RoPE for a W-token verify window: ``x`` (B, W, H, D) where window
-    offset j sits at absolute position ``pos[b] + j`` — each (row, offset)
-    gets its own rotation angle, unlike :func:`apply_rope_at` which rotates
-    every s-position of a row identically."""
+    """RoPE for a window of W positions a row starting at a traced ``pos``:
+    ``x`` (B, W, H, D), offset j of a row at absolute position ``pos + j``.
+    Scalar ``pos`` starts the whole batch at one position; a (B,) ``pos``
+    starts each row at its own (continuous-batching slots)."""
     b, w, h, d = x.shape
     freqs = jnp.asarray(_rope_freqs(d, theta, scaling), dtype=jnp.float32)
-    abs_pos = pos.astype(jnp.float32)[:, None] + jnp.arange(w, dtype=jnp.float32)[None, :]
-    angles = abs_pos[:, :, None] * freqs[None, None, :]  # (B, W, d/2)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    at = pos.astype(jnp.float32)[..., None] + jnp.arange(w, dtype=jnp.float32)
+    angles = at[..., None] * freqs  # (W, d/2), or (B, W, d/2)
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
     x1, x2 = x[..., 0::2], x[..., 1::2]
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin
@@ -1510,7 +1325,8 @@ def _prefill_stack(config: LlamaConfig, params, input_ids):
 
 
 def _prefill_head(config: LlamaConfig, params, x):
-    """Final norm + LM head on gathered hidden rows (B, D) → f32 (B, V)."""
+    """Final norm + LM head on hidden rows (..., D) → f32 (..., V): the
+    prefill's gathered last rows, a decode step's, a verify window's."""
     cdt = config.compute_dtype
     x = rms_norm(x, params["final_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
     if config.tie_word_embeddings:
@@ -1551,21 +1367,6 @@ def llama_prefill_at(config: LlamaConfig, params, input_ids, max_len: int, last_
     return _prefill_head(config, params, x_last), _pad_prefill_cache(ks, vs, max_len)
 
 
-def _use_pallas_attention(config, kv_layout) -> bool:
-    """Whether this dispatch routes attention through the Pallas paged
-    flash kernels (ops/paged_decode.py): opted in on the layout
-    (``KVCacheBackend.attention_impl``) and structurally unsupported for
-    sliding-window configs — the engine downgrades those to the reference
-    op up-front, this is the belt-and-braces model-side check. ``getattr``
-    keeps it usable from model families whose configs lack the llama-only
-    fields (gpt2 has no sliding window, softcap or query scalar)."""
-    return (
-        kv_layout is not None
-        and getattr(kv_layout, "attention_impl", "reference") == "pallas"
-        and getattr(config, "sliding_window", None) is None
-    )
-
-
 def _sliding_flags(config) -> tuple:
     """The per-layer ``sliding`` xs of the decode and verify scans: one
     array, even layers local (HF layer_types), for alternating
@@ -1575,89 +1376,28 @@ def _sliding_flags(config) -> tuple:
     return ((jnp.arange(config.num_hidden_layers) % 2) == 0,)
 
 
-def _scan_layers_over_pool(layer_step, x, cache, layers, *flags):
-    """The decode layer loop of a paged cache: the pool rides in the carry
-    beside ``x``, whole, and the scan steps over ``(layer params, layer
-    index[, flags])`` — so the program's donated pool is updated in place
-    and handed back, never cut into per-layer slices and stacked again
-    (kvcache.py, "The pool's layout"). ``layer_step(x, layer_params, ck, cv,
-    layer, *flags)`` returns ``(x, ck, cv)`` with ``ck`` / ``cv`` the whole
-    pools."""
-    def body(carry, inputs):
-        x, ck, cv = carry
-        layer_params, layer, *rest = inputs
-        return layer_step(x, layer_params, ck, cv, layer, *rest), None
-
-    n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
-    (x, ck, cv), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (layers, jnp.arange(n_layers, dtype=jnp.int32), *flags),
-    )
-    return x, {"k": ck, "v": cv}
-
-
-def _pallas_attn_scale(config) -> float:
-    return float(
-        1.0 / np.sqrt(getattr(config, "query_pre_attn_scalar", None) or config.head_dim)
+def _serving_step(config: LlamaConfig, params, cache, tokens, pos, kv_layout, attend_op):
+    """Embed -> the layer loop over :func:`_step_block` -> the head, for a
+    window ``tokens`` (B, W) at ``pos``. ``attend_op`` is the seam's operation
+    (``kvcache.attend_step`` writes the store, ``attend_window`` only reads
+    it); what the store is lives behind it and ``scan_layers``."""
+    cdt = config.compute_dtype
+    x = params["embed_tokens"]["embedding"].astype(cdt)[tokens]
+    if config.scale_embeddings:
+        x = x * jnp.asarray(config.hidden_size**0.5, dtype=cdt)
+    attention = dict(
+        scale=1.0 / math.sqrt(config.query_pre_attn_scalar or config.head_dim),
+        softcap=config.attn_logit_softcap, window=config.sliding_window,
     )
 
+    def block(x, layer_params, attend, sliding=None):
+        attend = functools.partial(attend, pos=pos, sliding=sliding, **attention)
+        return _step_block(config, layer_params, x, pos, attend)
 
-def _pallas_decode_override(config, kv_layout, pos, ck_pool, cv_pool, layer):
-    """Decode-step attention override: commit the rope-rotated new K/V
-    column into the whole pool at ``layer`` FIRST (``commit_column`` — no
-    dense view), then run the flash-decode kernel over that layer's blocks
-    of it. Store→load identity makes this exact in f32; int8 pools pay one
-    bounded quantization on the current column (the same 4e-3·amax bound as
-    every other committed position)."""
-    from ..ops.paged_decode import paged_flash_decode
-
-    attn_scale = _pallas_attn_scale(config)
-    softcap = getattr(config, "attn_logit_softcap", None)
-
-    def override(q, k_new, v_new):
-        ck = kv_layout.commit_column(ck_pool, k_new, pos, layer)
-        cv = kv_layout.commit_column(cv_pool, v_new, pos, layer)
-        p = pos if jnp.ndim(pos) != 0 else jnp.broadcast_to(pos, (q.shape[0],))
-        if isinstance(ck, dict):
-            out = paged_flash_decode(
-                q, ck["q"], cv["q"], kv_layout.tables, p,
-                k_scale=ck["s"], v_scale=cv["s"],
-                scale=attn_scale, softcap=softcap, layer=layer,
-            )
-        else:
-            out = paged_flash_decode(
-                q, ck, cv, kv_layout.tables, p,
-                scale=attn_scale, softcap=softcap, layer=layer,
-            )
-        return out, ck, cv
-
-    return override
-
-
-def _pallas_verify_override(config, kv_layout, pos, ck_pool, cv_pool, layer):
-    """Verify-step attention override: the kernel walks committed history
-    in ``layer``'s blocks of the pool (strictly ``k_pos < pos``) and attends
-    the fresh window K/V in-register — read-only on the pool,
-    commit-after-accept stays with the engine."""
-    from ..ops.paged_decode import paged_flash_verify
-
-    attn_scale = _pallas_attn_scale(config)
-    softcap = getattr(config, "attn_logit_softcap", None)
-
-    def override(q, k_win, v_win):
-        if isinstance(ck_pool, dict):
-            return paged_flash_verify(
-                q, ck_pool["q"], cv_pool["q"], k_win, v_win,
-                kv_layout.tables, pos,
-                k_scale=ck_pool["s"], v_scale=cv_pool["s"],
-                scale=attn_scale, softcap=softcap, layer=layer,
-            )
-        return paged_flash_verify(
-            q, ck_pool, cv_pool, k_win, v_win, kv_layout.tables, pos,
-            scale=attn_scale, softcap=softcap, layer=layer,
-        )
-
-    return override
+    x, kept = scan_layers(
+        attend_op, kv_layout, block, x, cache, params["layers"], *_sliding_flags(config)
+    )
+    return _prefill_head(config, params, x), kept
 
 
 def llama_decode_step(config: LlamaConfig, params, cache, token, pos, *,
@@ -1667,52 +1407,11 @@ def llama_decode_step(config: LlamaConfig, params, cache, token, pos, *,
     vector (each row at its own position — continuous-batching slots).
     Returns (logits (B, V), new cache).
 
-    ``kv_layout`` (a :class:`~accelerate_tpu.kvcache.PagedKVLayout`) swaps
-    the KV store for a paged block pool: ``cache`` leaves are the whole pool,
-    which the layer loop carries beside ``x`` and each layer reaches by its
-    index (:func:`_scan_layers_over_pool`) — its blocks gathered into the
-    dense per-slot view right before the layer attends and the new column
-    scattered back after, or the column committed first and the Pallas
-    kernel run over the pool in place. ``None`` keeps the dense arena path
-    byte-for-byte unchanged."""
-    cdt = config.compute_dtype
-    x = params["embed_tokens"]["embedding"].astype(cdt)[token]
-    if config.scale_embeddings:
-        x = x * jnp.asarray(config.hidden_size**0.5, dtype=cdt)
-
-    pallas = _use_pallas_attention(config, kv_layout)
-    flags = _sliding_flags(config)
-
-    def paged_step(x, layer_params, ck, cv, layer, sliding=None):
-        if pallas:
-            override = _pallas_decode_override(config, kv_layout, pos, ck, cv, layer)
-            return _decode_layer(config, layer_params, x, None, None, pos,
-                                 sliding=sliding, attention_override=override)
-        x, vk, vv = _decode_layer(
-            config, layer_params, x, kv_layout.view(ck, layer),
-            kv_layout.view(cv, layer), pos, sliding=sliding,
-        )
-        return x, kv_layout.commit(ck, vk, pos, layer), kv_layout.commit(cv, vv, pos, layer)
-
-    def dense_body(x, inputs):
-        layer_params, ck, cv, *sliding = inputs
-        x, ck, cv = _decode_layer(config, layer_params, x, ck, cv, pos, *sliding)
-        return x, (ck, cv)
-
-    if kv_layout is not None:
-        x, new_cache = _scan_layers_over_pool(paged_step, x, cache, params["layers"], *flags)
-    else:
-        x, (new_k, new_v) = lax.scan(
-            dense_body, x, (params["layers"], cache["k"], cache["v"], *flags)
-        )
-        new_cache = {"k": new_k, "v": new_v}
-    x = rms_norm(x, params["final_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
-    if config.tie_word_embeddings:
-        logits = x @ params["embed_tokens"]["embedding"].astype(cdt).T
-    else:
-        logits = x @ params["lm_head"]["kernel"].astype(cdt)
-    logits = _tanh_softcap(logits, config.final_logit_softcap)
-    return logits[:, 0].astype(jnp.float32), new_cache
+    ``kv_layout`` (a :class:`~accelerate_tpu.kvcache.PagedKVLayout`) says
+    that ``cache`` leaves are the whole paged block pool; ``None`` that they
+    are the dense arena. What follows from either is kvcache.py's."""
+    logits, cache = _serving_step(config, params, cache, token, pos, kv_layout, attend_step)
+    return logits[:, 0], cache
 
 
 def llama_verify_step(config: LlamaConfig, params, cache, tokens, pos, *,
@@ -1722,58 +1421,12 @@ def llama_verify_step(config: LlamaConfig, params, cache, tokens, pos, *,
     (``pos`` a traced (B,) vector). Returns (logits (B, W, V) f32,
     window KV {"k","v"}: (L, B, W, kvh, hd)).
 
-    The cache is consumed READ-ONLY (the dense arena as scan xs, the paged
-    pool as a loop invariant each layer indexes): nothing is committed
-    here. The caller decides the accepted prefix from the logits and commits
-    exactly that many window columns via the backend's ``commit_window`` —
-    so a rejected draft suffix never touches the persistent arena/pool and
-    there is no rollback path. With ``kv_layout`` the layer's blocks of the
-    pool are gathered into the dense view first (same as decode), and the
-    window attends a temporary copy of that view."""
-    cdt = config.compute_dtype
-    x = params["embed_tokens"]["embedding"].astype(cdt)[tokens]
-    if config.scale_embeddings:
-        x = x * jnp.asarray(config.hidden_size**0.5, dtype=cdt)
-
-    pallas = _use_pallas_attention(config, kv_layout)
-    flags = _sliding_flags(config)
-
-    def paged_body(x, inputs):
-        # the pool is only read here: a loop invariant the body closes over,
-        # addressed by layer like the decode step's (never sliced as xs)
-        layer_params, layer, *sliding = inputs
-        ck, cv = cache["k"], cache["v"]
-        if pallas:
-            override = _pallas_verify_override(config, kv_layout, pos, ck, cv, layer)
-            x, wk, wv = _verify_layer(config, layer_params, x, None, None, pos,
-                                      *sliding, attention_override=override)
-        else:
-            x, wk, wv = _verify_layer(
-                config, layer_params, x, kv_layout.view(ck, layer),
-                kv_layout.view(cv, layer), pos, *sliding,
-            )
-        return x, (wk, wv)
-
-    def dense_body(x, inputs):
-        layer_params, ck, cv, *sliding = inputs
-        x, wk, wv = _verify_layer(config, layer_params, x, ck, cv, pos, *sliding)
-        return x, (wk, wv)
-
-    if kv_layout is not None:
-        layers = jnp.arange(config.num_hidden_layers, dtype=jnp.int32)
-        xs = (params["layers"], layers, *flags)
-        x, (win_k, win_v) = lax.scan(paged_body, x, xs)
-    else:
-        x, (win_k, win_v) = lax.scan(
-            dense_body, x, (params["layers"], cache["k"], cache["v"], *flags)
-        )
-    x = rms_norm(x, params["final_norm"]["scale"], config.rms_norm_eps, config.rms_norm_offset)
-    if config.tie_word_embeddings:
-        logits = x @ params["embed_tokens"]["embedding"].astype(cdt).T
-    else:
-        logits = x @ params["lm_head"]["kernel"].astype(cdt)
-    logits = _tanh_softcap(logits, config.final_logit_softcap)
-    return logits.astype(jnp.float32), {"k": win_k, "v": win_v}
+    The cache is consumed READ-ONLY: nothing is committed here. The caller
+    decides the accepted prefix from the logits and commits exactly that
+    many window columns via the backend's ``commit_window`` — so a rejected
+    draft suffix never touches the persistent arena/pool and there is no
+    rollback path."""
+    return _serving_step(config, params, cache, tokens, pos, kv_layout, attend_window)
 
 
 def create_llama(config: LlamaConfig, seed: int = 0, abstract: bool = False) -> Model:

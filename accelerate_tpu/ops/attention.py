@@ -25,6 +25,7 @@ __all__ = [
     "blockwise_attention_partials",
     "dot_product_attention",
     "blockwise_attention",
+    "cache_attention",
     "dispatch_attention",
     "paged_attention",
     "verify_attention",
@@ -133,6 +134,61 @@ def dot_product_attention(
         preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
     ).astype(v.dtype)
     return out.reshape(b, sq, h, d)
+
+
+def cache_attention(
+    q: jax.Array,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    pos: jax.Array,
+    *,
+    scale: Optional[float] = None,
+    softcap: Optional[float] = None,
+    window: Optional[int] = None,
+    sliding: Optional[jax.Array] = None,
+) -> jax.Array:
+    """A window of ``W`` queries a row over a dense cache: ``q`` (B, W, h, d)
+    at positions ``pos .. pos+W-1`` (``pos`` a traced scalar, the whole batch
+    in lockstep, or a traced (B,) vector) over ``cache_k`` / ``cache_v``
+    (B, S, h_kv, d) in which those positions are already written. Query ``j``
+    attends ``k_pos <= pos + j``; ``W = 1`` is a decode step. The one
+    attention every serving family's step runs when no kernel does
+    (kvcache.py decides which).
+
+    GQA attends grouped (the cache is never tiled ``h / h_kv`` times), the
+    scaled query and both sums are float32 (G402), ``softcap`` is applied
+    before any mask. ``window`` is the Mistral convention ``q_pos - k_pos <
+    window``; a traced bool ``sliding`` applies it only where true (Gemma-2's
+    alternating layers: the flag rides the layer loop). Per-(q, k) scores are
+    independent dot products, so row ``j = 0`` of a window equals the
+    single-query call bitwise. Masked scores hit ``NEG_INF``, which softmax
+    underflows to exactly 0: unwritten or padded positions never leak."""
+    b, w, h, d = q.shape
+    h_kv = cache_k.shape[2]
+    dtype = q.dtype
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
+    qg = (q.astype(jnp.float32) * scale).reshape(b, w, h_kv, h // h_kv, d)
+    scores = jnp.einsum(
+        "bqgrd,bkgd->bgrqk", qg, cache_k.astype(dtype),
+        preferred_element_type=jnp.float32,  # G402: f32 score accumulation
+    )
+    scores = tanh_softcap(scores, softcap)
+    k_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 4)
+    q_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 3) + (
+        pos if jnp.ndim(pos) == 0 else pos[:, None, None, None, None]
+    )
+    scores = jnp.where(k_pos <= q_pos, scores, NEG_INF)
+    if window is not None:
+        in_window = q_pos - k_pos < window
+        if sliding is not None:
+            in_window = jnp.logical_or(jnp.logical_not(sliding), in_window)
+        scores = jnp.where(in_window, scores, NEG_INF)
+    weights = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum(
+        "bgrqk,bkgd->bqgrd", weights.astype(dtype), cache_v.astype(dtype),
+        preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
+    )
+    return out.reshape(b, w, h, d).astype(dtype)
 
 
 def paged_attention(

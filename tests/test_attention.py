@@ -77,3 +77,34 @@ def test_q_offset_ring_semantics():
     # second q block attends to all of kf with offset 32
     out2 = dot_product_attention(q2, kf, vf, causal=True, q_offset=32)
     np.testing.assert_allclose(np.asarray(ref[:, 32:]), np.asarray(out2), atol=1e-5)
+
+
+@pytest.mark.parametrize("w", [1, 4])
+@pytest.mark.parametrize("per_row_pos", [False, True])
+@pytest.mark.parametrize("kvh", [4, 2])
+def test_cache_attention_matches_rows_of_the_full_forward(kvh, per_row_pos, w):
+    """The one attention over a dense cache (the serving steps' seam,
+    kvcache.py) against rows ``pos .. pos+W-1`` of the full causal forward:
+    grouped and ungrouped, the whole batch at one position or each row at its
+    own, a decode step (W = 1) and a verify window. What lies past the window
+    in the cache is garbage a real cache holds too, and must not leak. With
+    softcap and a sliding window besides, and the traced flag that turns the
+    window off for a layer."""
+    from accelerate_tpu.ops.attention import cache_attention, dispatch_attention
+
+    q, k, v = _qkv(s=24, kvh=kvh)
+    starts = [7, 13] if per_row_pos else [9, 9]
+    pos = jnp.asarray(starts, jnp.int32) if per_row_pos else jnp.int32(9)
+    rows = np.asarray(starts)[:, None] + np.arange(w)[None, :]  # (B, W)
+    at = np.arange(2)[:, None], rows
+    noise = jnp.asarray(np.random.default_rng(1).normal(size=k.shape), jnp.float32)
+    written = (jnp.arange(24)[None, :] < jnp.asarray(starts)[:, None] + w)[:, :, None, None]
+    cache_k, cache_v = jnp.where(written, k, noise), jnp.where(written, v, noise)
+    for kw in ({}, {"softcap": 5.0}, {"window": 6}):
+        want = np.asarray(dispatch_attention("xla", q, k, v, causal=True, **kw))[at]
+        got = cache_attention(q[at], cache_k, cache_v, pos, **kw)
+        assert got.shape == (2, w, 4, 16)
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-6, err_msg=str(kw))
+    everywhere = cache_attention(q[at], cache_k, cache_v, pos, window=6, sliding=jnp.bool_(False))
+    plain = cache_attention(q[at], cache_k, cache_v, pos)
+    np.testing.assert_array_equal(np.asarray(everywhere), np.asarray(plain))

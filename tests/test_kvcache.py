@@ -702,3 +702,36 @@ def test_engine_stats_count_keys_and_values_and_report_recurrent_bytes():
     eng.drain()
     released = eng.stats()
     assert released["kv"]["live_tokens"] == 0 and released["recurrent_state_bytes"] == 3 * 2 * 2 * 64 * 2
+
+
+# --------------------------------------------- one seam between step and cache
+def test_model_families_reach_the_cache_only_through_the_seam():
+    """The layering (kvcache.py, "the seam to a model's step"): what the store
+    is and which attend path runs is decided in kvcache.py, so no module under
+    ``models/`` imports the paged kernels or asks whether a pool is the int8
+    ``{"q","s"}`` pair, and ``gpt2.py`` and ``lfm2.py`` take from their sibling
+    ``llama.py`` model mathematics by its public names only: of its private
+    names the loss's helper and the sequence body's remat policy, nothing
+    cache-facing."""
+    import ast
+    import pathlib
+
+    import accelerate_tpu.models as models
+
+    private_from_llama = {"_ce_from_hidden", "_remat_policy"}
+    for path in sorted(pathlib.Path(models.__file__).parent.glob("*.py")):
+        tree = ast.parse(path.read_text())
+        for node in ast.walk(tree):
+            if isinstance(node, (ast.Import, ast.ImportFrom)):
+                names = [a.name for a in node.names] + [getattr(node, "module", None) or ""]
+                assert not any("paged_decode" in n for n in names), (path.name, node.lineno)
+                if (isinstance(node, ast.ImportFrom) and node.module == "llama"
+                        and path.name in ("gpt2.py", "lfm2.py")):
+                    private = {a.name for a in node.names if a.name.startswith("_")}
+                    assert private <= private_from_llama, (path.name, private)
+            is_dict_test = (
+                isinstance(node, ast.Call) and getattr(node.func, "id", None) == "isinstance"
+                and getattr(node.args[1], "id", None) == "dict"
+            )
+            if is_dict_test:  # the loss's ``isinstance(out, dict)`` is the one
+                assert (path.name, node.args[0].id) == ("llama.py", "out"), (path.name, node.lineno)

@@ -401,6 +401,63 @@ def test_verify_attention_query0_matches_paged_attention():
     np.testing.assert_array_equal(np.asarray(ver[:, :1]), np.asarray(dec))
 
 
+def _step_family(name, compute_dtype):
+    from accelerate_tpu.models.gpt2 import GPT2Config, create_gpt2
+
+    if name == "gpt2":
+        return create_gpt2(GPT2Config.tiny(compute_dtype=compute_dtype), seed=0)
+    features = {
+        "llama_gqa_bias_softcap": dict(attention_bias=True, attn_logit_softcap=30.0),
+        # the window downgrades the kernel to the reference attend
+        "llama_alternating_window": dict(
+            sliding_window=6, alternating_sliding_window=True, attn_logit_softcap=30.0),
+    }[name]
+    return create_llama(LlamaConfig.tiny(compute_dtype=compute_dtype, **features), seed=1)
+
+
+@pytest.mark.parametrize("store", ["arena", "pool_reference", "pool_pallas"])
+@pytest.mark.parametrize(
+    "family", ["gpt2", "llama_gqa_bias_softcap", "llama_alternating_window"])
+def test_verify_step_at_a_window_of_one_is_the_decode_step(family, store):
+    """A family has one step block, and decode is that block at a window of
+    one: ``verify_step`` over one token gives ``decode_step``'s logits
+    bitwise, in bfloat16 compute as served, wherever the one attention
+    (``ops.attention.cache_attention``) runs: the arena and the gathered pool.
+    The decode and the verify kernel are two schedules of the same sums (a
+    walk over chunks of the pool with the column committed; history from the
+    pool and the window in registers): they agree to float32 rounding and in
+    every argmax, at the parent as here. The window's keys and values are
+    what the decode step wrote at ``pos``."""
+    from accelerate_tpu.kvcache import PagedKVLayout, pool_from_dense
+
+    model = _step_family(family, jnp.float32 if store == "pool_pallas" else jnp.bfloat16)
+    config, params = model.config, model.params
+    fam = config.serving_family()
+    rng = np.random.default_rng(5)
+    ids = jnp.asarray(rng.integers(1, 255, size=(2, 16)), jnp.int32)
+    last = jnp.asarray([8, 12], jnp.int32)
+    _, cache = fam.prefill_at(config, params, ids, 32, last)
+    layout = None
+    if store != "arena":
+        cache, tables = pool_from_dense(cache, 8, quantized=False)
+        layout = PagedKVLayout(tables, 8, config.compute_dtype, fam.head_dim,
+                               attention_impl=store.split("_")[1])
+    token = jnp.asarray([[7], [200]], jnp.int32)
+    pos = last + 1
+    window_logits, window = fam.verify_step(config, params, cache, token, pos, kv_layout=layout)
+    logits, new_cache = fam.decode_step(config, params, cache, token, pos, kv_layout=layout)
+    assert window_logits.shape == (2, 1, config.vocab_size)
+    if store == "pool_pallas":
+        np.testing.assert_allclose(np.asarray(window_logits[:, 0]), np.asarray(logits), atol=1e-5)
+        np.testing.assert_array_equal(np.argmax(window_logits[:, 0], -1), np.argmax(logits, -1))
+    else:
+        np.testing.assert_array_equal(np.asarray(window_logits[:, 0]), np.asarray(logits))
+    if store == "arena":
+        for which in ("k", "v"):
+            wrote = np.stack([np.asarray(new_cache[which][:, r, int(pos[r])]) for r in range(2)], 1)
+            np.testing.assert_array_equal(np.asarray(window[which][:, :, 0]), wrote)
+
+
 # ------------------------------------------------------------ server plumbing
 @pytest.mark.parametrize("kv_cache", ["dense", "paged"])
 def test_server_spec_greedy_parity_and_gauges(model, get_engine, kv_cache):
