@@ -14,8 +14,8 @@ behavior) and counted in the aux metrics.
 
 Beside it, :func:`dropless_moe` is the expert layer a server needs: sigmoid
 scores, every token reaches the experts it chose whatever the rest of the
-batch chose, rows sorted by expert into one grouped matmul
-(``jax.lax.ragged_dot``), and it is told which experts it holds.
+batch chose, rows sorted by expert into grouped matmuls (the Pallas kernel
+``moe_gmm`` of :mod:`.grouped_matmul`), and it is told which experts it holds.
 """
 
 from __future__ import annotations
@@ -26,6 +26,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from .grouped_matmul import group_visits, grouped_matmul
 
 __all__ = [
     "Routing", "route_topk", "moe_ffn", "load_balancing_loss", "router_z_loss",
@@ -204,8 +206,11 @@ def dropless_moe(
     PR 30).
 
     The (row, expert) pairs are sorted by expert, held experts first, and the
-    three matmuls are ``jax.lax.ragged_dot`` over the groups, which the TPU
-    compiler lowers to one grouped-matmul kernel each. A row's result is
+    three matmuls are :func:`~.grouped_matmul.grouped_matmul` over the groups:
+    one ``moe_gmm`` Pallas kernel each (interpreted off the TPU), its tiles
+    chosen from the rows and widths at hand, the groups' visits computed once
+    and shared by the three; differentiated, they are ``jax.lax.ragged_dot``.
+    A row's result is
     computed from that row alone (its dot products, then its k parts summed
     in the order of its own choice): it does not depend on what else is in
     the batch.
@@ -230,21 +235,17 @@ def dropless_moe(
     sizes = jnp.roll(rows, -first)[:g]  # the held experts' rows, in their local order
     if stacked:
         n_layers = w1.shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * g,), jnp.int32), sizes, (layer * g,)
-        )
         w1, w3, w2 = (w.reshape(n_layers * g, *w.shape[2:]) for w in (w1, w3, w2))
+    # which expert multiplies which rows: made once, the three matmuls share it
+    visits = group_visits(sizes, n * k, layer * g if stacked else 0)
     xs = x.astype(compute_dtype)[order // k]  # (N * k, D), sorted by expert
 
     def grouped(lhs, rhs):
-        return jax.lax.ragged_dot(
-            lhs, rhs.astype(compute_dtype), sizes, preferred_element_type=jnp.float32
-        )
+        return grouped_matmul(lhs, rhs.astype(compute_dtype), visits)
 
     hidden = (jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)).astype(compute_dtype)
-    parts = grouped(hidden, w2)  # (N * k, D) float32
-    # rows past the last group (experts held elsewhere) were never computed
-    parts = jnp.where((local[order] < g)[:, None], parts, 0.0)
+    # (N * k, D) float32; rows past the last group (experts held elsewhere) are zero
+    parts = grouped(hidden, w2)
     parts = parts[jnp.argsort(order)].reshape(n, k, d)  # back to (row, choice)
     out = jnp.sum(parts * weights[:, :, None], axis=1)
     return out.astype(x.dtype), rows
