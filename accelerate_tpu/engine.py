@@ -345,9 +345,15 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"{'speculative decoding' if spec is not None else 'chunked prefill'} "
                 f"needs a verify_step, which {type(self.config).__name__}'s family "
-                "does not hand over: a window of tokens over the cache would have "
-                "to leave the family's recurrent state as of the accepted prefix "
-                "(the missing piece: a recurrent-state snapshot to rewind to)"
+                "does not hand over: " + (
+                    "a window of tokens over the cache would have "
+                    "to leave the family's recurrent state as of the accepted prefix "
+                    "(the missing piece: a recurrent-state snapshot to rewind to)"
+                    if self._family.recurrent_layers else
+                    "no window of tokens attends its cache yet (the missing piece for "
+                    "a latent cache: kvcache.attend_window and "
+                    "ops/paged_decode.py::paged_flash_verify over one-leaf rows)"
+                )
             )
         self.slots = slots
         self.max_len = max_len
@@ -397,6 +403,8 @@ class ContinuousBatchingEngine:
             pool_blocks=pool_blocks, attention_impl=attention_impl,
             host_tier_bytes=host_tier_bytes,
         )
+        # bytes one position holds in the store over all layers, as allocated
+        self._kv_row_bytes = self._backend.row_bytes()
         if hasattr(self._backend, "bind_cache_reader"):
             # spill gathers read the engine's CURRENT donated cache: after
             # any dispatch self._donated is rebound to the program's output
@@ -997,6 +1005,17 @@ class ContinuousBatchingEngine:
         # count must fit the pool — names engine_block_size / pool blocks)
         self._backend.validate_request(prompt_len, max_new_tokens)
 
+    def validate_prompt(self, prompt: np.ndarray) -> None:
+        """Raise ValueError for a token id the embedding does not hold: the
+        gather would clamp it to a row of another token, in silence (a model
+        that serves a slice of its vocabulary holds the slice's ids only)."""
+        vocab = getattr(self.config, "vocab_size", None)
+        if vocab is not None and prompt.size and not 0 <= prompt.min() <= prompt.max() < vocab:
+            raise ValueError(
+                f"prompt holds token id(s) outside the vocabulary served here "
+                f"(0 .. {vocab - 1}): min {int(prompt.min())}, max {int(prompt.max())}"
+            )
+
     def can_admit(self, prompt, max_new_tokens: int) -> bool:
         """True when a slot AND the KV capacity for this request are free
         right now. Dense backends only need the slot; paged backends also
@@ -1029,6 +1048,7 @@ class ContinuousBatchingEngine:
         :meth:`step` tick."""
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         self.validate_request(len(prompt), max_new_tokens)
+        self.validate_prompt(prompt)
         if len(prompt) > self.prompt_bucket:
             return self._insert_chunked(
                 prompt, max_new_tokens=max_new_tokens, temperature=temperature,
@@ -1477,6 +1497,7 @@ class ContinuousBatchingEngine:
             kv_live_tokens=self.live_tokens(),
             kv_reserved_tokens=self._backend.reserved_tokens(),
             kv_walked_tokens=self.walked_tokens(),
+            kv_row_bytes=self._kv_row_bytes,
         ):
             self._donated, self._carried, counters = self._decode_jit(
                 self._donated, self._carried, self.model.params,

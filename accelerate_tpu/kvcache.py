@@ -93,6 +93,17 @@ same bytes), so its recurrent state is its own. ``hbm_bytes``, ``live`` and
 ``reserved`` tokens count keys and values; ``stats()["recurrent_state_bytes"]``
 reports the recurrent rows beside them.
 
+A latent row. A family whose values are a prefix of its keys' own rows (latent
+attention: one compressed row a token a layer under every query head) says so
+with ``value_dim`` (models/family.py): the cache then has ONE leaf, ``"k"``, of
+``kv_heads * head_dim`` values a position, and no ``"v"``; the store a step
+hands the seam is ``(rows, None)``, the reference attend takes its values as
+``rows[..., :value_dim]`` of the dense view, and the kernel copies each live row
+out of the pool once (``paged_flash_decode(..., v_pool=None, value_dim=...)``).
+The latent is never expanded in the arena or in the pool. ``paged_int8`` (one
+scale a position over a row whose two parts differ in size) and the host tier
+refuse such a family when the engine is built.
+
 Backends:
 
 ``dense``       today's arena semantics behind the same interface
@@ -343,12 +354,14 @@ def _run_kernel(kernel, q, k_pool, v_pool, *operands, **kw):
     return kernel(q, k_pool, v_pool, *operands, **kw)
 
 
-def _attend_dense(write, layout, store, layer, q, k, v, pos, **attention):
+def _attend_dense(write, layout, store, layer, q, k, v, pos, value_dim=None, **attention):
     """The reference attend: each of ``store``'s two as one layer's dense
     ``(B, S, kv_heads, head_dim)`` (gathered from the pool, the arena's slice,
     or with ``layer`` None the arena's layer as handed in), ``k`` / ``v``
     written into it by ``write``, and the one attention over it. Returns
-    ``(out, dense keys, dense values)``."""
+    ``(out, dense keys, dense values)``. With ``value_dim`` the store has no
+    values of its own: they are the first ``value_dim`` columns of the keys'
+    rows, and ``dense values`` is None."""
     def dense(which, new):
         if layout is not None:
             view = layout.view(which, layer)
@@ -356,12 +369,15 @@ def _attend_dense(write, layout, store, layer, q, k, v, pos, **attention):
             view = which if layer is None else which[layer]
         return write(view, new, pos)
 
-    dense_k, dense_v = dense(store[0], k), dense(store[1], v)
+    dense_k = dense(store[0], k)
+    if value_dim is not None:
+        return cache_attention(q, dense_k, dense_k[..., :value_dim], pos, **attention), dense_k, None
+    dense_v = dense(store[1], v)
     return cache_attention(q, dense_k, dense_v, pos, **attention), dense_k, dense_v
 
 
 def attend_step(layout, store, layer, q, k, v, pos, *, scale=None, softcap=None,
-                window=None, sliding=None):
+                window=None, sliding=None, value_dim=None):
     """One new position a row: write ``k`` / ``v`` (B, 1, kv_heads, head_dim,
     rotated) at ``pos`` (a traced scalar or (B,)) and attend ``q`` over
     positions ``<= pos``. Returns ``(out (B, 1, heads, head_dim), store)``.
@@ -375,27 +391,36 @@ def attend_step(layout, store, layer, q, k, v, pos, *, scale=None, softcap=None,
     FIRST and the kernel walks the block tables over the pool in place (store
     then load is exact in float; an int8 pool pays the one bounded
     quantization every committed position pays). ``scale``, ``softcap``,
-    ``window``, ``sliding``: :func:`~accelerate_tpu.ops.attention.cache_attention`'s."""
+    ``window``, ``sliding``: :func:`~accelerate_tpu.ops.attention.cache_attention`'s.
+
+    A latent store (``value_dim``, module docstring): ``store`` is ``(rows,
+    None)``, ``k`` the new row ``(B, 1, 1, width)`` and ``v`` None; ``q`` is as
+    wide as a row and the result ``value_dim`` wide a head."""
     keys, values = store
+    latent = value_dim is not None
     if _kernel_attends(layout, window):
         from .ops.paged_decode import paged_flash_decode
 
         keys = layout.commit_column(keys, k, pos, layer)
-        values = layout.commit_column(values, v, pos, layer)
+        if not latent:
+            values = layout.commit_column(values, v, pos, layer)
         rows = pos if jnp.ndim(pos) else jnp.broadcast_to(pos, q.shape[:1])
         out = _run_kernel(paged_flash_decode, q, keys, values, layout.tables, rows,
-                          scale=scale, softcap=softcap, layer=layer)
+                          scale=scale, softcap=softcap, layer=layer, value_dim=value_dim)
         return out.astype(q.dtype), (keys, values)
     out, dense_k, dense_v = _attend_dense(
-        _write_at, layout, store, layer, q, k, v, pos,
+        _write_at, layout, store, layer, q, k, v, pos, value_dim,
         scale=scale, softcap=softcap, window=window, sliding=sliding,
     )
-    if layout is not None:
-        return out, (layout.commit(keys, dense_k, pos, layer),
-                     layout.commit(values, dense_v, pos, layer))
-    if layer is None:
-        return out, (dense_k, dense_v)
-    return out, (keys.at[layer].set(dense_k), values.at[layer].set(dense_v))
+
+    def committed(which, dense):
+        if dense is None:  # a latent store keeps no values
+            return None
+        if layout is not None:
+            return layout.commit(which, dense, pos, layer)
+        return dense if layer is None else which.at[layer].set(dense)
+
+    return out, (committed(keys, dense_k), committed(values, dense_v))
 
 
 def attend_window(layout, store, layer, q, k, v, pos, *, scale=None, softcap=None,
@@ -833,6 +858,12 @@ def _recurrent_shape(family, slots: int) -> Optional[tuple]:
     return (family.recurrent_layers, slots, *family.recurrent_shape)
 
 
+def _kv_leaves(family) -> tuple:
+    """The cache's leaves of keys and values: a family whose values are a
+    prefix of its keys' rows (``value_dim``) keeps the one."""
+    return ("k",) if family.value_dim is not None else ("k", "v")
+
+
 def _write_recurrent(cache, new_cache, slot) -> dict:
     """The ``"recurrent"`` leaf with ``slot``'s row replaced by the prefill's
     (``(layers, 1, *state shape)``); nothing where the family keeps none."""
@@ -905,6 +936,12 @@ class KVCacheBackend:
     def stats(self) -> dict:
         raise NotImplementedError
 
+    def row_bytes(self) -> int:
+        """Bytes one position holds in the store over all its layers, as the
+        leaves were allocated (keys and values, or a latent family's one row;
+        an int8 pool's scales with them)."""
+        raise NotImplementedError
+
     def recurrent_state_bytes(self) -> int:
         """Bytes of the per-slot recurrent state (0 where the family's only
         state is keys and values); not part of :meth:`hbm_bytes`."""
@@ -931,6 +968,7 @@ class DenseKVBackend(KVCacheBackend):
         self.max_len = max_len
         family = config.serving_family()
         self._shape = (family.kv_layers, slots, max_len, family.kv_heads, family.head_dim)
+        self._leaves = _kv_leaves(family)
         self._recurrent = _recurrent_shape(family, slots)
         self._dtype = config.compute_dtype
         # tables are inert for dense; a constant (slots, 1) zero array keeps
@@ -939,8 +977,7 @@ class DenseKVBackend(KVCacheBackend):
 
     def init_device_state(self):
         return {
-            "k": jnp.zeros(self._shape, self._dtype),
-            "v": jnp.zeros(self._shape, self._dtype),
+            **{which: jnp.zeros(self._shape, self._dtype) for which in self._leaves},
             **self._init_recurrent(),
         }
 
@@ -958,7 +995,7 @@ class DenseKVBackend(KVCacheBackend):
                     new_cache[which].astype(cache[which].dtype),
                     (0, slot, 0, 0, 0),
                 )
-                for which in ("k", "v")
+                for which in self._leaves
             },
             **_write_recurrent(cache, new_cache, slot),
         }
@@ -977,7 +1014,7 @@ class DenseKVBackend(KVCacheBackend):
                 which: cache[which].at[:, rows, idx].set(
                     window_kv[which].astype(cache[which].dtype), mode="drop"
                 )
-                for which in ("k", "v")
+                for which in self._leaves
             },
         }
 
@@ -997,7 +1034,10 @@ class DenseKVBackend(KVCacheBackend):
         pass
 
     def hbm_bytes(self):
-        return 2 * int(np.prod(self._shape)) * jnp.dtype(self._dtype).itemsize
+        return len(self._leaves) * int(np.prod(self._shape)) * jnp.dtype(self._dtype).itemsize
+
+    def row_bytes(self):
+        return self.hbm_bytes() // (self.slots * self.max_len)
 
     def reserved_tokens(self):
         return self.slots * self.max_len
@@ -1056,6 +1096,16 @@ class PagedKVBackend(KVCacheBackend):
         self.pool_blocks = pool_blocks
         family = config.serving_family()
         self._kvh, self._hd = family.kv_heads, family.head_dim
+        self._leaves = _kv_leaves(family)
+        if family.value_dim is not None and (quantized or host_tier_bytes > 0):
+            raise ValueError(
+                f"{'kv_cache=paged_int8' if quantized else 'kv_host_tier_bytes'} cannot "
+                "serve a latent cache (a family whose values are the first columns of "
+                "its keys' rows, one leaf): the int8 pool keeps one scale a position "
+                "over keys and another over values, and the host tier spills and "
+                "restores a block as a pair of them (the missing piece: a scale for "
+                "each part of a latent row, and one-leaf spill payloads)"
+            )
         # the pool's leading axis: the layers that keep keys and values
         self._layers = family.kv_layers
         self._recurrent = _recurrent_shape(family, slots)
@@ -1108,7 +1158,7 @@ class PagedKVBackend(KVCacheBackend):
                 "s": jnp.zeros(shape[:3], jnp.float32),
             }
             return {"k": leaf(), "v": leaf(), **self._init_recurrent()}
-        return {"k": jnp.zeros(shape, self._dtype), "v": jnp.zeros(shape, self._dtype),
+        return {**{which: jnp.zeros(shape, self._dtype) for which in self._leaves},
                 **self._init_recurrent()}
 
     def make_layout(self, tables):
@@ -1130,7 +1180,7 @@ class PagedKVBackend(KVCacheBackend):
         n, bs = self.prefill_blocks, self.block_size
         ids = table_row[:n]
         out = _write_recurrent(cache, new_cache, slot)
-        for which in ("k", "v"):
+        for which in self._leaves:
             pool = cache[which]
             fresh = new_cache[which][:, 0, : n * bs]  # (L, n * bs, kvh, hd)
             fresh = fresh.reshape(fresh.shape[0], n, bs, *fresh.shape[2:])
@@ -1152,7 +1202,7 @@ class PagedKVBackend(KVCacheBackend):
             **cache,
             **{
                 which: layout.commit_window(cache[which], window_kv[which], pos, count)
-                for which in ("k", "v")
+                for which in self._leaves
             },
         }
 
@@ -1192,7 +1242,7 @@ class PagedKVBackend(KVCacheBackend):
         """Host bytes one spilled block occupies (K + V payload; int8 keeps
         the quantized bytes + f32 scales — a spilled block restores to the
         identical pool bytes it held)."""
-        return 2 * self._per_block_bytes()
+        return len(self._leaves) * self._per_block_bytes()
 
     def bind_cache_reader(self, reader: Callable[[], Any]) -> None:
         """The engine hands us a zero-cost view of its CURRENT donated
@@ -1393,7 +1443,10 @@ class PagedKVBackend(KVCacheBackend):
         return per_block
 
     def hbm_bytes(self):
-        return 2 * self.pool_blocks * self._per_block_bytes()
+        return len(self._leaves) * self.pool_blocks * self._per_block_bytes()
+
+    def row_bytes(self):
+        return len(self._leaves) * self._per_block_bytes() // self.block_size
 
     def hbm_bytes_live(self):
         """Bytes the Pallas flash-decode kernel actually reads per step:
@@ -1402,7 +1455,7 @@ class PagedKVBackend(KVCacheBackend):
         footprint (:meth:`hbm_bytes`) stays what HBM *holds*; this is what
         a decode step *touches* — the runtime counterpart of the G203
         per-program HBM table's pallas rows."""
-        return 2 * self.pool.active_blocks() * self._per_block_bytes()
+        return len(self._leaves) * self.pool.active_blocks() * self._per_block_bytes()
 
     def reserved_tokens(self):
         return (self.pool.active_blocks()) * self.block_size
@@ -1464,7 +1517,8 @@ def pool_from_dense(cache, block_size: int, quantized: bool):
     ``generate()`` run its decode scan through the same
     :class:`PagedKVLayout` gather/commit ops as the engine (one KV story,
     bitwise parity in f32). ``total_len`` must divide by ``block_size``. A leaf
-    that is not keys or values (a family's recurrent state) passes through."""
+    that is not keys or values (a family's recurrent state) passes through; a
+    latent family's cache has no ``"v"`` to relay."""
     def relay(dense):
         L, b, total, kvh, hd = dense.shape
         nb = total // block_size
@@ -1473,9 +1527,8 @@ def pool_from_dense(cache, block_size: int, quantized: bool):
             q, s = kv_quantize(pool)
             return {"q": _merge_heads(q), "s": s}
         return _merge_heads(pool)
-    k = relay(cache["k"])
-    v = relay(cache["v"])
+    relaid = {which: relay(cache[which]) for which in ("k", "v") if which in cache}
     b = cache["k"].shape[1]
     nb = cache["k"].shape[2] // block_size
     tables = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
-    return {**cache, "k": k, "v": v}, tables
+    return {**cache, **relaid}, tables

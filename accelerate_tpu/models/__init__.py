@@ -4,4 +4,5 @@ from .gpt2 import GPT2Config, create_gpt2, gpt2_apply, gpt2_loss, init_gpt2_para
 from .t5 import T5Config, create_t5, t5_apply, t5_loss, init_t5_params
 from .resnet import ResNetConfig, create_resnet, resnet_apply, resnet_classification_loss
 from .lfm2 import Lfm2Config, create_lfm2, lfm2_apply, lfm2_loss, init_lfm2_params
+from .axk1 import AxK1Config, create_axk1, axk1_apply, axk1_loss, init_axk1_params
 from .family import ServingFamily

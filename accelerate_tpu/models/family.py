@@ -41,6 +41,14 @@ class ServingFamily:
     ``prefill_at`` returns as of each prompt's true last position and
     ``decode_step`` advances.
 
+    A latent cache. A family whose values are a prefix of its keys' own rows
+    (latent attention: one compressed row a token a layer, shared by every query
+    head) sets ``value_dim``: the cache then has the one leaf ``"k"`` of
+    ``kv_heads * head_dim`` values a position (``head_dim`` is the row's whole
+    width) and no ``"v"``; a row's first ``value_dim`` columns are also its
+    value, the step hands the seam a query as wide as a row and gets a result
+    ``value_dim`` wide a head (kvcache.py, "A latent row").
+
     ``step_summary``: where set, ``prefill``, ``prefill_at`` and ``decode_step``
     return a third value, a dict of small device arrays that count what the
     step did (rows an expert got); it rides the engine's readback ring beside
@@ -58,3 +66,4 @@ class ServingFamily:
     recurrent_layers: int = 0
     recurrent_shape: Tuple[int, ...] = ()
     step_summary: Optional[Callable] = None
+    value_dim: Optional[int] = None

@@ -81,6 +81,7 @@ def dot_product_attention(
     segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Reference attention, fully materialized scores. XLA fuses this well for
     moderate sequence lengths; use the Pallas flash kernel (ops/flash_attention)
@@ -91,12 +92,16 @@ def dot_product_attention(
     for every engine (dense/blockwise/flash/ring/Ulysses): the lower bound
     applies EVEN WITH ``causal=False``, so a windowed query never attends
     to future keys. There is no symmetric/two-sided window mode; pass a
-    ``bias`` for bidirectional locality patterns."""
+    ``bias`` for bidirectional locality patterns.
+
+    ``v`` may be narrower or wider a head than ``q`` and ``k`` (latent
+    attention up-projected: keys 192, values 128): the result is as wide as
+    ``v``. ``scale`` overrides ``1/sqrt(d)``."""
     b, sq, h, d = q.shape
     h_kv = k.shape[2]
     n_rep = h // h_kv
     sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     # GQA attends grouped: q reshaped (b, sq, h_kv, n_rep, d) so each kv
     # head broadcasts over its n_rep query heads INSIDE the einsum — K/V are
     # never physically tiled n_rep× (an n_rep× KV bandwidth/memory saving,
@@ -133,7 +138,7 @@ def dot_product_attention(
         "bgrqk,bkgd->bqgrd", weights.astype(v.dtype), v,
         preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
     ).astype(v.dtype)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def cache_attention(
@@ -162,7 +167,12 @@ def cache_attention(
     alternating layers: the flag rides the layer loop). Per-(q, k) scores are
     independent dot products, so row ``j = 0`` of a window equals the
     single-query call bitwise. Masked scores hit ``NEG_INF``, which softmax
-    underflows to exactly 0: unwritten or padded positions never leak."""
+    underflows to exactly 0: unwritten or padded positions never leak.
+
+    ``cache_v`` may be narrower than ``cache_k``, the result is as wide as it
+    is: over a latent cache the values are the first columns of the keys' own
+    rows (``cache_v = cache_k[..., :value_dim]``, kvcache.py), and this is the
+    one attention over it too."""
     b, w, h, d = q.shape
     h_kv = cache_k.shape[2]
     dtype = q.dtype
@@ -188,7 +198,7 @@ def cache_attention(
         "bgrqk,bkgd->bqgrd", weights.astype(dtype), cache_v.astype(dtype),
         preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
     )
-    return out.reshape(b, w, h, d).astype(dtype)
+    return out.reshape(b, w, h, cache_v.shape[-1]).astype(dtype)
 
 
 def paged_attention(
@@ -257,7 +267,7 @@ def paged_attention(
         "bgrqk,bkgd->bqgrd", weights.astype(v.dtype), v,
         preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
     ).astype(v.dtype)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def verify_attention(
@@ -321,7 +331,7 @@ def verify_attention(
         "bgrqk,bkgd->bqgrd", weights.astype(v.dtype), v,
         preferred_element_type=jnp.float32,  # G402: f32 PV accumulation
     ).astype(v.dtype)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def _shard_map_over_batch_heads(fn, q, k):

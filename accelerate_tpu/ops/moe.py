@@ -156,24 +156,55 @@ def moe_ffn(
 
 
 # ------------------------------------------------------------ dropless experts
+def _keep_best_groups(scores, n_group: int, topk_group: int):
+    """``scores`` (N, E) with every expert outside a row's ``topk_group`` best
+    groups at ``-inf``: the ``E`` experts lie in ``n_group`` groups of equal
+    size, side by side; a group's score is the sum of its two best scores (the
+    DeepSeek-V3 rule), and ties go to the lower group as they go to the lower
+    expert."""
+    n, e = scores.shape
+    grouped = scores.reshape(n, n_group, e // n_group)
+    group_scores = jnp.sum(jax.lax.top_k(grouped, min(2, e // n_group))[0], axis=-1)
+    _, kept = jax.lax.top_k(group_scores, topk_group)  # (N, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, e)
+
+
 def route_sigmoid_topk(x, router_kernel, expert_bias, num_selected: int, *,
-                       norm_topk: bool = True, scale: float = 1.0):
+                       norm_topk: bool = True, norm_eps: float = 1e-6,
+                       scale: float = 1.0, n_group: int = 1, topk_group: int = 1):
     """Sigmoid routing in float32: ``s = sigmoid(x @ W_r)``; the chosen experts
     are the top-k of ``s + expert_bias`` (the bias takes part in the choice
-    only); the weights are ``s`` of the chosen, renormalised over them
-    (``/ (sum + 1e-6)``) when ``norm_topk``, times ``scale``. ``x`` (N, D) ->
-    ``(experts (N, k) int32, weights (N, k) float32)``. A row's routing depends
-    on that row alone."""
+    only), with ``n_group > 1`` among the experts of a row's ``topk_group`` best
+    groups only (:func:`_keep_best_groups`); the weights are ``s`` of the
+    chosen, renormalised over them (``/ (sum + norm_eps)``) when ``norm_topk``,
+    times ``scale``. ``x`` (N, D) -> ``(experts (N, k) int32, weights (N, k)
+    float32)``. A row's routing depends on that row alone."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
     choose_by = scores if expert_bias is None else scores + expert_bias.astype(jnp.float32)
+    if n_group > 1:
+        choose_by = _keep_best_groups(choose_by, n_group, topk_group)
     _, experts = jax.lax.top_k(choose_by, num_selected)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + norm_eps)
     return experts.astype(jnp.int32), weights * scale
+
+
+def _head_pairs(pairs: int, held: int, routed: int) -> int:
+    """How many of the ``pairs`` (row, choice) pairs, sorted held experts first,
+    the grouped matmuls are handed when ``held`` of ``routed`` experts are here:
+    twice the held experts' even share, in whole row tiles of 128; all of them
+    where that is all or most of them anyway (every expert held, a decode
+    step's few pairs). A prefill of 1,024 positions that chose 8 of 192 with 8
+    held has 8,192 pairs, about 341 of them held: past those the kernel only
+    writes zeros, three times a layer, and every pass between the matmuls runs
+    over the rows of no held expert."""
+    head = -(-2 * pairs * held // (routed * 128)) * 128
+    return pairs if 2 * head > pairs else head
 
 
 def dropless_moe(
@@ -188,7 +219,10 @@ def dropless_moe(
     first: int = 0,
     layer=None,
     norm_topk: bool = True,
+    norm_eps: float = 1e-6,
     scale: float = 1.0,
+    n_group: int = 1,
+    topk_group: int = 1,
     compute_dtype=jnp.bfloat16,
 ) -> tuple[jax.Array, jax.Array]:
     """SwiGLU experts without capacity and without drops: every row reaches the
@@ -197,8 +231,10 @@ def dropless_moe(
     ``x`` (N, D); ``router_kernel`` (D, E) and ``expert_bias`` (E,) span ALL
     ``E`` experts; ``w1``/``w3`` (G, D, I) and ``w2`` (G, I, D) are the ``G``
     experts held here, experts ``first .. first + G - 1`` (default: all of
-    them). Routing runs over all ``E``; the result is the part the held experts
-    give, so the shares of a layer split over chips add up to the whole layer.
+    them). Routing runs over all ``E`` (``n_group``, ``topk_group``,
+    ``norm_eps``: :func:`route_sigmoid_topk`'s); the result is the part the held
+    experts give, so the shares of a layer split over chips add up to the whole
+    layer.
     With ``layer`` (an int or a traced scalar) the weights are every layer's,
     stacked on a leading axis, ``(L, G, ...)``: they are handed to the grouped
     matmul whole, as ``L * G`` groups of which only this layer's hold rows,
@@ -210,6 +246,10 @@ def dropless_moe(
     one ``moe_gmm`` Pallas kernel each (interpreted off the TPU), its tiles
     chosen from the rows and widths at hand, the groups' visits computed once
     and shared by the three; differentiated, they are ``jax.lax.ragged_dot``.
+    Where a share of the experts is held and the pairs are many, the matmuls
+    and the passes between them take the head of the sorted pairs alone
+    (:func:`_head_pairs`: twice the held experts' even share), and all of them
+    in the batch that holds more than that; the result is the same either way.
     A row's result is
     computed from that row alone (its dot products, then its k parts summed
     in the order of its own choice): it does not depend on what else is in
@@ -225,7 +265,8 @@ def dropless_moe(
     g = w1.shape[1] if stacked else w1.shape[0]
     k = num_selected
     experts, weights = route_sigmoid_topk(
-        x, router_kernel, expert_bias, k, norm_topk=norm_topk, scale=scale
+        x, router_kernel, expert_bias, k, norm_topk=norm_topk, norm_eps=norm_eps,
+        scale=scale, n_group=n_group, topk_group=topk_group,
     )
     flat = experts.reshape(n * k)
     rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
@@ -236,16 +277,37 @@ def dropless_moe(
     if stacked:
         n_layers = w1.shape[0]
         w1, w3, w2 = (w.reshape(n_layers * g, *w.shape[2:]) for w in (w1, w3, w2))
-    # which expert multiplies which rows: made once, the three matmuls share it
-    visits = group_visits(sizes, n * k, layer * g if stacked else 0)
-    xs = x.astype(compute_dtype)[order // k]  # (N * k, D), sorted by expert
+    xc = x.astype(compute_dtype)
 
-    def grouped(lhs, rhs):
-        return grouped_matmul(lhs, rhs.astype(compute_dtype), visits)
+    def parts_of(pairs: int):
+        """The held experts' products of the first ``pairs`` sorted pairs, every
+        held one among them, back in the order of (row, choice)."""
+        # which expert multiplies which rows: made once, the three matmuls share it
+        visits = group_visits(sizes, pairs, layer * g if stacked else 0)
+        head = order if pairs == n * k else order[:pairs]
+        xs = xc[head // k]  # (pairs, D), sorted by expert
 
-    hidden = (jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)).astype(compute_dtype)
-    # (N * k, D) float32; rows past the last group (experts held elsewhere) are zero
-    parts = grouped(hidden, w2)
-    parts = parts[jnp.argsort(order)].reshape(n, k, d)  # back to (row, choice)
+        def grouped(lhs, rhs):
+            return grouped_matmul(lhs, rhs.astype(compute_dtype), visits)
+
+        hidden = (jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)).astype(compute_dtype)
+        # (pairs, D) float32; rows past the last group (experts held elsewhere) are zero
+        parts = grouped(hidden, w2)
+        back = jnp.argsort(order)
+        # a head is taken only where its last row is no group's, so zero: every
+        # pair behind the head reads that row
+        return parts[back if pairs == n * k else jnp.minimum(back, pairs - 1)]
+
+    # With a share of the experts held, most sorted pairs are no group's: the
+    # matmuls take the head of them that holds every held pair at any routing
+    # near even, and all of them only when a batch crowds onto the held experts
+    head_pairs = _head_pairs(n * k, g, e)
+    if head_pairs == n * k:
+        parts = parts_of(n * k)
+    else:
+        parts = jax.lax.cond(
+            jnp.sum(sizes) < head_pairs, lambda: parts_of(head_pairs), lambda: parts_of(n * k)
+        )
+    parts = parts.reshape(n, k, d)  # back to (row, choice)
     out = jnp.sum(parts * weights[:, :, None], axis=1)
     return out.astype(x.dtype), rows

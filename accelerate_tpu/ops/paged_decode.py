@@ -155,13 +155,19 @@ def decode_walked_positions(live: int, block_size: int) -> int:
     return max(-(-live // chunk), 1) * chunk
 
 
-def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
+def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, *rest,
                    block_size, chunk_blocks, blocks_per_row, n_rep, d, scale,
-                   softcap, quantized):
-    if quantized:
-        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, acc_ref, base_ref = rest
+                   softcap, quantized, value_dim):
+    # a latent pool (``value_dim``) has no pool of values and no buffer for
+    # them: a row's values are its own first ``value_dim`` columns
+    latent = value_dim is not None
+    if latent:
+        v_hbm = vbuf = None
+        o_ref, kbuf, sem, acc_ref, base_ref = rest
+    elif quantized:
+        v_hbm, ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, acc_ref, base_ref = rest
     else:
-        o_ref, kbuf, vbuf, sem, acc_ref, base_ref = rest
+        v_hbm, o_ref, kbuf, vbuf, sem, acc_ref, base_ref = rest
     bs, C = block_size, chunk_blocks
     T = C * bs
     rows, lanes = acc_ref.shape
@@ -185,6 +191,7 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             blk = tables_ref[slot_b, jnp.minimum(j, blocks_per_row - 1)]
             fn(
                 pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[buf, jj], sem.at[0, buf]),
+                None if latent else
                 pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[buf, jj], sem.at[1, buf]),
                 jj, live,
             )
@@ -198,7 +205,8 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             @pl.when(live)
             def _():
                 copy_k.start()
-                copy_v.start()
+                if copy_v is not None:
+                    copy_v.start()
         each_live_block(slot_b, chunk, buf, fn)
 
     def wait(slot_b, chunk, buf):
@@ -206,7 +214,8 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             @pl.when(live)
             def _():
                 copy_k.wait()  # graft: wait-ok — a DMA semaphore in the kernel, not a thread
-                copy_v.wait()  # graft: wait-ok
+                if copy_v is not None:
+                    copy_v.wait()  # graft: wait-ok
 
             # A block past the slot's position is never fetched: its rows of
             # the buffer hold whatever an earlier chunk left there. Its
@@ -214,7 +223,8 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             # stale NaN is a NaN in P x V: its value rows read as zeros.
             @pl.when(jnp.logical_not(live))
             def _():
-                vbuf[buf, jj] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+                held = kbuf if latent else vbuf  # a latent row is its own value
+                held[buf, jj] = jnp.zeros(held.shape[2:], held.dtype)
         each_live_block(slot_b, chunk, buf, fn)
 
     @pl.when(b == 0)
@@ -234,7 +244,9 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
             if quantized:
                 blocks = [x.astype(jnp.float32).astype(q_ref.dtype) for x in blocks]
             return blocks[0] if C == 1 else jnp.concatenate(blocks, axis=0)
-        return tile(kbuf), tile(vbuf)
+        k = tile(kbuf)
+        # read once: the values of a latent chunk are columns of the tile at hand
+        return k, (k[:, :value_dim] if latent else tile(vbuf))
 
     def body(i, carry):
         m_prev, l_prev = carry
@@ -274,6 +286,10 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
     m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
     _, l = lax.fori_loop(0, n, body, (m0, jnp.zeros((rows, 1), jnp.float32)))
     base_ref[0] = base + n
+
+    if latent:  # one shared head: every row's lanes are its own result
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return
 
     # Row r of the accumulator is right on its own kv head's lanes only.
     # Heads narrower than a 128-lane tile are picked a whole tile at a time
@@ -351,7 +367,7 @@ def _gathered_scale_rows(scale, block_tables, n_chunks, chunk):
 def paged_flash_decode(
     q: jax.Array,
     k_pool: jax.Array,
-    v_pool: jax.Array,
+    v_pool: Optional[jax.Array],
     block_tables: jax.Array,
     pos: jax.Array,
     *,
@@ -360,6 +376,7 @@ def paged_flash_decode(
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
     layer: Optional[jax.Array] = None,
+    value_dim: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Single-token paged decode attention as a Pallas flash kernel.
@@ -387,13 +404,32 @@ def paged_flash_decode(
     ``softcap`` is the static Gemma-2 tanh cap. Sliding-window masking is
     NOT supported — callers with a sliding-window config must use the
     reference op (the engine enforces this fallback).
+
+    A latent pool: ``value_dim`` with ``v_pool=None``. The pool holds one row
+    of ``d`` values a position under every query head (one shared "kv head"),
+    a row's first ``value_dim`` columns are also its value, and each live row
+    is copied out of HBM once and serves both matmuls. Returns (B, 1, h,
+    ``value_dim``).
     """
     b, sq, h, d = q.shape
     if sq != 1:
         raise ValueError(f"paged_flash_decode takes one query token, got {sq}")
-    (k_pool, v_pool), (k_scale, v_scale), block_tables, h_kv, bs = _flat_pools(
-        (k_pool, v_pool), (k_scale, v_scale), block_tables, layer, h, d
+    latent = value_dim is not None
+    if latent != (v_pool is None) or (latent and k_scale is not None):
+        raise ValueError(
+            "a latent pool (value_dim) is one unquantized pool whose rows hold their "
+            "own values: pass v_pool=None and no scales with it, and only with it"
+        )
+    pools = (k_pool,) if latent else (k_pool, v_pool)
+    pools, (k_scale, v_scale), block_tables, h_kv, bs = _flat_pools(
+        pools, (k_scale, v_scale), block_tables, layer, h, d
     )
+    if latent and (h_kv != 1 or not 0 < value_dim <= d):
+        raise ValueError(
+            f"a latent pool has one row of {d} a position with its value in the "
+            f"first {value_dim} columns, got rows of {h_kv * d}"
+        )
+    k_pool = pools[0]
     n_rep = h // h_kv
     bpr = block_tables.shape[1]
     lanes = h_kv * d
@@ -419,8 +455,11 @@ def paged_flash_decode(
 
     q_spec = pl.BlockSpec((1, rows, lanes), lambda bb, t, p: (bb, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [q_spec, pool_spec, pool_spec]
-    args = [qx, k_pool, v_pool]
+    in_specs = [q_spec] + [pool_spec] * len(pools)
+    args = [qx, *pools]
+    d_out = value_dim if latent else d  # a result row's width
+    value_buffers = [] if latent else [
+        pltpu.VMEM((2, chunk_blocks, bs, lanes), pools[1].dtype)]
     if quantized:
         s_spec = pl.BlockSpec((1, n_chunks, 1, chunk), lambda bb, t, p: (bb, 0, 0, 0))
         in_specs += [s_spec, s_spec]
@@ -433,12 +472,12 @@ def paged_flash_decode(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, d), lambda bb, t, p: (bb, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, d_out), lambda bb, t, p: (bb, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, chunk_blocks, bs, lanes), k_pool.dtype),
-            pltpu.VMEM((2, chunk_blocks, bs, lanes), v_pool.dtype),
+            *value_buffers,
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((rows, lanes), jnp.float32),
+            pltpu.VMEM((rows, d_out if latent else lanes), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
@@ -446,16 +485,16 @@ def paged_flash_decode(
         functools.partial(
             _decode_kernel, block_size=bs, chunk_blocks=chunk_blocks,
             blocks_per_row=bpr, n_rep=n_rep, d=d, scale=scale, softcap=softcap,
-            quantized=quantized,
+            quantized=quantized, value_dim=value_dim,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d_out), q.dtype),
         # the walk carries its buffers from one slot to the next
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
     )(block_tables, pos.astype(jnp.int32), *args)
-    return out[:, :h].reshape(b, 1, h, d)
+    return out[:, :h].reshape(b, 1, h, d_out)
 
 
 # ------------------------------------------------------------ verify kernel

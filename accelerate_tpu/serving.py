@@ -519,6 +519,8 @@ class InferenceServer:
             self._engine.validate_request(
                 ids.shape[0], max_new_tokens or self.config.default_max_new_tokens
             )
+            if hasattr(self._engine, "validate_prompt"):  # injected engines need not
+                self._engine.validate_prompt(ids)
             if self.config.kv_prefetch and hasattr(self._engine, "prefetch"):
                 # admission-time async prefetch: start the host-tier ->
                 # device copy of any spilled prefix NOW, on the submitter's

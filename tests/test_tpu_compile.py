@@ -187,6 +187,35 @@ def test_paged_flash_decode_compiles_on_the_whole_pool(compile_for_chip, case):
     assert sizes and sum(sizes) < 16 << 20, sizes
 
 
+def test_paged_flash_decode_compiles_on_a_latent_pool(compile_for_chip):
+    """The A.X-K1 cell's call (PR 35): 64 slots of 4,096 positions under 64 query
+    heads, one row of 640 a position (576 and zeros: whole tiles of 128 lanes)
+    whose first 512 columns are its value, seven layers' rows in one pool of 2.3 GB
+    that nothing may copy, and no pool of values at all. At 576 the chip lays the
+    rows out 640 wide all the same and the kernel's block copy is refused for a
+    slice that is not whole tiles: the row is stored at the width it occupies."""
+    slots, heads, row, value, layers, blocks = 64, 64, 640, 512, 7, 16385
+
+    def decode(q, rows, tables, pos, layer):
+        return paged_flash_decode(q, rows, None, tables, pos, layer=layer, value_dim=value,
+                                  scale=0.13, interpret=False)
+
+    operands = (((slots, 1, heads, row), jnp.bfloat16),
+                ((layers, blocks, BLOCK_SIZE, row), jnp.bfloat16),
+                ((slots, 256), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32))
+    text = compile_for_chip(decode, *operands)
+    flat = f"bf16[{layers * blocks},{BLOCK_SIZE},{row}]"
+    made = [line for line in text.splitlines()
+            if f" = {flat}" in line and " bitcast(" not in line and " parameter(" not in line]
+    assert not made, made[:2]
+    calls = re.findall(r"^\s*%paged_decode[.\d]* = .*$", text, flags=re.M)
+    assert len(calls) == 1 and f"bf16[{slots},{heads},{value}]" in calls[0], calls
+    with pytest.raises(Exception, match="aligned to tiling"):
+        narrow = list(operands)
+        narrow[0], narrow[1] = ((slots, 1, heads, 576), jnp.bfloat16), ((layers, blocks, BLOCK_SIZE, 576), jnp.bfloat16)
+        compile_for_chip(decode, *narrow)
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16_pool", "int8_pool"])
 @pytest.mark.parametrize("width", list(WIDTHS))
 def test_paged_flash_verify_compiles(compile_for_chip, width, quantized):
