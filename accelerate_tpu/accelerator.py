@@ -92,6 +92,56 @@ def check_wide_pp_limit(mesh_size: int, pp_size: int) -> None:
         )
 
 
+# What the plan of a train step leaves free beyond the bytes it counts, as a
+# share of the device's limit. It covers what the compiled step's table leaves
+# out (on a TPU v5e the program's own code, 24-31 MB, and the batches in
+# flight) and what steps dispatched ahead have been seen to add (1.33e9 B,
+# 7.9% of the limit, at PR 35's step), and it keeps the plan under the fullest
+# the chip is known to run a step at (90.7%): PERF.md section 6, PR 36.
+_PLAN_MARGIN = 1 / 8
+
+
+def _device_memory():
+    """``(bytes_limit, bytes_in_use)`` of this process's first device, or
+    ``None`` where the backend keeps no such count (the CPU). The one place a
+    train step's plan reads the device, and what a test replaces."""
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]), int(stats.get("bytes_in_use", 0))
+
+
+def _plan_remat(make_jit, args, memory):
+    """Walk ``REMAT_LADDER`` from its fastest rung down and keep the first
+    whose compiled step fits the device: ``(jitted, facts)``.
+
+    ``make_jit(rung)`` is the step traced with ``"auto"`` meaning that rung,
+    lowered and compiled here for ``args``; the call that follows finds that
+    executable and compiles nothing. It fits if its temporaries, beside its arguments or
+    beside everything the process holds now (the arguments are among it),
+    whichever is more, leave ``_PLAN_MARGIN`` of the limit free. A compile that
+    the device's compiler refuses for memory does not fit. The last rung is
+    kept whether it fits or not, as it was before there was a plan."""
+    from .analysis.lowering import memory_table
+    from .models.llama import REMAT_LADDER
+
+    limit, in_use = memory
+    for tried, rung in enumerate(REMAT_LADDER, start=1):
+        jitted = make_jit(rung)
+        try:
+            table = memory_table(jitted.lower(*args).compile())
+        except jax.errors.JaxRuntimeError as err:
+            if rung == REMAT_LADDER[-1] or "RESOURCE_EXHAUSTED" not in str(err):
+                raise
+            continue
+        needed = table["temp_size_in_bytes"] + max(table["argument_size_in_bytes"], in_use)
+        if needed <= limit * (1 - _PLAN_MARGIN) or rung == REMAT_LADDER[-1]:
+            return jitted, {
+                "remat": rung, "rungs_tried": tried, "hbm_live": table["hbm_live"],
+                "bytes_limit": limit, "bytes_in_use": in_use,
+            }
+
+
 def _is_optax_tx(obj) -> bool:
     return (
         hasattr(obj, "init")
@@ -568,7 +618,8 @@ class Accelerator:
                 rules = list(self.fsdp_plugin.sharding_rules) + rules
             if (
                 self.fsdp_plugin.activation_checkpointing
-                and getattr(getattr(model, "config", None), "remat_policy", None) == "nothing"
+                and getattr(getattr(model, "config", None), "remat_policy", None)
+                in ("auto", "nothing")
             ):
                 model.config.remat_policy = "minimal"
         fsdp_axes = pcfg.fsdp_dim_names
@@ -1006,6 +1057,17 @@ class Accelerator:
         ``gradient_accumulation_steps`` calls — inside the compiled program,
         no recompilation; reference GradientState semantics).
 
+        The step plans its own memory. At one micro-batch an update it carries
+        no accumulator (the gradient is the whole sum). Where the model's
+        configuration leaves ``remat_policy`` at ``"auto"`` and the device says
+        what it holds, the first call compiles the step rung by rung
+        (``models/llama.py::REMAT_LADDER``, fastest first) and keeps the first
+        whose bytes fit (``_plan_remat``); a policy that was set, a model
+        without one and a backend without a memory limit get one compile, as
+        ever. ``step.plan`` and the one ``train.plan`` span hold what was
+        kept: ``remat``, ``rungs_tried``, ``hbm_live``, ``bytes_limit``,
+        ``bytes_in_use``, ``accumulator_bytes``.
+
         ``multi_step=True``: the returned callable takes batches with an extra
         leading steps dim (N, ...) and runs all N steps in ONE program via
         ``lax.scan`` — amortizes dispatch overhead; returns the (N,) losses.
@@ -1268,14 +1330,10 @@ class Accelerator:
                     lambda g: g.astype(grad_comm_dtype), grads
                 )
             grads = _pin_grads(grads)
-            with jax.named_scope("train.accumulate"):
-                accum = jax.tree_util.tree_map(jnp.add, accum, grads) if k > 1 else grads
-            new_count = count + 1
-            do_update = (new_count % k) == 0 if k > 1 else jnp.bool_(True)
 
-            def apply_branch(operand):
-                params, opt_state, accum, scaler_state = operand
-                g = accum
+            def apply_update(params, opt_state, g, scaler_state):
+                # ``g``: the gradients of the update, summed over its ``k``
+                # micro-batches
                 if grad_comm_dtype is not None:
                     g = jax.tree_util.tree_map(
                         lambda x, p: x.astype(p.dtype), g, params
@@ -1319,48 +1377,61 @@ class Accelerator:
                     with jax.named_scope("train.optimizer"):
                         updates, opt_state = tx.update(g, opt_state, params)
                         params = optax.apply_updates(params, updates)
-                with jax.named_scope("train.accumulate"):
-                    accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
-                return params, opt_state, accum, scaler_state
+                return params, opt_state, scaler_state
 
             if k > 1:
+                def update_and_zero(operand):
+                    params, opt_state, accum, scaler_state = operand
+                    params, opt_state, scaler_state = apply_update(*operand)
+                    with jax.named_scope("train.accumulate"):
+                        accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
+                    return params, opt_state, accum, scaler_state
+
+                with jax.named_scope("train.accumulate"):
+                    accum = jax.tree_util.tree_map(jnp.add, accum, grads)
+                count = (count + 1) % k
                 params, opt_state, accum, scaler_state = jax.lax.cond(
-                    do_update, apply_branch, lambda op: op, (params, opt_state, accum, scaler_state)
+                    count == 0, update_and_zero, lambda op: op,
+                    (params, opt_state, accum, scaler_state),
                 )
+                # pin the accum OUTPUT to the grad shardings: the zeroed accum
+                # is a fresh broadcast whose sharding the partitioner picks
+                # freely; left unpinned it can come back replicated, so call
+                # N+1's input sharding differs from call N's and the whole
+                # fused program compiles a second signature
+                # (test_train_step_compiles_once_sharded)
+                accum = _pin_grads(accum)
             else:
-                params, opt_state, accum, scaler_state = apply_branch(
-                    (params, opt_state, accum, scaler_state)
+                # one micro-batch an update: its gradient is the whole sum;
+                # ``accum`` stays the empty tree it came in as and ``count`` 0
+                params, opt_state, scaler_state = apply_update(
+                    params, opt_state, grads, scaler_state
                 )
-            # pin the accum OUTPUT to the grad shardings: the zeroed accum is
-            # a fresh broadcast whose sharding the partitioner picks freely;
-            # left unpinned it can come back replicated, so call N+1's input
-            # sharding differs from call N's and the whole fused program
-            # compiles a second signature (test_train_step_compiles_once_sharded)
-            accum = _pin_grads(accum)
-            return (params, opt_state, accum, new_count % (k if k > 1 else 1),
-                    scaler_state, psgd_state, loss)
+            return params, opt_state, accum, count, scaler_state, psgd_state, loss
 
         if use_flat:
             from .utils.flatbuf import build_pack_spec, pack_tree, unpack_tree
 
             param_spec = build_pack_spec(model.params)
             opt_spec = build_pack_spec(optimizer.opt_state)
+            # no accumulator at one micro-batch an update: ``pa`` is the
+            # empty tree and passes through as it is
             accum_spec = build_pack_spec(
                 model.params,
                 dtype_of=(lambda p: grad_comm_dtype) if grad_comm_dtype is not None else None,
-            )
+            ) if k > 1 else None
 
             def core(pp, po, pa, count, scaler_state, psgd_state, *batch):
                 params = unpack_tree(param_spec, pp)
                 opt_state = unpack_tree(opt_spec, po)
-                accum = unpack_tree(accum_spec, pa)
+                accum = unpack_tree(accum_spec, pa) if k > 1 else pa
                 params, opt_state, accum, count, scaler_state, psgd_state, loss = fused(
                     params, opt_state, accum, count, scaler_state, psgd_state, *batch
                 )
                 return (
                     pack_tree(param_spec, params),
                     pack_tree(opt_spec, opt_state),
-                    pack_tree(accum_spec, accum),
+                    pack_tree(accum_spec, accum) if k > 1 else accum,
                     count,
                     scaler_state,
                     psgd_state,
@@ -1395,12 +1466,28 @@ class Accelerator:
         # arg 5 is the powersgd state (error feedback is param-sized); an
         # empty dict when the hook is off, so donating it is always safe
         donate_args = (0, 1, 2, 5) if donate else ()
-        compiled = jax.jit(target, donate_argnums=donate_args)
+
+        from .models.llama import REMAT_LADDER, auto_remat
+
+        def make_jit(rung):
+            """The step's program with ``remat_policy="auto"`` meaning
+            ``rung``. The rung is set inside the traced function, a new one a
+            rung: jit caches a trace by its function and the arguments' types,
+            and sees no configuration that the function reads."""
+            @functools.wraps(target)  # a profile names the program by it
+            def program(*args):
+                with auto_remat(rung):
+                    return target(*args)
+
+            return jax.jit(program, donate_argnums=donate_args)
 
         accum_dtype_of = (
             (lambda p: grad_comm_dtype) if grad_comm_dtype is not None else (lambda p: p.dtype)
         )
-        if use_flat:
+        if k == 1:
+            # one micro-batch an update: the step carries no accumulator
+            accum_init = {}
+        elif use_flat:
             accum_init = tuple(
                 jnp.zeros((size,), dtype=dt)
                 for size, dt in zip(accum_spec.buffer_sizes, accum_spec.buffer_dtypes)
@@ -1464,15 +1551,44 @@ class Accelerator:
                 # cache AND waste memory, so fall back to jit's own
                 # placement when no param shardings exist to mirror
                 accum_sh = grad_shardings if grad_shardings is not None else model.shardings
-                if use_flat or self.mesh.size == 1:
-                    state["accum"] = jax.device_put(state["accum"], replicated)
-                elif accum_sh is not None:
+                if k == 1:
+                    accum_sh = None  # the empty tree: nothing to place
+                elif use_flat or self.mesh.size == 1:
+                    accum_sh = replicated
+                if accum_sh is not None:
                     state["accum"] = jax.device_put(state["accum"], accum_sh)
                 # psgd state is committed by init_powersgd_state (mesh-aware)
             else:
                 state = jax.device_put(state)
 
+        # What the step saves for its backward pass. A policy that the model's
+        # configuration states stands; ``"auto"`` is the ladder's last rung
+        # until the first call has the batch to compile with and, where the
+        # device says what it holds, walks the ladder (``_plan_remat``).
+        policy = getattr(getattr(model, "config", None), "remat_policy", None)
+        plan = {
+            "remat": REMAT_LADDER[-1] if policy == "auto" else policy,
+            "rungs_tried": 0, "hbm_live": None, "bytes_limit": None, "bytes_in_use": None,
+            "accumulator_bytes": sum(
+                leaf.size * jnp.dtype(leaf.dtype).itemsize
+                for leaf in jax.tree_util.tree_leaves(accum_init)
+            ),
+        }
+
+        planned = False
+
+        def make_plan(args):
+            """The first call's work: one ``train.plan`` span a step built."""
+            with tracing.span("train.plan", accumulator_bytes=plan["accumulator_bytes"]) as sp:
+                memory = _device_memory() if policy == "auto" else None
+                if memory is not None:
+                    step.jitted, facts = _plan_remat(make_jit, args, memory)
+                    plan.update(facts)
+                for key, value in plan.items():
+                    sp.set(key, value)
+
         def step(*batch):
+            nonlocal planned
             if use_flat:
                 pp = model._packed_for(param_spec)
                 if pp is None:
@@ -1502,19 +1618,19 @@ class Accelerator:
                 in_params, in_opt = pp, po
             else:
                 in_params, in_opt = model.params, optimizer.opt_state
+            args = (in_params, in_opt, state["accum"], state["count"],
+                    state["scaler"], state["psgd"], *batch)
+            if not planned:
+                make_plan(args)
+                planned = True
             # host-side dispatch span only (the fused program runs async on
             # device), one every step: what the host spends to send a step
             with tracing.span(
-                "train.step", step=optimizer._step_count, flat=use_flat
+                "train.step", step=optimizer._step_count, flat=use_flat,
+                remat=plan["remat"],
             ):
-                params, opt_state, accum, count, scaler_state, psgd_state, loss = compiled(
-                    in_params,
-                    in_opt,
-                    state["accum"],
-                    state["count"],
-                    state["scaler"],
-                    state["psgd"],
-                    *batch,
+                params, opt_state, accum, count, scaler_state, psgd_state, loss = (
+                    step.jitted(*args)
                 )
             if use_flat:
                 model._set_packed_params(params, param_spec, _unpack_params)
@@ -1552,12 +1668,13 @@ class Accelerator:
                 )
             else:
                 in_params, in_opt = model.params, optimizer.opt_state
-            return compiled.lower(
+            return step.jitted.lower(
                 in_params, in_opt, state["accum"], state["count"],
                 state["scaler"], state["psgd"], *batch,
             )
 
-        step.jitted = compiled
+        step.jitted = make_jit(REMAT_LADDER[-1])
+        step.plan = plan
         step.lower = lower
         step.abstract = abstract_mode
         return step

@@ -79,8 +79,7 @@ class ProgramRecord:
     donated: Set[int]    # flat input indices that MUST carry an alias
     jaxpr: Any = None    # ClosedJaxpr when tracing exposed one (engine path)
     # flat indices donated but legitimately droppable (jax strips donation
-    # for inputs the program never reads — e.g. the accum tree when grad
-    # accumulation is off). Allowed, not required, to alias.
+    # for inputs the program never reads). Allowed, not required, to alias.
     donated_optional: Set[int] = dataclasses.field(default_factory=set)
     # family member tag ("chunk"/"restore" for the chunked-prefill members
     # of prefill_insert): G004 counts families by `name`; the perf/HBM
@@ -214,10 +213,9 @@ def build_train_step_program(return_state: bool = False):
 
     Donation: train_step donates (params, opt_state, accum, psgd_state).
     Flat input order is params, opt_state, accum, count, scaler, psgd,
-    batch; accum is param-shaped and psgd is EMPTY on this config, so the
-    donated flat range is the contiguous [0, 2P + O). Params and opt_state
-    must alias; the accum tree is only read when gradient accumulation is
-    on, so jax strips its donation here — it may alias, never must.
+    batch; at one micro-batch an update (this config) the step carries no
+    accumulator and psgd is EMPTY, so the donated flat range is the
+    contiguous [0, P + O), and every index of it must alias.
 
     With ``return_state=True`` returns ``(record, state)`` where ``state``
     carries the abstract ``params`` and ``opt_state`` trees — graftcheck
@@ -248,7 +246,6 @@ def build_train_step_program(return_state: bool = False):
         record = ProgramRecord(
             group="train_step", name="fused_train_step", lowered=lowered,
             donated=set(range(p + o)),
-            donated_optional=set(range(p + o, 2 * p + o)),
         )
         if return_state:
             return record, {"params": model.params, "opt_state": opt.opt_state}

@@ -219,7 +219,6 @@ def build_train_variant(tag: str, cfg_kwargs: dict) -> ShardedProgram:
             state_leaves=(_leaves_of(model.params, "param")
                           + _leaves_of(opt.opt_state, "moment")),
             donated=set(range(p + o)),
-            donated_optional=set(range(p + o, 2 * p + o)),
             out_leaves=_out_leaves(lowered.out_info),
         )
     finally:

@@ -29,7 +29,7 @@ from ..model import Model
 from ..ops.attention import dispatch_attention
 from ..parallel.sharding import constrain_activation, replicate_over_fsdp
 from .bert import _apply_dense, _dense, layer_norm
-from .llama import _ce_from_hidden, _remat_policy, llama_ce_denominator, llama_loss
+from .llama import _ce_from_hidden, checkpoint_layer, llama_ce_denominator, llama_loss
 
 __all__ = [
     "GPT2Config",
@@ -45,6 +45,15 @@ __all__ = [
 
 @dataclasses.dataclass
 class GPT2Config:
+    """GPT-2 (learned positions, LayerNorm, fused QKV, GELU MLP, tied head).
+
+    ``remat_policy`` is ``LlamaConfig``'s: ``"full"`` (no checkpoint), ``"dots"``
+    / ``"dots_no_batch"`` (matmul outputs saved), ``"minimal"`` (the two block
+    outputs a layer), ``"nothing"`` (a layer's input only), or ``"auto"``, the
+    default: ``Accelerator.train_step`` keeps the first of dots, full, minimal,
+    nothing whose compiled step fits the device; elsewhere it is ``"nothing"``.
+    """
+
     vocab_size: int = 50257
     hidden_size: int = 768
     num_hidden_layers: int = 12
@@ -53,7 +62,7 @@ class GPT2Config:
     layer_norm_eps: float = 1e-5
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
-    remat_policy: str = "nothing"  # "nothing" | "dots" | "minimal" | "full"
+    remat_policy: str = "auto"  # see the class docstring
     attention_impl: str = "blockwise"  # "xla" | "blockwise" | "flash"
     attention_kv_block: int = 512
     attention_block_q: int = 2048
@@ -230,12 +239,10 @@ def gpt2_apply(
         pos = jnp.arange(s) + position_offset
         x = constrain_activation(x + wpe[pos][None])
 
-    layer_fn = functools.partial(
+    layer_fn = checkpoint_layer(config, functools.partial(
         _gpt2_layer, config, position_offset=position_offset,
         attention_fn=attention_fn, segment_ids=segment_ids,
-    )
-    if config.remat_policy != "full":
-        layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(config.remat_policy))
+    ))
 
     if layer_stack_fn is not None:
         x, _aux = layer_stack_fn(params["layers"], x, lambda lp, x: (layer_fn(lp, x), jnp.float32(0.0)))
@@ -303,11 +310,9 @@ def gpt2_pipeline_parts(config: GPT2Config, attention_fn=None):
     llama_pipeline_parts; the CE tail is the shared ``_ce_from_hidden`` so
     the pipelined loss provably matches :func:`gpt2_loss`."""
     cdt = config.compute_dtype
-    layer_fn = functools.partial(
+    layer_fn = checkpoint_layer(config, functools.partial(
         _gpt2_layer, config, position_offset=0, attention_fn=attention_fn
-    )
-    if config.remat_policy != "full":
-        layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(config.remat_policy))
+    ))
 
     def embed_fn(params, mb):
         ids = mb["input_ids"]

@@ -7,7 +7,8 @@ Built for XLA, not ported:
 * **scan over layers** — one compiled layer body, stacked params (L, ...):
   compile time O(1) in depth, and the pattern XLA pipelines best;
 * **remat** — ``jax.checkpoint`` on the layer body with a selectable policy
-  ("nothing", "dots" saves matmul outputs, "full" saves everything);
+  (``LlamaConfig.remat_policy``; :func:`checkpoint_layer` is the one place a
+  layer loop reads it);
 * bf16 compute / fp32 master params; RMSNorm + rotary + SwiGLU + GQA;
 * attention implementation is injectable: "xla" (materialized), "blockwise"
   (online softmax), "flash" (Pallas kernel), or "ring"/"ulysses" wired by the
@@ -20,6 +21,8 @@ so the FSDP heuristic shards hidden dims, never the scan dim.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import functools
 import math
@@ -45,6 +48,24 @@ __all__ = ["LlamaConfig", "init_llama_params", "llama_apply", "create_llama", "l
 
 @dataclasses.dataclass
 class LlamaConfig:
+    """A Llama-family decoder (Llama, Mistral, Qwen2, Gemma, Mixtral).
+
+    ``remat_policy`` says what a layer saves for the backward pass
+    (:func:`checkpoint_layer`), from most memory and no recompute to least
+    memory and a whole second forward:
+
+    * ``"full"`` — no checkpoint: every intermediate is saved;
+    * ``"dots"`` — matmul outputs are saved, the flash kernel's among them,
+      and the rest is recomputed (``"dots_no_batch"``: only plain matmuls
+      without batch dimensions);
+    * ``"minimal"`` — the two block outputs a layer are saved;
+    * ``"nothing"`` — only a layer's input is saved;
+    * ``"auto"`` (the default) — ``Accelerator.train_step`` keeps the first
+      of dots, full, minimal, nothing (``REMAT_LADDER``, fastest first) whose
+      compiled step fits the device's memory; anywhere else, and where the
+      backend reports no memory limit, it is ``"nothing"``.
+    """
+
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -86,7 +107,7 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
-    remat_policy: str = "nothing"  # "nothing" | "dots" | "full"
+    remat_policy: str = "auto"  # see the class docstring
     attention_impl: str = "blockwise"  # "xla" | "blockwise" | "flash"
     attention_kv_block: int = 512
     # flash q-tile rows; v5e-measured: tall q tiles amortize the per-grid-step
@@ -434,18 +455,64 @@ def apply_rope(x: jax.Array, position_offset: int, theta: float,
     return out.astype(x.dtype)
 
 
-def _remat_policy(name: str):
-    if name == "dots":
-        return jax.checkpoint_policies.checkpoint_dots
-    if name == "dots_no_batch":
-        return jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-    if name == "minimal":
-        # save only the two per-layer block outputs (tagged in _layer):
-        # ~2 activations/layer instead of 7 under "dots", at the cost of
-        # recomputing qkv/gate/up projections in backward (~40% of fwd FLOPs
-        # vs 100% for "nothing")
-        return jax.checkpoint_policies.save_only_these_names("attn_block_out", "mlp_block_out")
-    return None
+# ``remat_policy`` by name -> the ``policy`` of ``jax.checkpoint`` ("full" is
+# no checkpoint at all and has no entry)
+_REMAT_POLICIES = {
+    "nothing": None,
+    # save only the two per-layer block outputs (tagged in _layer):
+    # ~2 activations/layer instead of 7 under "dots", at the cost of
+    # recomputing qkv/gate/up projections in backward (~40% of fwd FLOPs
+    # vs 100% for "nothing")
+    "minimal": jax.checkpoint_policies.save_only_these_names(
+        "attn_block_out", "mlp_block_out"
+    ),
+    # matmul outputs, and what the flash kernel hands its backward kernels
+    # (ops/flash_attention.py names them): the kernel is attention's two
+    # matmuls, and without its results a layer's backward runs it again
+    "dots": jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.checkpoint_dots,
+        jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse"),
+    ),
+    "dots_no_batch": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+}
+
+# What ``"auto"`` may become, fastest first: ``train_step`` keeps the first
+# whose compiled step fits. The order is a step's time on the chip, which was
+# also the order of the bytes a rung holds (TPU v5e, Mistral-7B widths, 2,048
+# tokens: dots 88.0 ms, full 92.1, minimal 95.3, nothing 96.6; PERF.md
+# section 6, PR 36): saving every intermediate writes and reads back more
+# than recomputing the cheap ones between the matmuls costs.
+REMAT_LADDER = ("dots", "full", "minimal", "nothing")
+
+_auto_remat = contextvars.ContextVar("accelerate_tpu_auto_remat", default="nothing")
+
+
+@contextlib.contextmanager
+def auto_remat(rung: str):
+    """What ``remat_policy="auto"`` means to a program traced inside: the
+    train step's plan sets its rung here, around the trace."""
+    token = _auto_remat.set(rung)
+    try:
+        yield
+    finally:
+        _auto_remat.reset(token)
+
+
+def checkpoint_layer(config, layer_fn):
+    """``layer_fn`` under the configuration's ``remat_policy``: the one place a
+    layer loop (llama's and GPT-2's, scanned, paired or a pipeline's stage)
+    reads it."""
+    name = config.remat_policy
+    if name == "auto":
+        name = _auto_remat.get()
+    if name == "full":
+        return layer_fn
+    if name not in _REMAT_POLICIES:
+        raise ValueError(
+            f"remat_policy={config.remat_policy!r}: expected \"auto\", \"full\" or "
+            f"one of {sorted(_REMAT_POLICIES)}"
+        )
+    return jax.checkpoint(layer_fn, policy=_REMAT_POLICIES[name])
 
 
 def _dot(config: LlamaConfig, x, w, tp_dim=None):
@@ -610,10 +677,9 @@ def _alternating_fns(config: LlamaConfig, layer_kw: dict, remat: bool = True):
         _layer, config, window=config.sliding_window, **layer_kw
     )
     global_fn = functools.partial(_layer, config, window=None, **layer_kw)
-    if remat and config.remat_policy != "full":
-        policy = _remat_policy(config.remat_policy)
-        local_fn = jax.checkpoint(local_fn, policy=policy)
-        global_fn = jax.checkpoint(global_fn, policy=policy)
+    if remat:
+        local_fn = checkpoint_layer(config, local_fn)
+        global_fn = checkpoint_layer(config, global_fn)
     return local_fn, global_fn
 
 
@@ -682,10 +748,7 @@ def llama_apply(
         position_offset=position_offset, attention_fn=attention_fn,
         segment_ids=segment_ids, position_ids=position_ids,
     )
-    layer_fn = functools.partial(_layer, config, **layer_kw)
-    policy = _remat_policy(config.remat_policy)
-    if config.remat_policy != "full":
-        layer_fn = jax.checkpoint(layer_fn, policy=policy)
+    layer_fn = checkpoint_layer(config, functools.partial(_layer, config, **layer_kw))
 
     alternating = config.alternating_sliding_window
     if layer_stack_fn is not None:
@@ -894,12 +957,9 @@ def llama_pipeline_parts(config: LlamaConfig, attention_fn: Optional[Callable] =
     MoE aux losses are not yet folded into the 1F1B path — Accelerator falls
     back to GPipe for expert models."""
     cdt = config.compute_dtype
-    layer_fn = functools.partial(
+    layer_fn = checkpoint_layer(config, functools.partial(
         _layer, config, position_offset=0, attention_fn=attention_fn
-    )
-    policy = _remat_policy(config.remat_policy)
-    if config.remat_policy != "full":
-        layer_fn = jax.checkpoint(layer_fn, policy=policy)
+    ))
     alt_fns = None
     if config.alternating_sliding_window:
         # stage slices start on even global layer indices whenever the

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from .attention import NEG_INF
@@ -433,6 +434,14 @@ def _flash_bwd(q, k, v, segs, out, lse, do, h, h_kv, causal, block_q, block_k,
 
 
 # ---------------------------------------------------------------- public op
+def _saved_by_name(out, lse):
+    """The forward kernel's two results under the names a ``jax.checkpoint``
+    policy can save them by (``models/llama.py``'s "dots" does): the backward
+    kernels need both, and a policy that saves only matmul outputs would run
+    the forward kernel again to have them."""
+    return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, segs, h, h_kv, causal, block_q, block_k, interpret,
                 window, softcap):
@@ -443,8 +452,8 @@ def _flash_core(q, k, v, segs, h, h_kv, causal, block_q, block_k, interpret,
 
 def _flash_core_fwd(q, k, v, segs, h, h_kv, causal, block_q, block_k, interpret,
                     window, softcap):
-    out, lse = _flash_fwd(q, k, v, segs, h, h_kv, causal, block_q, block_k,
-                          interpret, window, softcap)
+    out, lse = _saved_by_name(*_flash_fwd(
+        q, k, v, segs, h, h_kv, causal, block_q, block_k, interpret, window, softcap))
     return out, (q, k, v, segs, out, lse)
 
 
@@ -479,8 +488,8 @@ def _flash_core_lse(q, k, v, segs, h, h_kv, causal, block_q, block_k,
 
 def _flash_core_lse_fwd(q, k, v, segs, h, h_kv, causal, block_q, block_k,
                         interpret, softcap):
-    out, lse = _flash_fwd(q, k, v, segs, h, h_kv, causal, block_q, block_k,
-                          interpret, None, softcap)
+    out, lse = _saved_by_name(*_flash_fwd(
+        q, k, v, segs, h, h_kv, causal, block_q, block_k, interpret, None, softcap))
     return (out, lse), (q, k, v, segs, out, lse)
 
 

@@ -730,11 +730,11 @@ def main(argv=None) -> int:
            count=len(devices), jax=jax.__version__, compile_cache_dir=cache_dir)
 
     # Mistral-7B-v0.1 at its published widths. Each layer holds 218M
-    # parameters and the embedding with the head 262M; parameters, gradient
-    # accumulator and both Adam moments are float32, 16 bytes a parameter, and
-    # the step writes a second accumulator before it drops the first: 20.
-    # Two layers: 698M parameters, 14.0 GB of a 16 GB chip with 0.5 GB of
-    # activations at 2048 tokens a step. Three layers would need 18.3 GB.
+    # parameters and the embedding with the head 262M; parameters and both
+    # Adam moments are float32, 12 bytes a parameter between steps, and the
+    # gradient is a temporary of the step: 16. Two layers: 698M parameters,
+    # 8.4 GB held and 12.5 GB inside a step at 2048 tokens (the compiled
+    # step's own count on the chip, PR 36), of a 16 GB chip.
     mistral = functools.partial(LlamaConfig.mistral_7b, attention_impl="flash")
 
     if args.chips == 1:

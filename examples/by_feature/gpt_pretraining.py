@@ -55,6 +55,9 @@ def main():
         "small": lambda: GPT2Config.gpt2_small(
             max_position_embeddings=args.seq_len, use_chunked_ce=True
         ),
+        # remat_policy: "full" | "dots" | "dots_no_batch" | "minimal" |
+        # "nothing", or "auto" (the default: train_step keeps the fastest that
+        # fits the device); a policy stated here is never changed
         "medium": lambda: GPT2Config.gpt2_medium(
             max_position_embeddings=args.seq_len, use_chunked_ce=True,
             remat_policy="minimal",

@@ -41,6 +41,9 @@ def main():
 
     presets = {
         "tiny": lambda: LlamaConfig.tiny(max_position_embeddings=args.seq_len),
+        # remat_policy: "full" | "dots" | "dots_no_batch" | "minimal" |
+        # "nothing", or "auto" (the default: train_step keeps the fastest that
+        # fits the device); a policy stated here is never changed
         "7b": lambda: LlamaConfig.llama2_7b(
             max_position_embeddings=args.seq_len, remat_policy="dots"
         ),

@@ -334,16 +334,25 @@ def test_fsdp_plugin_wiring():
     assert model2.shardings["w"].spec == P(None, "dp_shard")
 
 
-def test_fsdp_plugin_activation_checkpointing():
+@pytest.mark.parametrize("policy", ["nothing", "auto"])
+def test_fsdp_plugin_activation_checkpointing(policy, monkeypatch):
+    """The plugin turns the default (and "nothing") into "minimal", which then
+    counts as set: the train step's plan leaves it alone."""
     import optax
 
-    from accelerate_tpu.models.llama import LlamaConfig, create_llama
+    import accelerate_tpu.accelerator as accelerator_module
+    from accelerate_tpu.models.llama import LlamaConfig, create_llama, llama_loss
     from accelerate_tpu.utils.dataclasses import FSDPPlugin
 
     acc = make_acc(fsdp_plugin=FSDPPlugin(activation_checkpointing=True))
-    cfg = LlamaConfig.tiny(remat_policy="nothing")
+    cfg = LlamaConfig.tiny(remat_policy=policy)
     model = create_llama(cfg)
-    model = acc.prepare(model)
+    model, _ = acc.prepare(model, optax.sgd(0.1))
+    assert model.config.remat_policy == "minimal"
+    monkeypatch.setattr(accelerator_module, "_device_memory", lambda: (1 << 40, 0))
+    step = acc.train_step(llama_loss)
+    step({"input_ids": jax.numpy.zeros((8, 16), jax.numpy.int32)})
+    assert step.plan["remat"] == "minimal" and step.plan["rungs_tried"] == 0
     assert model.config.remat_policy == "minimal"
 
 

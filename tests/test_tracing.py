@@ -624,6 +624,40 @@ def test_train_step_and_data_wait_leave_a_span_every_step(tracer):
     assert [sp.attrs["step"] for sp in waits][:4] == [0, 1, 2, 3]  # one a fetch
 
 
+def test_train_plan_leaves_one_span_with_the_rung_and_the_bytes(tracer, monkeypatch):
+    """One ``train.plan`` span a step built, with the rung kept, the rungs
+    tried and the bytes, the same facts as ``step.plan``; every ``train.step``
+    span names the rung."""
+    import jax.numpy as jnp
+    import optax
+
+    import accelerate_tpu.accelerator as accelerator_module
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.models.llama import REMAT_LADDER, LlamaConfig, create_llama, llama_loss
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+
+    monkeypatch.setattr(accelerator_module, "_device_memory", lambda: (1 << 40, 12345))
+    for state in (AcceleratorState, GradientState, PartialState):
+        state._reset_state()
+    try:
+        acc = Accelerator(gradient_accumulation_steps=2)
+        model, opt = acc.prepare(create_llama(LlamaConfig.tiny(), seed=0), optax.sgd(0.1))
+        step = acc.train_step(llama_loss)
+        for _ in range(3):
+            step({"input_ids": jnp.zeros((8, 16), jnp.int32)})
+    finally:
+        for state in (AcceleratorState, GradientState, PartialState):
+            state._reset_state()
+    (plan,) = tracer.spans(name="train.plan")
+    assert plan.attrs == step.plan
+    assert plan.attrs["remat"] == REMAT_LADDER[0] and plan.attrs["rungs_tried"] == 1
+    assert plan.attrs["bytes_limit"] == 1 << 40 and plan.attrs["bytes_in_use"] == 12345
+    assert plan.attrs["hbm_live"] > plan.attrs["accumulator_bytes"] > 0
+    sent = tracer.spans(name="train.step")
+    assert [sp.attrs["remat"] for sp in sent] == [REMAT_LADDER[0]] * 3
+    assert plan.t1 <= sent[0].t0  # the plan is made before the first step is sent
+
+
 # ------------------------------------------------- names in the device trace
 def test_named_scopes_reach_the_lowered_programs(tracer):
     """A profile names device work by each operation's ``op_name``: the
