@@ -1934,6 +1934,9 @@ class ContinuousBatchingEngine:
         self._step_counters.clear()
         self._prefill_queue.clear()
         self._backend.reset()  # fresh pool + empty prefix registry/tables
+        # the old state goes before the new one is made: a pool that fills most
+        # of the chip cannot be held twice (PERF.md, PR 38)
+        self._donated = self._carried = None
         self._donated, self._carried = self._init_state()
         return orphans
 

@@ -15,9 +15,9 @@ last block a final RMSNorm and an untied head. With ``u`` the normed input:
 * ``[k_nope_i | v_i] = c_kv W_kvb`` for head ``i``; ``s_i = (q_nope_i . k_nope_i
   + q_r_i . k_r) * scale``, causal softmax, ``o_i = softmax(s_i) v_i``, ``Attn =
   concat(o_i) W_o``. That order, *up-projected*, is the full forward's and the
-  prefill's (:class:`_SequenceView`): per-head keys ``qk_nope + qk_rope`` wide,
+  prefill's (:class:`SequenceView`): per-head keys ``qk_nope + qk_rope`` wide,
   values ``v_head_dim`` wide, over the prompt itself.
-* A decode step computes the same *absorbed* (:class:`_StepView`): ``q~_i =
+* A decode step computes the same *absorbed* (:class:`StepView`): ``q~_i =
   q_nope_i (W_kvb^K_i)^T`` (as wide as ``c_kv``), ``s_i = (q~_i . c_kv + q_r_i .
   k_r) * scale``, ``o~_i = softmax(s_i) c_kv``, ``o_i = o~_i W_kvb^V_i``: the
   step reads only cached rows, each once, as keys and (its first
@@ -41,6 +41,11 @@ over all of them; the held ones add their part, the others add nothing
 computed whole: the shares of a layer split over chips, the shared expert
 counted once, add up to the layer. ``vocab_size`` is the rows of the vocabulary
 held here, embedding and head alike: ids and logits are over the slice.
+
+The latent attention sublayer (:func:`mla_attention`, its two cache views and
+the rotation) is shared with LongCat-Flash (``models/longcat.py``), whose
+latents are scaled after their norms (:attr:`AxK1Config.latent_scales`: none
+here).
 
 The block is written once, over a *cache view*; the parameter tree keeps
 attention's parameters a layer (``attn.<layer>``) and stacks the dense and the
@@ -77,6 +82,12 @@ __all__ = [
     "axk1_prefill_at",
     "axk1_decode_step",
     "yarn_inv_freq",
+    "mla_params",
+    "mla_attention",
+    "SequenceView",
+    "StepView",
+    "swiglu",
+    "head_logits",
 ]
 
 _PUBLISHED_ROPE_SCALING = {
@@ -204,6 +215,12 @@ class AxK1Config:
         return (_yarn_mscale(factor, self.rope_scaling.get("mscale", 1))
                 / _yarn_mscale(factor, self.rope_scaling.get("mscale_all_dim", 0)))
 
+    @property
+    def latent_scales(self) -> tuple:
+        """The factors on the normed query latent and key-value latent: none in
+        A.X-K1 (LongCat-Flash has ``sqrt(hidden / rank)`` for each)."""
+        return (1.0, 1.0)
+
     def serving_family(self) -> ServingFamily:
         return ServingFamily(
             prefill=axk1_prefill, prefill_at=axk1_prefill_at,
@@ -247,6 +264,39 @@ class AxK1Config:
 _RESIDUAL_INIT_SCALE = 0.15
 
 
+def mla_params(config, normal) -> dict:
+    """One latent attention sublayer's parameters, its input norm among them;
+    ``normal(shape, scale)`` draws the next matrix. Every matrix at
+    ``1/sqrt(fan_in)``, ``o_proj`` at ``_RESIDUAL_INIT_SCALE`` of that."""
+    d, h = config.hidden_size, config.num_attention_heads
+    rq, rkv = config.q_lora_rank, config.kv_lora_rank
+    nope, rope, vd = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+
+    def matrix(shape, fan_in, scale=1.0):
+        return {"kernel": normal(shape, scale / np.sqrt(fan_in))}
+
+    def ones(width):
+        return {"scale": jnp.ones((width,), config.param_dtype)}
+
+    return {
+        "input_norm": ones(d),
+        "q_a": matrix((d, rq), d),
+        "q_a_norm": ones(rq),
+        # W_qb's and W_kvb's columns apart by what they make (a head's q_nope
+        # and q_r; its k_nope and v), W_qb's and the value half's outputs on
+        # the rows and W_kvb a head at a time: one matrix cut by columns
+        # inside a step, or read in another order than it lies in, is a copy
+        # of it every step (a decode step copied 0.53e9 B of them)
+        "q_b_nope": matrix((h * nope, rq), rq),
+        "q_b_rope": matrix((h * rope, rq), rq),
+        "kv_a": matrix((d, rkv + rope), d),
+        "kv_a_norm": ones(rkv),
+        "kv_b_k": matrix((h, rkv, nope), rkv),
+        "kv_b_v": matrix((h, vd, rkv), rkv),
+        "o_proj": matrix((h * vd, d), h * vd, _RESIDUAL_INIT_SCALE),
+    }
+
+
 def init_axk1_params(config: AxK1Config, key: jax.Array) -> dict:
     """Attention a layer, dense and expert layers stacked by kind on the first
     axis. Every matrix is drawn at
@@ -257,7 +307,6 @@ def init_axk1_params(config: AxK1Config, key: jax.Array) -> dict:
     g, e = config.n_routed_experts, config.router_experts
     i, im = config.intermediate_size, config.moe_intermediate_size
     ims = im * config.n_shared_experts
-    rq, rkv = config.q_lora_rank, config.kv_lora_rank
     dtype = config.param_dtype
     keys = iter(jax.random.split(key, 16 + 8 * n))
 
@@ -270,25 +319,6 @@ def init_axk1_params(config: AxK1Config, key: jax.Array) -> dict:
     def ones(shape):
         return {"scale": jnp.ones(shape, dtype)}
 
-    def attention_layer():
-        return {
-            "input_norm": ones((d,)),
-            "q_a": matrix((), d, rq),
-            "q_a_norm": ones((rq,)),
-            # W_qb's and W_kvb's columns apart by what they make (a head's q_nope
-            # and q_r; its k_nope and v), W_qb's and the value half's outputs on
-            # the rows and W_kvb a head at a time: one matrix cut by columns
-            # inside a step, or read in another order than it lies in, is a copy
-            # of it every step (a decode step copied 0.53e9 B of them)
-            "q_b_nope": {"kernel": normal((h * config.qk_nope_head_dim, rq), 1 / np.sqrt(rq))},
-            "q_b_rope": {"kernel": normal((h * config.qk_rope_head_dim, rq), 1 / np.sqrt(rq))},
-            "kv_a": matrix((), d, rkv + config.qk_rope_head_dim),
-            "kv_a_norm": ones((rkv,)),
-            "kv_b_k": matrix((h,), rkv, config.qk_nope_head_dim),
-            "kv_b_v": {"kernel": normal((h, config.v_head_dim, rkv), 1 / np.sqrt(rkv))},
-            "o_proj": matrix((), h * config.v_head_dim, d, _RESIDUAL_INIT_SCALE),
-        }
-
     return {
         "embed_tokens": {"embedding": normal((config.vocab_size, d), 0.02)},
         "norm": ones((d,)),
@@ -296,7 +326,7 @@ def init_axk1_params(config: AxK1Config, key: jax.Array) -> dict:
         # attention's parameters a layer, not stacked: a layer's slice of a stack
         # is a copy where the compiler stages that weight ahead of its matmul,
         # and it made one of every layer's, every step (PERF.md, PR 35)
-        "attn": {str(layer): attention_layer() for layer in range(n)},
+        "attn": {str(layer): mla_params(config, normal) for layer in range(n)},
         "dense": {
             "ffn_norm": ones((nd, d)),
             "w1": matrix((nd,), d, i),
@@ -343,11 +373,19 @@ def _padded_row(config: AxK1Config, latent, rope):
         [latent, rope, jnp.zeros((*latent.shape[:-1], pad), latent.dtype)], axis=-1)
 
 
+def _scaled(x, factor: float):
+    """``x * factor``, and ``x`` itself where the factor is 1: a family without
+    latent scales (A.X-K1) keeps its program to the operation."""
+    return x if factor == 1.0 else x * factor
+
+
 # ------------------------------------------------------------------ cache views
-class _SequenceView:
+class SequenceView:
     """Whole sequences from position 0, nothing cached before them: the full
     forward and the prefill, attention up-projected. With ``keep`` it keeps what
-    a cache needs: every layer's rows ``[c_kv | k_r]``."""
+    a cache needs: every layer's rows ``[c_kv | k_r]``. The key-value latent's
+    scale (``latent_scales[1]``) multiplies the up-projected keys and values in
+    float32: the row is cached unscaled."""
 
     def __init__(self, config: AxK1Config, keep: bool = False):
         self.config, self.keep = config, keep
@@ -364,8 +402,11 @@ class _SequenceView:
         c_kv = row[..., : config.kv_lora_rank]
         k_rope = row[..., config.kv_lora_rank: config.kv_lora_rank + config.qk_rope_head_dim]
         w_k, w_v = w_k.astype(cdt), w_v.astype(cdt)  # (h, rkv, nope), (h, v, rkv)
-        k_nope = jnp.einsum("btc,hcn->bthn", c_kv[:, :, 0], w_k, preferred_element_type=jnp.float32)
-        v = jnp.einsum("btc,hnc->bthn", c_kv[:, :, 0], w_v, preferred_element_type=jnp.float32)
+        kv_scale = config.latent_scales[1]
+        k_nope = _scaled(jnp.einsum("btc,hcn->bthn", c_kv[:, :, 0], w_k,
+                                    preferred_element_type=jnp.float32), kv_scale)
+        v = _scaled(jnp.einsum("btc,hnc->bthn", c_kv[:, :, 0], w_v,
+                               preferred_element_type=jnp.float32), kv_scale)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate(
             [k_nope.astype(cdt), jnp.broadcast_to(k_rope, (b, t, h, k_rope.shape[-1]))], axis=-1)
@@ -377,10 +418,12 @@ class _SequenceView:
         return {"k": jnp.pad(rows, pad)}
 
 
-class _StepView:
+class StepView:
     """One new position a row, at ``pos``, over a cache of rows ``"k"`` (a dense
     ``(layers, B, max_len, 1, row)`` arena, or with ``kv_layout`` the paged pool,
-    carried whole): attention absorbed, the step reads only cached rows."""
+    carried whole): attention absorbed, the step reads only cached rows. The
+    key-value latent's scale multiplies the absorbed query and the result, each
+    in float32, where the sequence view scales keys and values."""
 
     def __init__(self, config: AxK1Config, cache: dict, pos, kv_layout=None):
         self.config, self.pos, self.kv_layout = config, pos, kv_layout
@@ -392,15 +435,17 @@ class _StepView:
     def attend(self, index: int, q_nope, q_rope, row, w_k, w_v):
         config, cdt = self.config, self.config.compute_dtype
         w_k, w_v = w_k.astype(cdt), w_v.astype(cdt)  # (h, rkv, nope), (h, v, rkv)
-        q_latent = jnp.einsum("bthn,hcn->bthc", q_nope, w_k, preferred_element_type=jnp.float32)
+        kv_scale = config.latent_scales[1]
+        q_latent = _scaled(jnp.einsum("bthn,hcn->bthc", q_nope, w_k,
+                                      preferred_element_type=jnp.float32), kv_scale)
         q = _padded_row(config, q_latent.astype(cdt), q_rope)  # as wide as a row
         out, (self.rows, _) = attend_step(
             self.kv_layout, (self.rows, None), index, q, row, None, self.pos,
             scale=config.softmax_scale, value_dim=config.kv_lora_rank,
         )
-        return jnp.einsum(
+        return _scaled(jnp.einsum(
             "bthc,hnc->bthn", out.astype(cdt), w_v, preferred_element_type=jnp.float32
-        ).astype(cdt)
+        ), kv_scale).astype(cdt)
 
     def cache(self) -> dict:
         return {"k": self.rows}
@@ -414,15 +459,19 @@ def _matmul(config: AxK1Config, x, kernel):
     return jnp.dot(x.astype(cdt), kernel.astype(cdt), preferred_element_type=jnp.float32)
 
 
-def _attention(config: AxK1Config, p: dict, layer: int, u, view):
-    # ``p``: this layer's own parameters
+def mla_attention(config, p: dict, layer: int, u, view):
     """The query's two parts and the position's new row, handed to the view:
-    what is cached and in which order the products are taken is the view's."""
+    what is cached and in which order the products are taken is the view's.
+    ``p`` is the sublayer's own parameters (:func:`mla_params`), ``layer`` its
+    place in the cache; ``config`` any family's with the MLA widths,
+    ``rope_theta``, ``rope_scaling``, ``rope_amplitude``, ``softmax_scale``,
+    ``cache_row_dim`` and ``latent_scales``."""
     cdt, eps = config.compute_dtype, config.rms_norm_eps
     b, t, _ = u.shape
     h, rkv = config.num_attention_heads, config.kv_lora_rank
     positions = view.positions(t)
-    c_q = rms_norm(_matmul(config, u, p["q_a"]["kernel"]), p["q_a_norm"]["scale"], eps)
+    c_q = _scaled(rms_norm(_matmul(config, u, p["q_a"]["kernel"]), p["q_a_norm"]["scale"], eps),
+                  config.latent_scales[0])
     def up(name):  # W_qb's rows are its outputs: (heads * width, q_lora_rank)
         return jnp.einsum("btr,fr->btf", c_q.astype(cdt), p[name]["kernel"].astype(cdt),
                           preferred_element_type=jnp.float32).reshape(b, t, h, -1)
@@ -440,10 +489,14 @@ def _attention(config: AxK1Config, p: dict, layer: int, u, view):
     return _matmul(config, out.reshape(b, t, h * config.v_head_dim), p["o_proj"]["kernel"])
 
 
-def _swiglu(config: AxK1Config, u, p: dict, index: int):
-    gate = jax.nn.silu(_matmul(config, u, p["w1"]["kernel"][index]))
-    up = _matmul(config, u, p["w3"]["kernel"][index])
-    return _matmul(config, gate * up, p["w2"]["kernel"][index])
+def swiglu(config, u, p: dict, index: Optional[int] = None):
+    """``w2(silu(u w1) * u w3)`` of ``p``'s three kernels, or of their
+    ``index``-th layer where they are stacked."""
+    def kernel(name):
+        return p[name]["kernel"] if index is None else p[name]["kernel"][index]
+
+    gate = jax.nn.silu(_matmul(config, u, kernel("w1")))
+    return _matmul(config, gate * _matmul(config, u, kernel("w3")), kernel("w2"))
 
 
 def _experts(config: AxK1Config, p: dict, index: int, u, view):
@@ -461,7 +514,7 @@ def _experts(config: AxK1Config, p: dict, index: int, u, view):
     routed = routed.reshape(b, t, d)
     if not config.n_shared_experts:
         return routed
-    return _swiglu(config, u, p["shared"], index) + routed
+    return swiglu(config, u, p["shared"], index) + routed
 
 
 def _block(config: AxK1Config, params: dict, layer: int, x, view):
@@ -472,11 +525,11 @@ def _block(config: AxK1Config, params: dict, layer: int, x, view):
     eps = config.rms_norm_eps
     p = params["attn"][str(layer)]
     u = rms_norm(x, p["input_norm"]["scale"], eps)
-    h = x + _attention(config, p, layer, u, view)
+    h = x + mla_attention(config, p, layer, u, view)
     if layer < config.first_k_dense_replace:
         p = params["dense"]
         u = rms_norm(h, p["ffn_norm"]["scale"][layer], eps)
-        return h + _swiglu(config, u, p, layer)
+        return h + swiglu(config, u, p, layer)
     p = params["moe"]
     index = layer - config.first_k_dense_replace
     u = rms_norm(h, p["ffn_norm"]["scale"][index], eps)
@@ -492,7 +545,7 @@ def _layers(config: AxK1Config, params: dict, tokens, view):
     return x
 
 
-def _head(config: AxK1Config, params: dict, x):
+def head_logits(config, params: dict, x):
     """The last norm and the untied head: float32 logits over the vocabulary
     held here."""
     x = rms_norm(x, params["norm"]["scale"], config.rms_norm_eps)
@@ -528,8 +581,8 @@ def moe_step_summary(counters: dict) -> dict:
 def axk1_apply(config: AxK1Config, params: dict, input_ids):
     """Full forward, differentiable: ``input_ids`` (B, S) -> float32 logits
     (B, S, V)."""
-    view = _SequenceView(config)
-    return _head(config, params, _layers(config, params, input_ids, view))
+    view = SequenceView(config)
+    return head_logits(config, params, _layers(config, params, input_ids, view))
 
 
 axk1_loss = llama_loss  # next-token cross entropy over whatever the forward returns
@@ -542,10 +595,10 @@ def axk1_prefill_at(config: AxK1Config, params: dict, input_ids, max_len: int, l
     ``(layers, B, max_len, 1, cache_row_dim)``, padded
     positions' included (decode overwrites a position before it attends it);
     there is no ``"v"``."""
-    view = _SequenceView(config, keep=True)
+    view = SequenceView(config, keep=True)
     x = _layers(config, params, input_ids, view)
     last = x[jnp.arange(x.shape[0]), last_index]
-    return _head(config, params, last), view.cache(max_len), _step_counters(config, view)
+    return head_logits(config, params, last), view.cache(max_len), _step_counters(config, view)
 
 
 def axk1_prefill(config: AxK1Config, params: dict, input_ids, max_len: int):
@@ -558,10 +611,10 @@ def axk1_decode_step(config: AxK1Config, params: dict, cache: dict, token, pos, 
                      kv_layout=None):
     """One token a row: ``token`` (B, 1) at ``pos`` (a traced scalar, or (B,)
     positions of continuous-batching slots) -> ``(logits (B, V), cache,
-    counters)``. The block at a window of one over a :class:`_StepView`."""
-    view = _StepView(config, cache, pos, kv_layout)
+    counters)``. The block at a window of one over a :class:`StepView`."""
+    view = StepView(config, cache, pos, kv_layout)
     x = _layers(config, params, token, view)
-    return _head(config, params, x[:, 0]), view.cache(), _step_counters(config, view)
+    return head_logits(config, params, x[:, 0]), view.cache(), _step_counters(config, view)
 
 
 def create_axk1(config: AxK1Config, seed: int = 0, abstract: bool = False) -> Model:

@@ -13,9 +13,10 @@ shape static (XLA requirement); overflow tokens are dropped (standard Switch
 behavior) and counted in the aux metrics.
 
 Beside it, :func:`dropless_moe` is the expert layer a server needs: sigmoid
-scores, every token reaches the experts it chose whatever the rest of the
-batch chose, rows sorted by expert into grouped matmuls (the Pallas kernel
-``moe_gmm`` of :mod:`.grouped_matmul`), and it is told which experts it holds.
+or softmax scores, every token reaches the experts it chose whatever the rest
+of the batch chose, rows sorted by expert into grouped matmuls (the Pallas
+kernel ``moe_gmm`` of :mod:`.grouped_matmul`), zero-compute (identity) experts
+beside them, and it is told which experts it holds.
 """
 
 from __future__ import annotations
@@ -170,17 +171,24 @@ def _keep_best_groups(scores, n_group: int, topk_group: int):
     return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, e)
 
 
+_SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
+
+
 def route_sigmoid_topk(x, router_kernel, expert_bias, num_selected: int, *,
                        norm_topk: bool = True, norm_eps: float = 1e-6,
-                       scale: float = 1.0, n_group: int = 1, topk_group: int = 1):
-    """Sigmoid routing in float32: ``s = sigmoid(x @ W_r)``; the chosen experts
-    are the top-k of ``s + expert_bias`` (the bias takes part in the choice
-    only), with ``n_group > 1`` among the experts of a row's ``topk_group`` best
-    groups only (:func:`_keep_best_groups`); the weights are ``s`` of the
-    chosen, renormalised over them (``/ (sum + norm_eps)``) when ``norm_topk``,
-    times ``scale``. ``x`` (N, D) -> ``(experts (N, k) int32, weights (N, k)
-    float32)``. A row's routing depends on that row alone."""
-    scores = jax.nn.sigmoid(jnp.dot(
+                       scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
+                       scoring: str = "sigmoid"):
+    """Routing in float32: ``s = sigmoid(x @ W_r)``, or with ``scoring=
+    "softmax"`` the softmax of ``x @ W_r`` over all the experts; the chosen
+    experts are the top-k of ``s + expert_bias`` (the bias takes part in the
+    choice only), with ``n_group > 1`` among the experts of a row's
+    ``topk_group`` best groups only (:func:`_keep_best_groups`); the weights are
+    ``s`` of the chosen, renormalised over them (``/ (sum + norm_eps)``) when
+    ``norm_topk``, times ``scale``. ``x`` (N, D) -> ``(experts (N, k) int32,
+    weights (N, k) float32)``. A row's routing depends on that row alone. (The
+    name is older than the softmax: the benchmark's planted fault,
+    ``chipbench/tests/expert_left_out.py``, replaces it by this name.)"""
+    scores = _SCORINGS[scoring](jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
@@ -223,6 +231,8 @@ def dropless_moe(
     scale: float = 1.0,
     n_group: int = 1,
     topk_group: int = 1,
+    scoring: str = "sigmoid",
+    zero_experts: int = 0,
     compute_dtype=jnp.bfloat16,
 ) -> tuple[jax.Array, jax.Array]:
     """SwiGLU experts without capacity and without drops: every row reaches the
@@ -232,9 +242,14 @@ def dropless_moe(
     ``E`` experts; ``w1``/``w3`` (G, D, I) and ``w2`` (G, I, D) are the ``G``
     experts held here, experts ``first .. first + G - 1`` (default: all of
     them). Routing runs over all ``E`` (``n_group``, ``topk_group``,
-    ``norm_eps``: :func:`route_sigmoid_topk`'s); the result is the part the held
-    experts give, so the shares of a layer split over chips add up to the whole
-    layer.
+    ``norm_eps``, ``scoring``: :func:`route_sigmoid_topk`'s); the result is the
+    part the held experts give, so the shares of a layer split over chips add up
+    to the whole layer.
+    The last ``zero_experts`` of the ``E`` are zero-compute experts (identities,
+    LongCat-Flash's): a pair that chose one is no group's row in the grouped
+    matmuls, and adds ``w * x`` to its row once, on every chip whatever ``first``
+    is, as a shared expert would (held experts must lie among the other ``E -
+    zero_experts``).
     With ``layer`` (an int or a traced scalar) the weights are every layer's,
     stacked on a leading axis, ``(L, G, ...)``: they are handed to the grouped
     matmul whole, as ``L * G`` groups of which only this layer's hold rows,
@@ -264,9 +279,12 @@ def dropless_moe(
     stacked = layer is not None
     g = w1.shape[1] if stacked else w1.shape[0]
     k = num_selected
+    if first + g > e - zero_experts:
+        raise ValueError(f"held experts {first} .. {first + g - 1} reach the "
+                         f"{zero_experts} zero-compute experts of {e}")
     experts, weights = route_sigmoid_topk(
         x, router_kernel, expert_bias, k, norm_topk=norm_topk, norm_eps=norm_eps,
-        scale=scale, n_group=n_group, topk_group=topk_group,
+        scale=scale, n_group=n_group, topk_group=topk_group, scoring=scoring,
     )
     flat = experts.reshape(n * k)
     rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
@@ -310,4 +328,7 @@ def dropless_moe(
         )
     parts = parts.reshape(n, k, d)  # back to (row, choice)
     out = jnp.sum(parts * weights[:, :, None], axis=1)
+    if zero_experts:  # a zero-compute pair's part is zero above: it adds its row
+        identity = jnp.sum(jnp.where(experts >= e - zero_experts, weights, 0.0), axis=-1)
+        out = out + identity[:, None] * x.astype(jnp.float32)
     return out.astype(x.dtype), rows

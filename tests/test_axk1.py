@@ -222,10 +222,10 @@ def test_absorbed_attention_is_up_projected_attention_on_the_same_rows():
         jax.random.normal(jax.random.fold_in(key, 3), (b, t, 1, config.qk_rope_head_dim)))
     kv_b = (model.params["attn"][str(layer)]["kv_b_k"]["kernel"],
             model.params["attn"][str(layer)]["kv_b_v"]["kernel"])
-    up = axk1._SequenceView(config).attend(layer, q_nope, q_rope, row, *kv_b)
+    up = axk1.SequenceView(config).attend(layer, q_nope, q_rope, row, *kv_b)
     arena = jnp.zeros((config.num_hidden_layers, b, t, 1, config.cache_row_dim))
     for pos in range(t):
-        view = axk1._StepView(config, {"k": arena}, jnp.asarray(pos))
+        view = axk1.StepView(config, {"k": arena}, jnp.asarray(pos))
         out = view.attend(layer, q_nope[:, pos: pos + 1], q_rope[:, pos: pos + 1],
                           row[:, pos: pos + 1], *kv_b)
         arena = view.cache()["k"]
@@ -339,9 +339,9 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_layer(
     p = model.params["moe"]
     index = 1
     x = jax.random.normal(jax.random.key(9), (24, config.hidden_size), jnp.float32)
-    whole_view = axk1._SequenceView(config)
+    whole_view = axk1.SequenceView(config)
     whole = axk1._experts(config, p, index, x[None], whole_view)[0]
-    shared = axk1._swiglu(config, x[None], p["shared"], index)[0]
+    shared = axk1.swiglu(config, x[None], p["shared"], index)[0]
     held = config.n_routed_experts // 4
     parts = []
     for share in range(4):
@@ -349,7 +349,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_layer(
         cut_config = AxK1Config.tiny(param_dtype=jnp.float32, compute_dtype=jnp.float32,
                                      n_routed_experts=held, router_experts=16, first_expert=first)
         cut = {**p, "experts": {n: p["experts"][n][:, first: first + held] for n in ("w1", "w3", "w2")}}
-        view = axk1._SequenceView(cut_config)
+        view = axk1.SequenceView(cut_config)
         part = axk1._experts(cut_config, cut, index, x[None], view)[0]
         assert np.array_equal(np.asarray(view.expert_rows[0]), np.asarray(whole_view.expert_rows[0]))
         want = reference._experts(as_file(cut_config), "float32", x, flat({"moe": cut}), index)

@@ -330,6 +330,25 @@ def test_engine_failure_fails_inflight_and_server_recovers(model, get_engine, fa
     assert srv.metrics["batch_failures"] >= 1
 
 
+def test_reset_lets_go_of_the_old_state_before_it_makes_the_new(get_engine, monkeypatch):
+    """A server's worker resets its engine whenever it stops; the old pool is
+    dropped before the fresh one is allocated, so a pool that fills most of the
+    chip is never held twice (the LongCat cell's 4.03e9 B pool beside 10.35e9 B of
+    weights could not be: PERF.md, PR 38)."""
+    eng = get_engine(slots=2)
+    made = eng._init_state
+    seen = []
+
+    def init_state():
+        seen.append((eng._donated, eng._carried))
+        return made()
+
+    monkeypatch.setattr(eng, "_init_state", init_state)
+    eng.reset()
+    assert seen == [(None, None)]
+    assert eng._donated is not None and eng._carried is not None
+
+
 def test_submissions_from_many_threads_all_resolve(model, get_engine):
     eng = get_engine(slots=4)
     cfg = ServingConfig(

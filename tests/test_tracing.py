@@ -592,6 +592,35 @@ def test_continuous_server_tick_nests_dispatch_readback_and_reply(tracer):
         assert inside <= tick.duration_s + 1e-9
 
 
+def test_readback_spans_count_every_expert_pair_by_where_it_went(tracer):
+    """A family with zero-compute experts (LongCat-Flash, four of sixteen routed
+    experts held, eight identities) puts on every ``engine.readback`` span, decode
+    steps and prefills alike, its chosen (row, expert) pairs of all expert layers
+    (``moe_pairs``) and those that went to an identity (``moe_zero_pairs``), beside
+    the held experts' counters: held, elsewhere and identity add up to all."""
+    import jax.numpy as jnp
+
+    from accelerate_tpu.models.longcat import LongcatFlashConfig, create_longcat
+
+    config = LongcatFlashConfig.tiny(n_routed_experts=4, router_experts=24, first_expert=4,
+                                     compute_dtype=jnp.float32)
+    model = create_longcat(config, seed=0)
+    cfg = ServingConfig(mode="continuous", kv_cache="paged", attention_impl="pallas",
+                        engine_slots=2, engine_max_len=32, engine_prompt_bucket=8,
+                        engine_block_size=4, engine_readback_lag=1)
+    with InferenceServer(model, cfg) as srv:
+        for f in [srv.submit(p, max_new_tokens=4, pad_token_id=0) for p in ([5, 9, 3], [8, 1])]:
+            f.result(timeout=120)
+    readback = [sp.attrs for sp in tracer.spans(name="engine.readback") if "moe_pairs" in sp.attrs]
+    assert {a["kind"] for a in readback} == {"prefill", "decode"}
+    per_row = config.moe_topk * config.num_layers
+    for a in readback:
+        rows = 2 if a["kind"] == "decode" else 8  # the slots, or the prompt's bucket
+        assert a["moe_pairs"] == rows * per_row
+        assert a["moe_assignments"] + a["moe_rows_elsewhere"] + a["moe_zero_pairs"] == a["moe_pairs"]
+    assert sum(a["moe_zero_pairs"] for a in readback) > 0
+
+
 def test_train_step_and_data_wait_leave_a_span_every_step(tracer):
     import optax
 
