@@ -1956,8 +1956,9 @@ class ContinuousBatchingEngine:
         block_size = getattr(self._backend, "block_size", 0)
         if not block_size or self.attention_impl != "pallas":
             return 0
+        latent = self._family.value_dim is not None  # the kernel's longer chunk
         return sum(
-            decode_walked_positions(_held_positions(o), block_size)
+            decode_walked_positions(_held_positions(o), block_size, latent)
             for o in self._occupants
         )
 

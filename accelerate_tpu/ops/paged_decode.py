@@ -12,9 +12,10 @@ block table *inside* the kernel instead:
   block tables and per-slot positions ride in as scalar-prefetch operands,
   and the kernel walks a slot's own ``ceil((pos + 1) / T)`` chunks of ``T``
   positions (``decode_chunk_positions``: the blocks that fill a 128-lane
-  score tile) in a loop. A chunk's live blocks are copied by
-  ``pltpu.make_async_copy`` — pool block ``tables[slot, j]`` whole (all KV
-  heads: one contiguous copy) — into one of two buffers, so that the next
+  score tile, four such tiles over a latent pool) in a loop. A chunk's
+  live blocks are copied by ``pltpu.make_async_copy`` — pool block
+  ``tables[slot, j]`` whole (all KV heads: one contiguous copy) — into one
+  of two buffers, so that the next
   chunk, or the next slot's first, is in flight while this one is computed.
   Blocks wholly past a slot's position are *neither fetched nor weighted*
   — exactly the contract documented on ``paged_attention`` (masked scores
@@ -138,20 +139,26 @@ def _load_kv_head(k_ref, v_ref, g, d, scales):
 # positions, a whole number of blocks; the engine's `kv_walked_tokens` counter
 # is reckoned from the same two functions, so the two cannot drift.
 _CHUNK_LANES = 128
+# A latent chunk carries one row a position where a per-head chunk carries
+# keys and values of every kv head, so 128 of its positions are too few bytes
+# for a chunk's fixed work (the descriptors' loop, the dependent chain of two
+# matmuls and a softmax update): at 512 it carries a GPT-2 chunk's 655 KB.
+_LATENT_CHUNK_LANES = 512
 
 
-def decode_chunk_positions(block_size: int) -> int:
+def decode_chunk_positions(block_size: int, latent: bool = False) -> int:
     """Positions the decode kernel folds into its softmax at a time: the
-    blocks that fill a 128-lane score tile (one block where a block is
-    wider)."""
-    return max(_CHUNK_LANES // block_size, 1) * block_size
+    blocks that fill a 128-lane score tile, or four such tiles over a latent
+    pool (one block where a block is wider)."""
+    lanes = _LATENT_CHUNK_LANES if latent else _CHUNK_LANES
+    return max(lanes // block_size, 1) * block_size
 
 
-def decode_walked_positions(live: int, block_size: int) -> int:
+def decode_walked_positions(live: int, block_size: int, latent: bool = False) -> int:
     """Positions `paged_flash_decode` computes on for a slot that holds
     ``live`` positions (its ``pos + 1``): whole chunks, and one chunk for a
     slot that holds nothing (a vacant slot rides at position 0)."""
-    chunk = decode_chunk_positions(block_size)
+    chunk = decode_chunk_positions(block_size, latent)
     return max(-(-live // chunk), 1) * chunk
 
 
@@ -411,6 +418,14 @@ def paged_flash_decode(
     is copied out of HBM once and serves both matmuls. Returns (B, 1, h,
     ``value_dim``).
     """
+    walk = _latent_decode if value_dim is not None else _paged_flash_decode
+    return walk(q, k_pool, v_pool, block_tables, pos, k_scale=k_scale, v_scale=v_scale,
+                scale=scale, softcap=softcap, layer=layer, value_dim=value_dim,
+                interpret=interpret)
+
+
+def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scale, scale,
+                        softcap, layer, value_dim, interpret):
     b, sq, h, d = q.shape
     if sq != 1:
         raise ValueError(f"paged_flash_decode takes one query token, got {sq}")
@@ -437,7 +452,7 @@ def paged_flash_decode(
         scale = 1.0 / math.sqrt(d)
     interpret = _resolve_interpret(interpret)
     quantized = k_scale is not None
-    chunk = decode_chunk_positions(bs)
+    chunk = decode_chunk_positions(bs, latent)
     chunk_blocks = chunk // bs
     n_chunks = -(-bpr // chunk_blocks)
 
@@ -495,6 +510,15 @@ def paged_flash_decode(
         name="paged_decode",
     )(block_tables, pos.astype(jnp.int32), *args)
     return out[:, :h].reshape(b, 1, h, d_out)
+
+
+# A latent chunk unrolls 32 blocks' copies where a per-head chunk unrolls 8,
+# and lowering that kernel once for every layer of a step took a serving
+# cell's set-up 5 s longer on the chip's host (PERF.md, section 6). Jitted, the
+# layers' calls, which are the same, trace and lower it once; XLA inlines the
+# call, so the step runs the same kernels.
+_latent_decode = jax.jit(
+    _paged_flash_decode, static_argnames=("scale", "softcap", "value_dim", "interpret"))
 
 
 # ------------------------------------------------------------ verify kernel

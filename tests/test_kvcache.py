@@ -441,26 +441,33 @@ def test_paged_pool_hbm_is_smaller_and_stats_track_live_tokens(model, get_engine
 
 
 @pytest.mark.parametrize(
-    "kv_cache,attention_impl", [("paged", "pallas"), ("paged", "reference"), ("dense", "reference")]
+    "family,kv_cache,attention_impl",
+    [("llama", "paged", "pallas"), ("llama", "paged", "reference"), ("llama", "dense", "reference"),
+     ("axk1", "paged", "pallas")],
 )
-def test_walked_tokens_count_whole_chunks_of_the_decode_kernel(model, kv_cache, attention_impl):
+def test_walked_tokens_count_whole_chunks_of_the_decode_kernel(model, family, kv_cache, attention_impl):
     """`kv_walked_tokens` of the `engine.decode_step` span: each slot's live
     positions rounded up to the decode kernel's chunk (the kernel's own
-    function), a chunk for the vacant slot; nothing where that kernel does not
-    run: the reference attention over a pool, a cache without one."""
+    function, whose chunk is four times as long over a latent pool: A.X-K1's),
+    a chunk for the vacant slot; nothing where that kernel does not run: the
+    reference attention over a pool, a cache without one."""
+    from accelerate_tpu.models.axk1 import AxK1Config, create_axk1
     from accelerate_tpu.ops.paged_decode import decode_chunk_positions, decode_walked_positions
 
+    latent = family == "axk1"
+    if latent:
+        model = create_axk1(AxK1Config.tiny(compute_dtype=jnp.float32), seed=3)
     eng = ContinuousBatchingEngine(
         model, slots=2, max_len=64, prompt_bucket=16, kv_cache=kv_cache,
         block_size=8, attention_impl=attention_impl,
     )
-    chunk = decode_chunk_positions(8) if attention_impl == "pallas" else 0
+    chunk = decode_chunk_positions(8, latent) if attention_impl == "pallas" else 0
     assert eng.walked_tokens() == 2 * chunk  # both vacant
     occ = eng.insert(_prompts(1, seed=31)[0], max_new_tokens=6, pad_token_id=0)
     live = len(occ.prompt) + len(occ.tokens)
     assert eng.live_tokens() == live
     if attention_impl == "pallas":
-        assert eng.walked_tokens() == decode_walked_positions(live, 8) + chunk
+        assert eng.walked_tokens() == decode_walked_positions(live, 8, latent) + chunk
     else:
         assert eng.walked_tokens() == 0
     eng.drain()

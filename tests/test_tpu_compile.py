@@ -187,14 +187,26 @@ def test_paged_flash_decode_compiles_on_the_whole_pool(compile_for_chip, case):
     assert sizes and sum(sizes) < 16 << 20, sizes
 
 
-def test_paged_flash_decode_compiles_on_a_latent_pool(compile_for_chip):
-    """The A.X-K1 cell's call (PR 35): 64 slots of 4,096 positions under 64 query
-    heads, one row of 640 a position (576 and zeros: whole tiles of 128 lanes)
-    whose first 512 columns are its value, seven layers' rows in one pool of 2.3 GB
-    that nothing may copy, and no pool of values at all. At 576 the chip lays the
+# the two latent cells' calls as (slots, layers, pool blocks, blocks a row):
+# A.X-K1, 64 slots of 4,096 positions over 7 layers; LongCat-Flash-Chat, 192
+# slots of 2,048 over its 8 latent sublayers
+LATENT_POOLS = {
+    "ax_k1_serve_reason64": (64, 7, 16385, 256),
+    "longcat_serve_chat": (192, 8, 24577, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_POOLS))
+def test_paged_flash_decode_compiles_on_a_latent_pool(compile_for_chip, case):
+    """A latent cell's call: 64 query heads over one row of 640 a position (576
+    and zeros: whole tiles of 128 lanes) whose first 512 columns are its value,
+    every layer's rows in one pool of 2.3 or 4.0 GB that nothing may copy, and no
+    pool of values at all; the walk's two buffers of a latent chunk (512
+    positions) inside the 16 MB a kernel gets unasked. At 576 the chip lays the
     rows out 640 wide all the same and the kernel's block copy is refused for a
     slice that is not whole tiles: the row is stored at the width it occupies."""
-    slots, heads, row, value, layers, blocks = 64, 64, 640, 512, 7, 16385
+    slots, layers, blocks, blocks_per_row = LATENT_POOLS[case]
+    heads, row, value = 64, 640, 512
 
     def decode(q, rows, tables, pos, layer):
         return paged_flash_decode(q, rows, None, tables, pos, layer=layer, value_dim=value,
@@ -202,7 +214,7 @@ def test_paged_flash_decode_compiles_on_a_latent_pool(compile_for_chip):
 
     operands = (((slots, 1, heads, row), jnp.bfloat16),
                 ((layers, blocks, BLOCK_SIZE, row), jnp.bfloat16),
-                ((slots, 256), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32))
+                ((slots, blocks_per_row), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32))
     text = compile_for_chip(decode, *operands)
     flat = f"bf16[{layers * blocks},{BLOCK_SIZE},{row}]"
     made = [line for line in text.splitlines()
@@ -210,6 +222,10 @@ def test_paged_flash_decode_compiles_on_a_latent_pool(compile_for_chip):
     assert not made, made[:2]
     calls = re.findall(r"^\s*%paged_decode[.\d]* = .*$", text, flags=re.M)
     assert len(calls) == 1 and f"bf16[{slots},{heads},{value}]" in calls[0], calls
+    assert "vmem_limit_bytes" not in calls[0]
+    scoped = re.search(r'"used_scoped_memory_configs":\[([^\]]*)\]', calls[0])
+    sizes = [int(n) for n in re.findall(r'"size":"(\d+)"', scoped.group(1))]
+    assert sizes and sum(sizes) < 16 << 20, sizes
     with pytest.raises(Exception, match="aligned to tiling"):
         narrow = list(operands)
         narrow[0], narrow[1] = ((slots, 1, heads, 576), jnp.bfloat16), ((layers, blocks, BLOCK_SIZE, 576), jnp.bfloat16)
