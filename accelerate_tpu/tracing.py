@@ -51,10 +51,24 @@ what it was: the identifier the spans of one request share.
 Thread-safety: each ring is appended only by its owner thread (no lock
 on the hot path; list element writes are atomic under the GIL); dumps
 copy each ring before serializing.
+
+The host runtime beneath the program: two process-wide hooks, installed
+once (:func:`_install_hooks`), feed the default tracer what the program
+cannot open a span for. A ``gc.callbacks`` entry makes every collection
+of generation 1 or 2 a ``python.gc`` span and counts generation 0;
+``jax.monitoring`` listeners make each phase of JAX's pipeline a
+``jax.trace``, ``jax.lower`` or ``jax.compile`` span, opened when the
+phase begins and closed when it ends. Neither is pushed onto its
+thread's stack. Both keep running totals the rings cannot drop
+(:meth:`Tracer.totals`). A collection can start inside any allocation,
+the tracer's own locked sections included, so the collector's hook takes
+no lock that the code it interrupted may hold: those it can reach are
+``RLock``s.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -94,11 +108,26 @@ SESSION_CAPACITY = 65536
 # jax.profiler.TraceAnnotation once resolved, False where this jax has none
 _ANNOTATION: Any = None
 
+# what the host-runtime hooks count whatever the rings and the session keep:
+# every collection by generation, and the outermost span of each JAX phase
+TOTALS = ("python.gc.gen0", "python.gc.gen1", "python.gc.gen2",
+          "jax.trace", "jax.lower", "jax.compile")
+_GC_TOTALS = TOTALS[:3]
+# the events JAX 0.9 reports at both ends of a phase (dispatch.log_elapsed_time)
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_JAX_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+              "/jax/compilation_cache/cache_misses": "miss"}
+
 
 def _annotation():
     """``jax.profiler.TraceAnnotation``, resolved once, or None. A process
     that has not imported jax has no profiler session to join, and a span
-    must never be what imports it (fleet parents stay off JAX)."""
+    must never be what imports it (fleet parents stay off JAX). Resolving
+    it is also when JAX's listeners are installed."""
     global _ANNOTATION
     ann = _ANNOTATION
     if ann is None:
@@ -111,6 +140,7 @@ def _annotation():
         except Exception:  # noqa: BLE001 — no profiler must not break span()
             ann = False
         _ANNOTATION = ann
+        _hook_jax()
     return ann or None
 
 
@@ -193,6 +223,8 @@ class _SpanCM:
     added cost is that one call."""
 
     __slots__ = ("_tracer", "_span", "_stack", "_annotation", "_session")
+    _push = True  # what opens inside this span finds it as its parent
+    _bridge = staticmethod(_annotation)
 
     def __init__(self, tracer: "Tracer", span_obj: Span):
         self._tracer = tracer
@@ -209,10 +241,11 @@ class _SpanCM:
         if stack is None:
             stack = local.stack = []
         self._stack = stack
-        if stack:
+        if stack and not sp.parent:
             sp.parent = stack[-1]
-        stack.append(sp.id)
-        ann = _annotation()
+        if self._push:
+            stack.append(sp.id)
+        ann = self._bridge()
         if ann is not None and ann.is_enabled():
             sp.profiled = True
             self._session = tracer._enter_session()
@@ -253,6 +286,20 @@ class _SpanCM:
         return False
 
 
+class _HookSpanCM(_SpanCM):
+    """A span the host runtime opens (the collector, a phase of JAX's
+    pipeline): left off its thread's stack, so that it parents nothing the
+    interrupted code opens, and never the one to resolve jax, since a
+    collection may start inside an import."""
+
+    __slots__ = ()
+    _push = False
+
+    @staticmethod
+    def _bridge():
+        return _ANNOTATION or None
+
+
 class _Ring:
     """Bounded per-thread span buffer: drop-oldest, drops counted."""
 
@@ -284,17 +331,23 @@ class Tracer:
         self._config = config if config is not None else TracingConfig()
         self._local = threading.local()
         self._rings: List[_Ring] = []
-        self._rings_lock = threading.Lock()
+        # RLocks: the collector's hook may run inside a section that holds one
+        self._rings_lock = threading.RLock()
         self._dump_lock = threading.Lock()
         self._dump_count = 0
         self._epoch_monotonic = time.monotonic()
         self._epoch_unix = time.time()
         # the profiled spans of the newest profiler session, whole
-        self._session_lock = threading.Lock()
+        self._session_lock = threading.RLock()
         self._session_spans: List[Span] = []
         self._session_dropped = 0
         self._session_seen = 0  # sessions seen so far; 0: none yet
         self._in_session = False
+        # (count, seconds, max_s) by name; a key is never added, so a copy
+        # taken while a hook writes cannot fail
+        self._totals: Dict[str, tuple] = dict.fromkeys(TOTALS, (0, 0.0, 0.0))
+        self._totals_at_session: Optional[Dict[str, tuple]] = None
+        self._totals_lock = threading.Lock()  # JAX's phases, never the collector
 
     # -- introspection
     @property
@@ -346,6 +399,7 @@ class Tracer:
                     self._session_seen += 1
                     self._session_spans = []
                     self._session_dropped = 0
+                    self._totals_at_session = dict(self._totals)
                     self._in_session = True
         return self._session_seen
 
@@ -361,7 +415,61 @@ class Tracer:
             else:
                 self._session_dropped += 1
 
+    # -- the host runtime (what the process-wide hooks hand the default tracer)
+    def _add_total(self, name: str, seconds: float) -> None:
+        count, total, longest = self._totals[name]
+        self._totals[name] = (count + 1, total + seconds, max(longest, seconds))
+
+    def _jax_begin(self, name: str, fun: Optional[str]) -> None:
+        opened = getattr(self._local, "jax", None)
+        if opened is None:
+            opened = self._local.jax = []
+        sp = Span(name, None, {} if fun is None else {"fun": fun})
+        if opened:  # a phase inside another: an inner jit traced by an outer one
+            sp.parent = opened[-1][1]._span.id
+        cm = _HookSpanCM(self, sp)
+        cm.__enter__()
+        opened.append((name, cm, not opened))
+
+    def _jax_end(self, name: str) -> None:
+        """Closes this thread's newest open span of phase ``name``, and any
+        begun inside it whose end never came; an end with no begin is
+        dropped. Only an outermost span counts toward the totals."""
+        opened = getattr(self._local, "jax", None) or []
+        for i in range(len(opened) - 1, -1, -1):
+            if opened[i][0] == name:
+                break
+        else:
+            return
+        ended = opened[i:]
+        del opened[i:]
+        for _, cm, _ in reversed(ended):
+            cm.__exit__(None, None, None)
+        _, cm, outermost = ended[0]
+        if outermost:
+            with self._totals_lock:
+                self._add_total(name, cm._span.duration_s)
+
+    def _jax_cache(self, outcome: str) -> None:
+        for name, cm, _ in reversed(getattr(self._local, "jax", None) or []):
+            if name == "jax.compile":
+                cm._span.set("cache", outcome)
+                return
+
     # -- reading (tests, dumps)
+    def totals(self, at_session_start: bool = False) -> Optional[Dict[str, Dict[str, float]]]:
+        """``{name: {"count", "seconds", "max_s"}}`` for each of
+        :data:`TOTALS`: every collection by generation since this tracer was
+        built, and every outermost span of a JAX phase (one with no
+        ``jax.*`` span open beneath it on its thread). With
+        ``at_session_start`` the same as they stood when the first span of
+        the newest profiler session opened, None before any session."""
+        held = self._totals_at_session if at_session_start else dict(self._totals)
+        if held is None:
+            return None
+        return {name: {"count": count, "seconds": seconds, "max_s": longest}
+                for name, (count, seconds, longest) in held.items()}
+
     def spans(self, trace_id: Optional[str] = None,
               name: Optional[str] = None) -> List[Span]:
         """Snapshot of recorded spans across every thread's ring,
@@ -536,6 +644,7 @@ def get_tracer() -> Tracer:
             if _DEFAULT is None:
                 _DEFAULT = Tracer(_env_config())
             tracer = _DEFAULT
+        _install_hooks()
     return tracer
 
 
@@ -544,7 +653,121 @@ def configure(config: TracingConfig) -> Tracer:
     global _DEFAULT
     with _DEFAULT_LOCK:
         _DEFAULT = Tracer(config)
-        return _DEFAULT
+        tracer = _DEFAULT
+    _install_hooks()
+    return tracer
+
+
+# ---------------------------------------------------- the host-runtime hooks
+# Installed once a process (JAX's listeners cannot be taken off, gc.callbacks
+# is global), each dispatches to the default tracer of the moment.
+_GC_HOOKED = False
+_JAX_HOOKED = False
+_HOOKS_LOCK = threading.Lock()
+# the collection in progress, (tracer, generation, t0, span or None): the
+# interpreter runs one collection at a time
+_GC_OPEN: Optional[tuple] = None
+_GC_NESTED = 0  # collections begun inside another's callback, ignored
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` entry: a ``python.gc`` span for a collection of
+    generation 1 or 2, the totals for every one. Takes no lock that the
+    code it interrupted may hold, and never builds the default tracer."""
+    global _GC_OPEN, _GC_NESTED
+    if phase == "start":
+        if _GC_OPEN is not None:
+            _GC_NESTED += 1
+            return
+        tracer = _DEFAULT
+        if tracer is None or not tracer._config.enabled:
+            return
+        generation = info["generation"]
+        cm = None
+        if generation:  # generation 0 runs too often to span: counted only
+            cm = _HookSpanCM(tracer, Span("python.gc", None, {"generation": generation}))
+            cm.__enter__()
+        _GC_OPEN = (tracer, generation, time.monotonic(), cm)
+        return
+    if _GC_NESTED:
+        _GC_NESTED -= 1
+        return
+    opened = _GC_OPEN
+    if opened is None:
+        return
+    _GC_OPEN = None
+    tracer, generation, t0, cm = opened
+    seconds = time.monotonic() - t0
+    if cm is not None:
+        cm._span.set("collected", info["collected"])
+        cm._span.set("uncollectable", info["uncollectable"])
+        cm.__exit__(None, None, None)
+    tracer._add_total(_GC_TOTALS[generation], seconds)
+
+
+def _on_jax_begin(event: str, value: float, **kwargs: Any) -> None:
+    """``jax.monitoring`` scalar listener: a phase of JAX's pipeline begins."""
+    name = _JAX_PHASES.get(event)
+    if name is not None:
+        tracer = get_tracer()
+        if tracer._config.enabled:
+            tracer._jax_begin(name, kwargs.get("fun_name"))
+
+
+def _on_jax_end(event: str, start: float, end: float, **kwargs: Any) -> None:
+    """``jax.monitoring`` time-span listener: the phase ends."""
+    name = _JAX_PHASES.get(event)
+    if name is not None:
+        tracer = get_tracer()
+        if tracer._config.enabled:
+            tracer._jax_end(name)
+
+
+def _on_jax_event(event: str, **kwargs: Any) -> None:
+    """``jax.monitoring`` event listener: the persistent cache's verdict on
+    the compile in progress."""
+    outcome = _JAX_CACHE.get(event)
+    if outcome is not None:
+        tracer = get_tracer()
+        if tracer._config.enabled:
+            tracer._jax_cache(outcome)
+
+
+def _hook_jax() -> None:
+    """Registers the three JAX listeners, once a process; a jax that does
+    not report both ends of a phase gets none."""
+    global _JAX_HOOKED
+    with _HOOKS_LOCK:
+        if _JAX_HOOKED:
+            return
+        _JAX_HOOKED = True
+        try:
+            from jax import monitoring
+        except ImportError:
+            return
+        registers = [getattr(monitoring, f"register_{kind}_listener", None)
+                     for kind in ("scalar", "event_time_span", "event")]
+        if all(registers):
+            for register, listener in zip(registers, (_on_jax_begin, _on_jax_end, _on_jax_event)):
+                register(listener)
+
+
+def _install_hooks() -> None:
+    """The collector's hook now, JAX's where jax is imported (else when a
+    span first finds it, :func:`_annotation`). Called when this module is
+    imported, so that a process's first compiles are counted, and again
+    whenever a default tracer is built."""
+    global _GC_HOOKED
+    with _HOOKS_LOCK:
+        if not _GC_HOOKED:
+            _GC_HOOKED = True
+            gc.callbacks.append(_on_gc)
+    if "jax" in sys.modules:
+        _annotation()
+        _hook_jax()
+
+
+_install_hooks()
 
 
 def span(name: str, trace_id: Optional[str] = None, **attrs: Any):

@@ -15,6 +15,12 @@
   attributes, id and parent; the session's spans are kept whole; with no
   session no annotation is made; every step leaves a span (no sampling);
   a continuous server's tick nests dispatch, readback and reply;
+* the host runtime — a collection of generation 1 or 2 is a ``python.gc``
+  span in the host plane, generation 0 only counted; a fresh ``jit``
+  leaves ``jax.trace``, ``jax.lower``, ``jax.compile`` and a nested one
+  counts once in ``totals()``; the hooks are installed once a process,
+  record nothing from a disabled tracer and cannot deadlock on the
+  tracer's own locks;
 * latency surface — ``ServingResult`` reports queue_wait_s / prefill_s /
   decode_steps for every completed request;
 * MetricsRegistry — the unified counters/gauges/reservoir surface and
@@ -22,11 +28,13 @@
 """
 
 import contextlib
+import gc
 import glob
 import json
 import os
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -377,14 +385,24 @@ def host_events(directory):
     return out
 
 
+RUNTIME_SPANS = ("python.gc", "jax.trace", "jax.lower", "jax.compile")
+
+
+def own(spans):
+    """The program's own spans: the collector may run inside any test."""
+    return [sp for sp in spans if sp.name not in RUNTIME_SPANS]
+
+
 class _FakeAnnotation:
-    """Stands in for ``jax.profiler.TraceAnnotation``: counts what is made."""
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts what the
+    program's own spans make."""
 
     active = False
     made = 0
 
     def __init__(self, name, **stats):
-        type(self).made += 1
+        if name not in RUNTIME_SPANS:
+            type(self).made += 1
 
     @classmethod
     def is_enabled(cls):
@@ -463,41 +481,55 @@ def test_session_spans_are_the_sessions_own(tracer, tmp_path):
                     pass
     with tracing.span("between"):
         pass
-    first = tracer.session_spans()
+    first = own(tracer.session_spans())
     assert [sp.name for sp in first] == ["inside"] * 3
     assert [sp.attrs["i"] for sp in first] == [0, 1, 2]  # oldest first
     assert all(sp.profiled for sp in first)
-    assert not any(sp.profiled for sp in tracer.spans() if sp.name != "inside")
+    assert not any(sp.profiled for sp in own(tracer.spans()) if sp.name != "inside")
     assert tracer.session_spans(name="nothing") == []
     # kept after the session, whatever runs afterwards
     for _ in range(20):
         with tracing.span("after"):
             pass
-    assert len(tracer.session_spans()) == 3
+    assert len(own(tracer.session_spans())) == 3
     # the first span of a later session empties the list
     with profiler_session(tmp_path / "two"):
         with tracing.span("later"):
             pass
-    assert [sp.name for sp in tracer.session_spans()] == ["later"]
+    assert [sp.name for sp in own(tracer.session_spans())] == ["later"]
     assert tracer.session_dropped == 0
+
+
+@contextlib.contextmanager
+def collector_paused():
+    """No collection but an explicit one: for a count that a ``python.gc``
+    span must not take a place in. One already due runs as ``disable``
+    returns, before the body."""
+    gc.disable()
+    try:
+        yield
+    finally:
+        gc.enable()
 
 
 def test_session_store_counts_what_it_drops(tracer, fake_annotation, monkeypatch):
     monkeypatch.setattr(tracing, "SESSION_CAPACITY", 8)
-    fake_annotation.active = True
-    for i in range(11):
-        with tracing.span("s", i=i):
-            pass
-    kept = tracer.session_spans()
+    with collector_paused():
+        fake_annotation.active = True
+        for i in range(11):
+            with tracing.span("s", i=i):
+                pass
+        kept = tracer.session_spans()
+        dropped = tracer.session_dropped
     assert [sp.attrs["i"] for sp in kept] == list(range(8))
-    assert tracer.session_dropped == 3
+    assert dropped == 3
     fake_annotation.active = False
     with tracing.span("outside"):
         pass
     fake_annotation.active = True
     with tracing.span("next"):
         pass
-    assert [sp.name for sp in tracer.session_spans()] == ["next"]
+    assert [sp.name for sp in own(tracer.session_spans())] == ["next"]
     assert tracer.session_dropped == 0  # a new session counts anew
 
 
@@ -509,7 +541,7 @@ def test_annotations_are_made_only_inside_a_session(tracer, fake_annotation, ses
             pass
     assert fake_annotation.made == (5 if session else 0)
     assert sp.profiled is session
-    assert len(tracer.session_spans()) == (5 if session else 0)
+    assert len(own(tracer.session_spans())) == (5 if session else 0)
 
 
 def test_a_profiler_that_fails_does_not_break_span(tracer, monkeypatch):
@@ -534,6 +566,218 @@ def test_disabled_tracer_is_shared_noop_inside_a_session(fake_annotation):
     with t.span("a"):
         pass
     assert fake_annotation.made == 0 and t.session_spans() == []
+
+
+# ------------------------------------------------------ the host runtime
+JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+JAX_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def test_a_collection_in_a_profiler_session_is_in_the_host_plane(tracer, tmp_path):
+    """A collection of generation 1 is a ``python.gc`` annotation on the
+    line of the thread it stopped, with the ids ``chipbench.hostspans``
+    takes a span by: the innermost span open there, so it names a gap."""
+    from chipbench import hostspans
+
+    with profiler_session(tmp_path):
+        with tracing.span("serving.tick") as tick:
+            gc.collect(1)
+    mine = [sp for sp in tracer.session_spans("python.gc") if sp.parent == tick.id
+            and sp.attrs["generation"] == 1 and tick.t0 <= sp.t0 <= sp.t1 <= tick.t1][-1]
+    assert {"collected", "uncollectable"} <= set(mine.attrs)
+    found = {s.stats["span"]: s for s in hostspans.read(str(tmp_path))}
+    event, outer = found[mine.id], found[tick.id]
+    assert event.name == "python.gc" and event.stats["generation"] == 1
+    assert event.stats["parent"] == tick.id and event.thread == outer.thread
+    assert outer.start_ns <= event.start_ns and event.end_ns <= outer.end_ns
+    middle = (event.start_ns + event.end_ns) / 2
+    assert hostspans.name_gaps([(1e-3, middle)], list(found.values())) == [["python.gc", 1e-3]]
+
+
+def test_a_fresh_jit_leaves_trace_lower_and_compile_and_a_nested_one_counts_once(tracer):
+    import jax
+    import jax.numpy as jnp
+
+    def inner_fn(x):
+        return x * 3
+
+    inner = jax.jit(inner_fn)
+
+    @jax.jit
+    def outer_fn(x):
+        return inner(x) + 1
+
+    x = jnp.arange(4.0)
+    before = tracer.totals()
+    outer_fn(x)
+    after = tracer.totals()
+    phases = [sp for sp in tracer.spans() if sp.name.startswith("jax.")
+              and "outer_fn" in sp.attrs.get("fun", "")]
+    assert [sp.name for sp in phases] == ["jax.trace", "jax.lower", "jax.compile"]
+    assert phases[0].t1 <= phases[1].t0 and phases[1].t1 <= phases[2].t0
+    (nested,) = [sp for sp in tracer.spans(name="jax.trace") if sp.attrs.get("fun") == "inner_fn"]
+    assert nested.parent == phases[0].id and phases[0].t0 <= nested.t0 <= nested.t1 <= phases[0].t1
+    for sp in phases:  # the nested trace is inside the outer one: counted once
+        assert after[sp.name]["count"] == before[sp.name]["count"] + 1
+        assert after[sp.name]["seconds"] - before[sp.name]["seconds"] == pytest.approx(sp.duration_s)
+    with tracing.span("after") as later:  # the phases left the thread's stack as it was
+        pass
+    assert later.parent == 0
+
+
+def test_a_compile_carries_the_caches_verdict_and_unmatched_ends_are_dropped(tracer):
+    tracing._on_jax_end(JAX_COMPILE, 0.0, 0.0, fun_name="f")  # no begin: dropped
+    tracing._on_jax_begin(JAX_COMPILE, 0.0, fun_name="f")
+    tracing._on_jax_event("/jax/compilation_cache/cache_hits")
+    tracing._on_jax_begin(JAX_TRACE, 0.0, fun_name="g")  # its end never comes
+    tracing._on_jax_end(JAX_COMPILE, 0.0, 0.0, fun_name="f")
+    tracing._on_jax_end(JAX_TRACE, 0.0, 0.0, fun_name="g")  # too late: dropped
+    (compiled,) = tracer.spans(name="jax.compile")
+    (traced,) = tracer.spans(name="jax.trace")
+    assert compiled.attrs == {"fun": "f", "cache": "hit"}
+    assert traced.parent == compiled.id  # closed with the phase it began in
+    totals = tracer.totals()
+    assert totals["jax.compile"]["count"] == 1 and totals["jax.trace"]["count"] == 0
+    with tracing.span("after") as later:
+        pass
+    assert later.parent == 0
+
+
+def test_a_collection_inside_the_tracers_own_locks_does_not_deadlock(tracer, fake_annotation):
+    """The collector may start inside any allocation, the tracer's locked
+    sections included; the hook's span (the first of a session, on a thread
+    with no ring yet) must get through with the lock held on its thread."""
+    fake_annotation.active = True
+    locks = {name: lock for name, lock in vars(tracer).items() if hasattr(lock, "acquire")}
+    locks.update(_DEFAULT_LOCK=tracing._DEFAULT_LOCK, _HOOKS_LOCK=tracing._HOOKS_LOCK)
+    assert {"_session_lock", "_rings_lock", "_totals_lock", "_dump_lock"} <= set(locks)
+    for name, lock in locks.items():
+        tracer._in_session = False  # the next span opens a session
+        done, threads = threading.Event(), []
+
+        def collect_holding_the_lock():
+            threads.append(threading.get_ident())
+            with lock:
+                gc.collect(1)
+                gc.collect(2)
+            done.set()
+
+        threading.Thread(target=collect_holding_the_lock, daemon=True).start()
+        assert done.wait(timeout=30), f"a collection deadlocked under {name}"
+        spanned = [sp.attrs["generation"] for sp in tracer.session_spans("python.gc")
+                   if sp.tid == threads[0]]
+        assert 1 in spanned and 2 in spanned, name
+
+
+def test_totals_lose_no_update_under_many_threads(tracer):
+    """Sixteen threads collect and run JAX phases at once with a short switch
+    interval: every collection and every outermost phase is counted once."""
+    import itertools
+    import sys
+
+    collections = itertools.count()
+
+    def witness(phase, info):
+        if phase == "stop":
+            next(collections)
+
+    def work():
+        for _ in range(50):
+            gc.collect(1)
+            tracing._on_jax_begin(JAX_COMPILE, 0.0, fun_name="f")
+            tracing._on_jax_end(JAX_COMPILE, 0.0, 0.0, fun_name="f")
+
+    interval = sys.getswitchinterval()
+    gc.callbacks.append(witness)
+    sys.setswitchinterval(1e-6)
+    try:
+        before = tracer.totals()
+        threads = [threading.Thread(target=work) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        after = tracer.totals()
+    finally:
+        sys.setswitchinterval(interval)
+        gc.callbacks.remove(witness)
+    assert not any(t.is_alive() for t in threads)
+    counted = sum(after[g]["count"] - before[g]["count"] for g in tracing.TOTALS[:3])
+    assert counted == next(collections)
+    assert after["jax.compile"]["count"] - before["jax.compile"]["count"] == 16 * 50
+
+
+def test_the_hooks_are_installed_once_a_process(tmp_path):
+    from jax._src import monitoring
+
+    previous = tracing.get_tracer().config
+    try:
+        for _ in range(3):
+            tracing.configure(TracingConfig(dump_dir=str(tmp_path)))
+        Tracer(TracingConfig())
+        tracing._install_hooks()
+    finally:
+        tracing.configure(previous)
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    assert monitoring.get_scalar_listeners().count(tracing._on_jax_begin) == 1
+    assert monitoring.get_event_time_span_listeners().count(tracing._on_jax_end) == 1
+    assert monitoring.get_event_listeners().count(tracing._on_jax_event) == 1
+
+
+def test_a_disabled_tracer_records_nothing_from_either_hook(monkeypatch):
+    """Each hook is an attribute check and a return: no clock is read."""
+    import jax
+    import jax.numpy as jnp
+
+    reads = []
+    previous = tracing.get_tracer().config
+    disabled = tracing.configure(TracingConfig(enabled=False))
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        monotonic=lambda: reads.append(1) or 0.0, time=time.time))
+    try:
+        gc.collect(0)
+        gc.collect(1)
+        jax.jit(lambda v: v - 7)(jnp.ones(2))
+    finally:
+        monkeypatch.undo()
+        tracing.configure(previous)
+    assert reads == []
+    assert disabled.spans() == [] and disabled.totals(at_session_start=True) is None
+    assert all(t["count"] == 0 for t in disabled.totals().values())
+
+
+def test_generation_zero_is_counted_and_never_spanned(tracer, fake_annotation):
+    fake_annotation.active = True
+    before = tracer.totals()
+    for _ in range(5):
+        gc.collect(0)
+    gc.collect(1)
+    after = tracer.totals()
+    assert after["python.gc.gen0"]["count"] >= before["python.gc.gen0"]["count"] + 5
+    assert after["python.gc.gen0"]["seconds"] > before["python.gc.gen0"]["seconds"]
+    assert after["python.gc.gen1"]["count"] >= before["python.gc.gen1"]["count"] + 1
+    assert 0 < after["python.gc.gen1"]["max_s"] <= after["python.gc.gen1"]["seconds"]
+    spanned = [sp.attrs["generation"] for sp in tracer.spans(name="python.gc")]
+    assert 1 in spanned and 0 not in spanned
+    assert all(sp.attrs["generation"] for sp in tracer.session_spans("python.gc"))
+
+
+def test_totals_at_session_start_are_those_the_sessions_first_span_found(tracer, fake_annotation):
+    import jax
+    import jax.numpy as jnp
+
+    assert tracer.totals(at_session_start=True) is None  # no session yet
+    x = jnp.ones(3)
+    jax.jit(lambda v: v * 5)(x)  # built before the session
+    before = tracer.totals()
+    fake_annotation.active = True
+    with tracing.span("first"):
+        pass
+    jax.jit(lambda v: v * 6)(x)  # built inside it
+    at_start, now = tracer.totals(at_session_start=True), tracer.totals()
+    for phase in ("jax.trace", "jax.lower", "jax.compile"):
+        assert at_start[phase] == before[phase]
+        assert now[phase]["count"] == at_start[phase]["count"] + 1
 
 
 def test_continuous_server_tick_nests_dispatch_readback_and_reply(tracer):
