@@ -20,10 +20,12 @@ every row hit EOS at step 3. This module removes all three costs at once:
   program: sampling params are per-row traced operands, not compile keys,
   so the seed and ``max_new_tokens`` group-key fragmentation of the static
   path disappears entirely.
-* **Exactly two jitted programs** per (slots, max_len) configuration:
+* **Two jitted program families** per (slots, max_len) configuration:
   ``prefill_insert`` (bucketed prompt forward via the models'
   ``*_prefill_at``, then scatter its KV rows into a free arena slot with
-  ``lax.dynamic_update_slice``) and ``decode_step`` (one fused step over
+  ``lax.dynamic_update_slice``; one program a rung of
+  :func:`prefill_rungs`: the bucket, and half of it where that is at least
+  128 positions of whole blocks) and ``decode_step`` (one fused step over
   ALL slots — finished/vacant slots ride along masked). The KV arena and
   per-slot position/PRNG state are donated across calls, so steady-state
   decode performs zero reallocation of the arena. Speculative decoding
@@ -84,7 +86,21 @@ from .utils.fault import (
 
 logger = get_logger(__name__)
 
-__all__ = ["ContinuousBatchingEngine", "SlotOccupant", "RemotePrefill"]
+__all__ = ["ContinuousBatchingEngine", "SlotOccupant", "RemotePrefill", "prefill_rungs"]
+
+# the MXU's row tile: a prefill of fewer rows is bound by its weights' bytes,
+# so a narrower program than this buys nothing
+_MIN_RUNG = 128
+
+
+def prefill_rungs(prompt_bucket: int, block_size: int) -> Tuple[int, ...]:
+    """The widths a prompt is padded to for its prefill, ascending: the
+    bucket, and half of it where that half is at least :data:`_MIN_RUNG`
+    positions and whole blocks of ``block_size``."""
+    half = prompt_bucket // 2
+    if half >= _MIN_RUNG and half % block_size == 0:
+        return (half, prompt_bucket)
+    return (prompt_bucket,)
 
 
 # ------------------------------------------------------------------ occupants
@@ -363,6 +379,10 @@ class ContinuousBatchingEngine:
                 f"prompt_bucket must be in [1, max_len-1], got "
                 f"{self.prompt_bucket} (max_len={max_len})"
             )
+        # a prompt's prefill is padded to the smallest rung that holds it
+        # (_padded_prompt); the bucket stays the top rung and every limit
+        self.rungs = prefill_rungs(self.prompt_bucket, block_size)
+        self._rung_dispatches = dict.fromkeys(self.rungs, 0)
         # chunked prefill (docs/serving.md "Long-context serving"): when
         # enabled, prompts LONGER than the bucket are admitted and fed one
         # `prefill_chunk`-wide chunk per scheduler tick through the
@@ -446,7 +466,7 @@ class ContinuousBatchingEngine:
         # arena program. Neither compiles unless prefill_remote is used.
         self._prefill_fwd_jit = jax.jit(self._prefill_forward_impl)
         self._prefill_commit_jit = jax.jit(
-            self._prefill_commit_impl, donate_argnums=(0,)
+            self._prefill_commit_impl, donate_argnums=(0,), static_argnames=("width",)
         )
         # chunked-prefill members of the prefill_insert program family:
         # `_chunk_jit` runs one prompt chunk as a verify-style window
@@ -490,8 +510,8 @@ class ContinuousBatchingEngine:
         self.steps = 0
         self.retired = 0
         # distinct (program, operand-shape) signatures actually dispatched —
-        # the "<= 2 compiled programs" acceptance stat (one prompt bucket →
-        # one prefill signature + one decode signature)
+        # the compiled-programs acceptance stat (one prefill signature a
+        # rung dispatched + one decode signature)
         self._programs: dict[str, set] = {}
         # perf observatory (docs/observability.md): wall time is only read
         # at poll() — the deferred-readback ring's synchronizing point —
@@ -697,12 +717,13 @@ class ContinuousBatchingEngine:
         self, donated, carried, params, prompt, length, slot, key_data,
         temp, top_k, top_p, eos, pad, budget, table_row,
     ):
-        # bucketed prompt forward; logits at the last REAL position. Dense:
-        # the returned max_len-wide cache (zeros beyond the bucket) scatters
-        # over the full slot row, wiping every stale byte of the previous
-        # occupant. Paged: per-block dynamic_update_slice writes into the
-        # slot's table-row blocks (recycled blocks rely on the write-before-
-        # attend invariant instead of a wipe — kvcache.py docstring).
+        # prompt forward over its rung (one program a rung: the prompt's
+        # width); logits at the last REAL position. Dense: the returned
+        # max_len-wide cache (zeros beyond the rung) scatters over the full
+        # slot row, wiping every stale byte of the previous occupant. Paged:
+        # the rung's blocks of the table row take one scatter (recycled
+        # blocks rely on the write-before-attend invariant instead of a
+        # wipe — kvcache.py docstring).
         logits, new_cache, *counters = self._family.prefill_at(
             self.config, params, prompt, self.max_len, (length - 1)[None]
         )
@@ -712,7 +733,7 @@ class ContinuousBatchingEngine:
         budget_left = budget - 1
         done0 = hit_eos | (budget_left <= 0)
         cache = self._backend.prefill_write(
-            donated["cache"], new_cache, slot, table_row
+            donated["cache"], new_cache, slot, table_row, prompt.shape[1]
         )
         new_donated = {
             "cache": cache,
@@ -748,17 +769,18 @@ class ContinuousBatchingEngine:
 
     def _prefill_commit_impl(
         self, donated, carried, new_cache, t0, next_key, slot, length,
-        temp, top_k, top_p, eos, pad, budget, table_row,
+        temp, top_k, top_p, eos, pad, budget, table_row, *, width,
     ):
         # the arena half of _prefill_impl: scatter the precomputed KV
-        # window and install the slot's carried state. done0 is recomputed
-        # here (not in the forward) so a degradation-clamped budget at
-        # commit time behaves exactly like a plain insert with that budget.
+        # window (its forward ran over the rung ``width``) and install the
+        # slot's carried state. done0 is recomputed here (not in the
+        # forward) so a degradation-clamped budget at commit time behaves
+        # exactly like a plain insert with that budget.
         hit_eos = (eos >= 0) & (t0 == eos)
         budget_left = budget - 1
         done0 = hit_eos | (budget_left <= 0)
         cache = self._backend.prefill_write(
-            donated["cache"], new_cache, slot, table_row
+            donated["cache"], new_cache, slot, table_row, width
         )
         new_donated = {
             "cache": cache,
@@ -873,6 +895,19 @@ class ContinuousBatchingEngine:
 
     def _record(self, name: str, sig: tuple) -> None:
         self._programs.setdefault(name, set()).add(sig)
+
+    def _rung(self, prompt_len: int) -> int:
+        """The width a prompt of ``prompt_len`` tokens is padded to for its
+        prefill: the smallest rung that holds it. The one choice of rung,
+        made alike by :meth:`insert`, :meth:`prefill_remote` and
+        :meth:`insert_prefilled`, so the disaggregated path runs the
+        programs a plain insert runs."""
+        return next(r for r in self.rungs if r >= prompt_len)
+
+    def _padded_prompt(self, prompt: np.ndarray) -> np.ndarray:
+        padded = np.zeros((1, self._rung(len(prompt))), np.int32)
+        padded[0, : len(prompt)] = prompt
+        return padded
 
     # -------------------------------------------------------------- host API
     def free_slots(self) -> int:
@@ -1041,7 +1076,8 @@ class ContinuousBatchingEngine:
         seed: int = 0,
         tag: Any = None,
     ) -> SlotOccupant:
-        """Admit one request into a free slot: bucketed prefill, KV scatter,
+        """Admit one request into a free slot: a prefill padded to the
+        smallest rung that holds the prompt (:meth:`_rung`), KV scatter,
         first token sampled inside the same program. Prompts longer than
         the bucket (chunked prefill enabled) take the chunked path: the
         first chunk dispatches here, the rest interleave one per
@@ -1060,24 +1096,25 @@ class ContinuousBatchingEngine:
             # paged: allocate/COW-share the request's blocks and install the
             # slot's table row; raises RuntimeError when the pool is out of
             # blocks (callers gate on can_admit()). Dense: a no-op row.
-            table_row, _shared = self._backend.acquire(slot, prompt, max_new_tokens)
+            table_row, shared = self._backend.acquire(slot, prompt, max_new_tokens)
         except BaseException:
             self._return_slot(slot)
             raise
-        padded = np.zeros((1, self.prompt_bucket), np.int32)
-        padded[0, : len(prompt)] = prompt
+        padded = self._padded_prompt(prompt)
+        rung = padded.shape[1]
         pad_id = (
             pad_token_id if pad_token_id is not None
             else (eos_token_id if eos_token_id is not None else 0)
         )
         kd = jax.random.key_data(jax.random.key(seed))
         trace_id = getattr(tag, "trace_id", None)
-        self._record("prefill_insert", (self.prompt_bucket,))
+        self._record("prefill_insert", (rung,))
+        self._rung_dispatches[rung] += 1
         # host-side dispatch span: the jitted body never sees the tracer
         # (G107) — this times the interleaved prefill on the decode thread
         with tracing.span(
             "engine.prefill", trace_id=trace_id,
-            slot=slot, prompt_len=len(prompt), bucket=self.prompt_bucket,
+            slot=slot, prompt_len=len(prompt), bucket=rung,
         ):
             self._donated, self._carried, t0, d0, counters = self._prefill_jit(
                 self._donated, self._carried, self.model.params,
@@ -1087,7 +1124,7 @@ class ContinuousBatchingEngine:
                 jnp.float32(top_p if top_p is not None else 1.0),
                 jnp.int32(eos_token_id if eos_token_id is not None else -1),
                 jnp.int32(pad_id), jnp.int32(max_new_tokens),
-                jnp.asarray(table_row),
+                jnp.asarray(self._backend.prefill_row(table_row, shared)),
             )
         occ = SlotOccupant(
             slot=slot, tag=tag, prompt=prompt, budget=max_new_tokens,
@@ -1325,13 +1362,14 @@ class ContinuousBatchingEngine:
                 "prefill_remote cannot disaggregate a chunked (long) prompt; "
                 "admit it via insert() so chunks interleave with decode"
             )
-        padded = np.zeros((1, self.prompt_bucket), np.int32)
-        padded[0, : len(prompt)] = prompt
+        padded = self._padded_prompt(prompt)
+        rung = padded.shape[1]
         kd = jax.random.key_data(jax.random.key(seed))
-        self._record("prefill_forward", (self.prompt_bucket,))
+        self._record("prefill_forward", (rung,))
+        self._rung_dispatches[rung] += 1
         with tracing.span(
             "engine.prefill", trace_id=trace_id,
-            remote=True, prompt_len=len(prompt),
+            remote=True, prompt_len=len(prompt), bucket=rung,
         ):
             new_cache, t0, next_key = self._prefill_fwd_jit(
                 self.model.params, jnp.asarray(padded), jnp.int32(len(prompt)), kd,
@@ -1432,7 +1470,7 @@ class ContinuousBatchingEngine:
         else:
             slot = self._pop_free_slot()
         try:
-            table_row, _shared = self._backend.acquire(slot, prompt, budget)
+            table_row, shared = self._backend.acquire(slot, prompt, budget)
         except BaseException:
             self._return_slot(slot)
             raise
@@ -1441,7 +1479,8 @@ class ContinuousBatchingEngine:
             else (pre.eos_token_id if pre.eos_token_id is not None else 0)
         )
         trace_id = getattr(tag, "trace_id", None)
-        self._record("prefill_commit", ())
+        rung = self._rung(len(prompt))
+        self._record("prefill_commit", (rung,))
         with tracing.span(
             "engine.insert_prefilled", trace_id=trace_id,
             slot=slot, prompt_len=len(prompt),
@@ -1454,7 +1493,7 @@ class ContinuousBatchingEngine:
                 jnp.float32(pre.top_p if pre.top_p is not None else 1.0),
                 jnp.int32(pre.eos_token_id if pre.eos_token_id is not None else -1),
                 jnp.int32(pad_id), jnp.int32(budget),
-                jnp.asarray(table_row),
+                jnp.asarray(self._backend.prefill_row(table_row, shared)), width=rung,
             )
         occ = SlotOccupant(
             slot=slot, tag=tag, prompt=prompt, budget=budget,
@@ -1965,8 +2004,10 @@ class ContinuousBatchingEngine:
     def stats(self) -> dict:
         """Observability twin of ``generate_cache_stats``: how many distinct
         (program, operand-shape) signatures this engine dispatched — the
-        acceptance gate asserts <= 2 per (slots, max_len) config (<= 3 with
-        speculative decoding's ``verify_step``) — plus lifetime counters,
+        acceptance gate asserts one decode and one prefill a rung per
+        (slots, max_len) config (and one ``verify_step`` with speculative
+        decoding) — plus lifetime counters (``prefill_rungs``: prompt
+        forwards dispatched at each rung, plain and remote alike),
         speculative acceptance accounting (``spec``: drafted/accepted/
         wasted token counters, acceptance EWMA, emitted-tokens-per-verify;
         accepted/wasted lag drafted by up to ``readback_lag`` polls), and
@@ -1996,6 +2037,7 @@ class ContinuousBatchingEngine:
             "free": len(self._free),
             "inserted": self.inserted,
             "remote_prefills": self.remote_prefills,
+            "prefill_rungs": dict(self._rung_dispatches),
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunk_limit": self._prefill_chunk_limit,
             "prefill_chunks": self.prefill_chunks,

@@ -13,10 +13,10 @@ when speculative decoding adds its ``verify_step``):
   host-side block table mapping its logical positions to pool blocks. Decode
   gathers a slot's blocks into the dense per-layer view the model attention
   already consumes (``pool[layer, tables]`` + reshape), writes the new token
-  column back with one scatter, and prefill writes its bucket's blocks with
+  column back with one scatter, and prefill writes its rung's blocks with
   one scatter more. Tables ride into the compiled programs as
   *traced operands* (values change, shapes don't), so a paged engine still
-  dispatches exactly one prefill and one decode program per config.
+  dispatches one decode program and one prefill program a rung per config.
 * **Admission by free blocks, not max_len** — a request needs
   ``ceil((prompt + budget) / block_size)`` blocks, so short requests stop
   paying long requests' reservation. The engine/server gate admission on
@@ -27,9 +27,10 @@ when speculative decoding adds its ``verify_step``):
   instead of new ones (system prompts dedup across every concurrent user).
   Refcounts release on retirement; zero-ref registered blocks park in an
   LRU "cached" tier that still serves hits and is evicted only on demand.
-  Shared-prefix prefill re-writes are bitwise idempotent: causal attention
-  makes prefix KV depend only on prefix tokens, so every sharer computes
-  the same bytes (and, with deterministic quantization, the same int8).
+  A sharer's prefill does not write the shared blocks (their table entries
+  go to the null block for its scatter): causal attention makes prefix KV
+  depend only on prefix tokens, but a sharer padded to another rung runs
+  another program, which may round otherwise.
 * **int8 KV** — pool stored as int8 plus per-(layer, block, position) f32
   scales; quantized on write (prefill blocks and the decode column) and
   dequantized inside the compiled step right before attention. Halves-to-
@@ -88,8 +89,8 @@ whatever the backend. ``prefill_write`` stores what the prefill took at the
 prompt's true last position, the decode step advances it, and ``release``
 clears nothing: the next occupant's prefill overwrites the row before anything
 reads it. A prefix-cache hit shares blocks of keys and values only; the sharer's
-prefill still runs over its whole prompt (shared blocks are re-written with the
-same bytes), so its recurrent state is its own. ``hbm_bytes``, ``live`` and
+prefill still runs over its whole prompt (it leaves the shared blocks as they
+are), so its recurrent state is its own. ``hbm_bytes``, ``live`` and
 ``reserved`` tokens count keys and values; ``stats()["recurrent_state_bytes"]``
 reports the recurrent rows beside them.
 
@@ -890,9 +891,10 @@ class KVCacheBackend:
         """None = the model decode consumes the cache directly (dense)."""
         raise NotImplementedError
 
-    def prefill_write(self, cache, new_cache, slot, table_row):
-        """Scatter a bucketed prefill's KV (``(L, 1, max_len, kvh, hd)``
-        per leaf) into the store for ``slot``/``table_row``."""
+    def prefill_write(self, cache, new_cache, slot, table_row, width: int):
+        """Scatter a prefill's KV (``(L, 1, max_len, kvh, hd)`` per leaf,
+        computed over the first ``width`` positions, the prompt's rung)
+        into the store for ``slot``/``table_row``."""
         raise NotImplementedError
 
     def commit_window(self, cache, window_kv, tables, pos, count):
@@ -910,6 +912,11 @@ class KVCacheBackend:
     def acquire(self, slot: int, prompt: np.ndarray, budget: int,
                 defer_register: bool = False) -> Tuple[np.ndarray, int]:
         raise NotImplementedError
+
+    def prefill_row(self, row: np.ndarray, shared: int) -> np.ndarray:
+        """The table row a prefill writes through, from ``acquire``'s
+        ``(row, shared)``."""
+        return row
 
     def release(self, slot: int) -> None:
         raise NotImplementedError
@@ -985,9 +992,9 @@ class DenseKVBackend(KVCacheBackend):
         return None
 
     @jax.named_scope("kv.scatter")
-    def prefill_write(self, cache, new_cache, slot, table_row):
-        # full-row dynamic_update_slice: zeros beyond the bucket wipe every
-        # stale byte of the slot's previous occupant
+    def prefill_write(self, cache, new_cache, slot, table_row, width):
+        # full-row dynamic_update_slice whatever the rung: zeros beyond it
+        # wipe every stale byte of the slot's previous occupant
         return {
             **{
                 which: lax.dynamic_update_slice(
@@ -1168,16 +1175,16 @@ class PagedKVBackend(KVCacheBackend):
         )
 
     @jax.named_scope("kv.scatter")
-    def prefill_write(self, cache, new_cache, slot, table_row):
-        """One scatter of the bucketed prefill KV into the slot's blocks,
-        every layer at once (``[:, ids]``, as a restore writes them). The
-        bucket's block count is static (``ceil(prompt_bucket /
-        block_size)``), so this stays ONE compiled program; rows whose
-        allocation is shorter than the bucket carry null-block table entries
-        there, harmlessly absorbing the extra writes. Shared (COW) prefix
-        blocks are re-written with bitwise identical content — see the
-        module docstring invariants."""
-        n, bs = self.prefill_blocks, self.block_size
+    def prefill_write(self, cache, new_cache, slot, table_row, width):
+        """One scatter of a prefill's KV into the slot's blocks, every layer
+        at once (``[:, ids]``, as a restore writes them). The block count is
+        the rung's, ``ceil(width / block_size)`` (static: one compiled
+        program a rung), so a prefill at half the bucket writes half the
+        blocks and leaves the row's later blocks as they were; rows whose
+        allocation is shorter than the rung carry null-block table entries
+        there, harmlessly absorbing the extra writes, and so do shared (COW)
+        prefix blocks (:meth:`prefill_row`)."""
+        n, bs = _ceil_div(width, self.block_size), self.block_size
         ids = table_row[:n]
         out = _write_recurrent(cache, new_cache, slot)
         for which in self._leaves:
@@ -1224,6 +1231,15 @@ class PagedKVBackend(KVCacheBackend):
         self._device_tables_cache = None
         self._flush_spills()
         return row, shared
+
+    def prefill_row(self, row, shared):
+        """``row`` with its ``shared`` leading (COW prefix) blocks sent to the
+        null block: their content is what the prefill that registered them
+        wrote, and a sharer's prefill, which may run at another rung and so
+        round otherwise, never writes it again."""
+        row = row.copy()
+        row[:shared] = _NULL_BLOCK
+        return row
 
     def prefix_digest(self, limit: int = 512) -> List[int]:
         """Compact fingerprint of this backend's prefix registry: crc32 of

@@ -584,6 +584,7 @@ def serve_phase(config, serving, requests, *, seed: int = 0,
             (a.tokens == b.tokens).all() for a, b in zip(results, again)
         ),
         "programs": stats["programs"],
+        "prefill_rungs": stats["prefill_rungs"],
         "jit_signatures": {
             "decode": engine._decode_jit._cache_size(),
             "prefill": engine._prefill_jit._cache_size(),
@@ -626,10 +627,11 @@ def serve_phase(config, serving, requests, *, seed: int = 0,
               "serve: token ids outside the vocabulary")
     check(facts["same_tokens_both_rounds"],
           "serve: the same seeded requests gave other tokens the second time")
-    # tests/test_kvcache.py: one prompt bucket gives one prefill and one decode
-    check(facts["programs"] == {"prefill_insert": 1, "decode_step": 1},
+    # tests/test_prefill_rungs.py: one prefill a rung the prompts reached, one decode
+    rungs = sum(1 for n in facts["prefill_rungs"].values() if n)
+    check(facts["programs"] == {"prefill_insert": rungs, "decode_step": 1},
           f"serve: engine dispatched programs {facts['programs']}")
-    check(max(facts["jit_signatures"].values()) == 1,
+    check(facts["jit_signatures"] == {"decode": 1, "prefill": rungs},
           f"serve: engine programs compiled more than once: {facts['jit_signatures']}")
     return facts
 

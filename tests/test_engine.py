@@ -24,6 +24,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from accelerate_tpu import tracing
 from accelerate_tpu.engine import ContinuousBatchingEngine
 from accelerate_tpu.inference import (
     generate,
@@ -213,13 +214,20 @@ def test_mixed_traffic_compiles_exactly_two_programs(get_engine):
 
 
 # ---------------------------------------------------- remote-prefill parity
-def test_remote_prefill_bitwise_parity(model, get_engine):
+@pytest.mark.parametrize("rung,bucket,max_len,lens", [
+    (8, 8, 32, (5, 7)),  # a bucket of 8: one rung
+    (128, 256, 512, (100, 128)),  # the half rung of a bucket of 256
+    (256, 256, 512, (129, 250)),  # the whole bucket
+], ids=["one_rung", "half_rung", "full_rung"])
+def test_remote_prefill_bitwise_parity(model, get_engine, rung, bucket, max_len, lens):
     """prefill_remote + insert_prefilled must be bitwise identical to a
     plain insert — same forward, same sample, same key evolution — for
     greedy AND sampled requests, including a downward budget override at
-    commit time (the disaggregated path docs/serving.md promises)."""
-    eng = get_engine(slots=2, max_len=32, prompt_bucket=8)
-    prompts = _prompts(2, lens=(5, 7), seed=11)
+    commit time (the disaggregated path docs/serving.md promises), on
+    each rung a prompt can take."""
+    eng = get_engine(slots=2, max_len=max_len, prompt_bucket=bucket)
+    prompts = _prompts(2, lens=lens, seed=11)
+    assert [eng._rung(len(p)) for p in prompts] == [rung, rung]
     cases = [
         dict(temperature=0.0),
         dict(temperature=0.8, top_k=40, top_p=0.9, seed=13),
@@ -230,13 +238,17 @@ def test_remote_prefill_bitwise_parity(model, get_engine):
         eng.drain()
         plain.append(occ.output_row())
         eng.reset()
+    trace_id = f"remote-rung-{bucket}-{rung}"
     for p, kw, want in zip(prompts, cases, plain):
-        pre = eng.prefill_remote(p, max_new_tokens=6, pad_token_id=0, **kw)
+        pre = eng.prefill_remote(p, max_new_tokens=6, pad_token_id=0,
+                                 trace_id=trace_id, **kw)
         assert eng.accepts_prefill(pre)
         occ = eng.insert_prefilled(pre, tag="rp")
         eng.drain()
         np.testing.assert_array_equal(occ.output_row(), want)
         eng.reset()
+    spans = tracing.get_tracer().spans(trace_id=trace_id, name="engine.prefill")
+    assert [s.attrs["bucket"] for s in spans] == [rung, rung]
     # Budget can only be clamped downward at commit; the clamped result
     # matches a plain insert at the clamped budget, padding and all.
     p, kw = prompts[0], cases[0]

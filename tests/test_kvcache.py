@@ -684,12 +684,12 @@ def test_prefill_write_stores_the_recurrent_row_and_release_clears_nothing(kind)
         }
 
     row, _ = backend.acquire(2, np.arange(10, dtype=np.int32), 6)
-    cache = backend.prefill_write(cache, prefilled(1.0), jnp.int32(2), jnp.asarray(row))
+    cache = backend.prefill_write(cache, prefilled(1.0), jnp.int32(2), jnp.asarray(row), 16)
     assert float(cache["recurrent"][:, 2].min()) == 1.0
     assert float(jnp.abs(cache["recurrent"][:, jnp.array([0, 1, 3])]).max()) == 0.0
     backend.release(2)
     row, _ = backend.acquire(2, np.arange(5, dtype=np.int32) + 50, 4)
-    cache = backend.prefill_write(cache, prefilled(3.0), jnp.int32(2), jnp.asarray(row))
+    cache = backend.prefill_write(cache, prefilled(3.0), jnp.int32(2), jnp.asarray(row), 16)
     assert float(cache["recurrent"][:, 2].min()) == float(cache["recurrent"][:, 2].max()) == 3.0
     if kind == "paged":
         assert backend.reserved_tokens() == 16  # 9 positions in blocks of 8: keys and values only
