@@ -77,7 +77,7 @@ from jax import lax
 
 from . import perfwatch, tracing
 from .logging import get_logger
-from .ops.paged_decode import decode_walked_positions
+from .ops.paged_decode import decode_copy_plan, decode_walked_positions
 from .utils.fault import (
     EngineCapacityError,
     EngineInvariantError,
@@ -141,6 +141,10 @@ class SlotOccupant:
     # ``ServingResult.decode_steps`` span-summary source
     trace_id: Optional[str] = None
     decode_steps: int = 0
+    # the paged decode kernel's copies over one leaf of one layer, by the
+    # slot's live blocks (`decode_copy_plan` of its table row, made once at
+    # admission); None where that kernel does not walk the slot
+    copies: Optional[np.ndarray] = None
 
     def output_row(self) -> np.ndarray:
         """prompt + emitted tokens, padded with ``pad_id`` to the full
@@ -1129,7 +1133,7 @@ class ContinuousBatchingEngine:
         occ = SlotOccupant(
             slot=slot, tag=tag, prompt=prompt, budget=max_new_tokens,
             pad_id=pad_id, eos_id=eos_token_id, inserted_s=self._clock(),
-            trace_id=trace_id,
+            trace_id=trace_id, copies=self._copy_plan(table_row),
         )
         self._occupants[slot] = occ
         self.inserted += 1
@@ -1166,7 +1170,7 @@ class ContinuousBatchingEngine:
         occ = SlotOccupant(
             slot=slot, tag=tag, prompt=prompt, budget=max_new_tokens,
             pad_id=pad_id, eos_id=eos_token_id, inserted_s=self._clock(),
-            trace_id=trace_id, prefilling=True,
+            trace_id=trace_id, prefilling=True, copies=self._copy_plan(table_row),
         )
         # host-tier restore: consecutive spilled blocks past the device
         # registry's shared depth scatter back in ONE program — a host hit
@@ -1498,7 +1502,7 @@ class ContinuousBatchingEngine:
         occ = SlotOccupant(
             slot=slot, tag=tag, prompt=prompt, budget=budget,
             pad_id=pad_id, eos_id=pre.eos_token_id, inserted_s=self._clock(),
-            trace_id=trace_id,
+            trace_id=trace_id, copies=self._copy_plan(table_row),
         )
         self._occupants[slot] = occ
         self.inserted += 1
@@ -1528,7 +1532,9 @@ class ContinuousBatchingEngine:
         # occupants; nothing is fetched from the device for it). It times
         # the host dispatch only — the jitted body itself never sees the
         # tracer (G107) — and carries what the step was dispatched with:
-        # rows doing useful work, and live against reserved KV positions
+        # rows doing useful work, live against reserved KV positions, and the
+        # blocks the decode kernel copies against the copies it issues
+        live_blocks, copies = self.walk_copies()
         with tracing.span(
             "engine.decode_step",
             live=self.live_count(), tick=self._tick,
@@ -1536,6 +1542,7 @@ class ContinuousBatchingEngine:
             kv_live_tokens=self.live_tokens(),
             kv_reserved_tokens=self._backend.reserved_tokens(),
             kv_walked_tokens=self.walked_tokens(),
+            kv_live_blocks=live_blocks, kv_copy_descriptors=copies,
             kv_row_bytes=self._kv_row_bytes,
         ):
             self._donated, self._carried, counters = self._decode_jit(
@@ -1992,14 +1999,41 @@ class ContinuousBatchingEngine:
         retired slot's device position stays where it was until the next
         insert, which this does not see). 0 where that kernel does not run:
         without a block pool, or with the reference attention over one."""
-        block_size = getattr(self._backend, "block_size", 0)
-        if not block_size or self.attention_impl != "pallas":
+        if not self._walks_pool():
             return 0
+        block_size = self._backend.block_size
         latent = self._family.value_dim is not None  # the kernel's longer chunk
         return sum(
             decode_walked_positions(_held_positions(o), block_size, latent)
             for o in self._occupants
         )
+
+    def _walks_pool(self) -> bool:
+        """Whether the paged decode kernel walks this engine's cache."""
+        return bool(getattr(self._backend, "block_size", 0)) and self.attention_impl == "pallas"
+
+    def _copy_plan(self, table_row) -> Optional[np.ndarray]:
+        if not self._walks_pool():
+            return None
+        latent = self._family.value_dim is not None
+        return decode_copy_plan(table_row, self._backend.block_size, latent)
+
+    def walk_copies(self) -> Tuple[int, int]:
+        """``(blocks, copies)`` the paged decode kernel moves over one leaf of
+        one layer in a step dispatched now: each slot's live blocks, and the
+        copy descriptors they take (``decode_copy_plan`` of the slot's row);
+        a slot that holds nothing rides on one block and one copy.
+        ``(0, 0)`` where that kernel does not run, as for
+        :meth:`walked_tokens`."""
+        if not self._walks_pool():
+            return 0, 0
+        block_size = self._backend.block_size
+        blocks = copies = 0
+        for occ in self._occupants:
+            held = -(-_held_positions(occ) // block_size)
+            blocks += max(held, 1)
+            copies += int(occ.copies[held]) if held else 1
+        return blocks, copies
 
     def stats(self) -> dict:
         """Observability twin of ``generate_cache_stats``: how many distinct

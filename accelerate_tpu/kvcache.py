@@ -221,6 +221,13 @@ class PagedKVLayout:
         # (commit_column) and the fused flash-decode kernel walks the tables
         # itself — no dense view is ever materialized (ops/paged_decode.py)
         self.attention_impl = attention_impl
+        if attention_impl == "pallas":
+            from .ops.paged_decode import decode_chunk_runs
+
+            # how each chunk of a latent pool's walk breaks into runs of the
+            # pool, made once here, outside the layer loop (dead code where the
+            # step walks a per-head pool, whose blocks are copied one by one)
+            self.chunk_runs = decode_chunk_runs(tables, block_size)
 
     @jax.named_scope("kv.gather")
     def view(self, pool, layer=None):
@@ -407,7 +414,8 @@ def attend_step(layout, store, layer, q, k, v, pos, *, scale=None, softcap=None,
             values = layout.commit_column(values, v, pos, layer)
         rows = pos if jnp.ndim(pos) else jnp.broadcast_to(pos, q.shape[:1])
         out = _run_kernel(paged_flash_decode, q, keys, values, layout.tables, rows,
-                          scale=scale, softcap=softcap, layer=layer, value_dim=value_dim)
+                          scale=scale, softcap=softcap, layer=layer, value_dim=value_dim,
+                          chunk_runs=layout.chunk_runs if latent else None)
         return out.astype(q.dtype), (keys, values)
     out, dense_k, dense_v = _attend_dense(
         _write_at, layout, store, layer, q, k, v, pos, value_dim,
@@ -616,7 +624,7 @@ class PagedBlockPool:
     Single-threaded by design — the serving worker owns the engine. Block
     states:
 
-    * **free** — on the free list, content meaningless.
+    * **free** — set in the free map, content meaningless.
     * **active** — refcount >= 1; owned by >= 1 live slots.
     * **cached** — refcount 0 but still registered under its prompt-prefix
       key; serves prefix hits across *sequential* waves and is evicted LRU
@@ -625,10 +633,20 @@ class PagedBlockPool:
     The registry keys are the exact prefix bytes ``prompt[: (d+1) *
     block_size]`` — no hash collisions, and a lookup walks depths 0, 1, 2…
     stopping at the first miss, so evicting a shallow block simply orphans
-    (and stops serving) its deeper extensions."""
+    (and stops serving) its deeper extensions.
+
+    A request's private blocks are handed out as two ascending runs of free
+    blocks where the free map holds them: the blocks it registers from the top
+    of the pool, the rest from the bottom (each split at the decode walk's
+    chunk boundaries, ``run_blocks`` blocks, where no run is that long), so
+    that the walk copies a chunk's live blocks with a few descriptors
+    (``ops/paged_decode.py``) and blocks left cached by a release do not cut
+    the free ones into short runs. Cached blocks are evicted only for what the
+    free ones lack, LRU first, before the runs are chosen: the same blocks a
+    pool that took them one at a time would evict."""
 
     def __init__(self, *, num_blocks: int, block_size: int, slots: int,
-                 blocks_per_row: int):
+                 blocks_per_row: int, run_blocks: int = 1):
         if num_blocks < 2:
             raise ValueError(
                 f"pool needs >= 2 blocks (1 is the reserved null block), "
@@ -638,6 +656,7 @@ class PagedBlockPool:
         self.block_size = block_size
         self.slots = slots
         self.blocks_per_row = blocks_per_row
+        self.run_blocks = run_blocks
         # host-tier spill interception: when set, _evict_one hands every
         # still-registered LRU victim's (key, block) to the owner BEFORE
         # the registry entry dies, so the backend can snapshot the device
@@ -648,7 +667,8 @@ class PagedBlockPool:
 
     # -------------------------------------------------------------- lifecycle
     def reset(self) -> None:
-        self._free: List[int] = list(range(self.num_blocks - 1, _NULL_BLOCK, -1))
+        self._is_free = np.ones(self.num_blocks, dtype=bool)
+        self._is_free[_NULL_BLOCK] = False
         self._ref = np.zeros(self.num_blocks, dtype=np.int64)
         self._registry: Dict[bytes, int] = {}
         self._key_of: Dict[int, bytes] = {}
@@ -674,7 +694,7 @@ class PagedBlockPool:
 
     def free_blocks(self) -> int:
         """Allocatable capacity: truly free + LRU-evictable cached."""
-        return len(self._free) + len(self._cached)
+        return self._free_count() + len(self._cached)
 
     def active_blocks(self) -> int:
         return int((self._ref > 0).sum())
@@ -700,7 +720,7 @@ class PagedBlockPool:
         hits = self._shared_prefix(prompt)
         needed = self.blocks_needed(len(prompt), budget) - len(hits)
         evictable = len(self._cached) - sum(1 for b in hits if self._ref[b] == 0)
-        return needed <= len(self._free) + evictable
+        return needed <= self._free_count() + evictable
 
     # -------------------------------------------------------------- allocation
     def _evict_one(self) -> int:
@@ -718,10 +738,43 @@ class PagedBlockPool:
             del self._registry[key]
         return blk
 
-    def _alloc_block(self) -> int:
-        if self._free:
-            return self._free.pop()
-        return self._evict_one()
+    def _free_count(self) -> int:
+        return int(np.count_nonzero(self._is_free))
+
+    def _take_runs(self, at: int, count: int, high: bool) -> List[int]:
+        """``count`` free blocks (the free map holds enough) for row entries
+        ``at...``, in ascending runs: all of them as one run where the free
+        map holds one that long, else a run to the walk's next chunk boundary
+        (``run_blocks``), else the longest free run whole. The run is the free
+        map's highest that fits, taken from its top, where ``high``; else its
+        lowest, taken from its bottom."""
+        blocks: List[int] = []
+        while len(blocks) < count:
+            edges = np.flatnonzero(np.diff(self._is_free, prepend=False, append=False))
+            starts, lengths = edges[::2], edges[1::2] - edges[::2]
+            rest = count - len(blocks)
+            to_boundary = self.run_blocks - (at + len(blocks)) % self.run_blocks
+            for want in (rest, min(rest, to_boundary)):
+                fits = np.flatnonzero(lengths >= want)
+                if fits.size:
+                    i = fits[-1] if high else fits[0]
+                    break
+            else:
+                i = int(np.argmax(lengths))
+                want = int(lengths[i])
+            first = int(starts[i] + lengths[i] - want if high else starts[i])
+            self._is_free[first:first + want] = False
+            blocks.extend(range(first, first + want))
+        return blocks
+
+    def _deregister(self, key: bytes) -> None:
+        """Drop ``key``'s registration; a cached block it held is plain free."""
+        old = self._registry.pop(key, None)
+        if old is not None:
+            del self._key_of[old]
+            if old in self._cached:  # orphan at ref 0: plain free now
+                del self._cached[old]
+                self._is_free[old] = True
 
     def _register(self, key: bytes, blk: int) -> None:
         """Map ``key`` -> ``blk`` in the prefix registry, deregistering any
@@ -731,12 +784,8 @@ class PagedBlockPool:
         an old block. Deregister it first — otherwise the old block's
         eventual eviction would delete the NEW registry entry, and evicting
         the new block afterwards would KeyError."""
-        old = self._registry.get(key)
-        if old is not None and old != blk:
-            del self._key_of[old]
-            if old in self._cached:  # orphan at ref 0: plain free now
-                del self._cached[old]
-                self._free.append(old)
+        if self._registry.get(key) not in (None, blk):
+            self._deregister(key)
         self._registry[key] = blk
         self._key_of[blk] = key
 
@@ -781,10 +830,25 @@ class PagedBlockPool:
         self.prefix_hits += len(hits)
         self.prefix_misses += full - len(hits)
         deferred: List[Tuple[bytes, int]] = []
-        # private blocks; full prompt blocks past the shared depth register
-        # so the NEXT request with this prefix shares them
-        for j in range(len(hits), total):
-            blk = self._alloc_block()
+        if not defer_register:
+            # the registrations this request supersedes (deeper blocks of a
+            # prefix whose shallower block was evicted) free their cached
+            # blocks before any block is chosen, as they would be reused
+            for j in range(len(hits), full):
+                self._deregister(prompt[: (j + 1) * bs].tobytes())
+        # what the free blocks lack is evicted first, LRU first, so the
+        # private blocks can be chosen as runs of free ones
+        for _ in range(total - len(hits) - self._free_count()):
+            self._is_free[self._evict_one()] = True
+        # the full prompt blocks past the shared depth register, so that the
+        # NEXT request with this prefix shares them: they come from the top of
+        # the pool and the rest from the bottom, so that what stays cached
+        # after a release lies beside other cached blocks and leaves the rest
+        # free in long runs (a chunk of the walk copies two runs whole)
+        fresh = max(full - len(hits), 0)
+        private = (self._take_runs(len(hits), fresh, high=True)
+                   + self._take_runs(len(hits) + fresh, total - len(hits) - fresh, high=False))
+        for j, blk in zip(range(len(hits), total), private):
             self._ref[blk] = 1
             if j < full:
                 key = prompt[: (j + 1) * bs].tobytes()
@@ -833,7 +897,7 @@ class PagedBlockPool:
                     self._cached[blk] = None  # most-recently-released = MRU
                     self._cached.move_to_end(blk)
                 else:
-                    self._free.append(blk)
+                    self._is_free[blk] = True
         self._rows[slot] = []
         self.tables[slot] = _NULL_BLOCK
 
@@ -841,7 +905,7 @@ class PagedBlockPool:
         lookups = self.prefix_hits + self.prefix_misses
         return {
             "blocks_total": self.num_blocks,
-            "blocks_free": len(self._free),
+            "blocks_free": self._free_count(),
             "blocks_cached": len(self._cached),
             "blocks_active": self.active_blocks(),
             "prefix_hits": self.prefix_hits,
@@ -1119,9 +1183,14 @@ class PagedKVBackend(KVCacheBackend):
         self._dtype = config.compute_dtype
         self.kind = "paged_int8" if quantized else "paged"
         self.attention_impl = attention_impl
+        from .ops.paged_decode import decode_chunk_positions
+
         self.pool = PagedBlockPool(
             num_blocks=pool_blocks, block_size=block_size, slots=slots,
             blocks_per_row=self.blocks_per_row,
+            # a slot's runs break where the decode walk's chunks do
+            run_blocks=decode_chunk_positions(block_size, family.value_dim is not None)
+            // block_size,
         )
         self._device_tables_cache = None
         # ---------------------------------------------- host-RAM spill tier

@@ -17,6 +17,9 @@ block table *inside* the kernel instead:
   ``tables[slot, j]`` whole (all KV heads: one contiguous copy) — into one
   of two buffers, so that the next
   chunk, or the next slot's first, is in flight while this one is computed.
+  Over a latent pool a chunk whose live blocks are at most two runs of the
+  pool (``decode_chunk_runs``, made once a step) is copied a power of two
+  blocks at a time, one copy for each set bit of a run's length.
   Blocks wholly past a slot's position are *neither fetched nor weighted*
   — exactly the contract documented on ``paged_attention`` (masked scores
   softmax to an exp-underflow-exact 0.0, so skipping == computing): a dead
@@ -83,6 +86,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -91,7 +95,8 @@ from .attention import NEG_INF
 
 __all__ = [
     "paged_flash_decode", "paged_flash_verify", "fused_sample",
-    "decode_chunk_positions", "decode_walked_positions",
+    "decode_chunk_positions", "decode_walked_positions", "decode_chunk_runs",
+    "decode_copy_plan", "decode_copy_descriptors",
 ]
 
 
@@ -162,12 +167,79 @@ def decode_walked_positions(live: int, block_size: int, latent: bool = False) ->
     return max(-(-live // chunk), 1) * chunk
 
 
-def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, *rest,
-                   block_size, chunk_blocks, blocks_per_row, n_rep, d, scale,
-                   softcap, quantized, value_dim):
+def decode_chunk_runs(block_tables, block_size: int):
+    """How each chunk of a latent pool's rows breaks into runs of the pool,
+    for `paged_flash_decode`'s copies: ``(B, chunks)`` int32, ``head + (reach
+    << 16)``, where ``head`` of a chunk's leading blocks are one run (each
+    block the one before it plus one) and ``reach`` of them are at most two.
+    numpy in, numpy out (the engine's count at admission); a traced array in,
+    one small XLA computation (the engine's decode step makes it once, outside
+    its loop over layers). A layer's offset on the tables keeps every run."""
+    xp = np if isinstance(block_tables, np.ndarray) else jnp
+    C = decode_chunk_positions(block_size, latent=True) // block_size
+    b, bpr = block_tables.shape
+    chunks = xp.pad(block_tables, ((0, 0), (0, -bpr % C))).reshape(b, -1, C)
+    breaks = xp.cumsum(chunks[..., 1:] != chunks[..., :-1] + 1, axis=-1)
+    head = 1 + xp.sum(breaks == 0, axis=-1)
+    reach = 1 + xp.sum(breaks <= 1, axis=-1)
+    return (head + reach * (1 << 16)).astype(xp.int32)
+
+
+def _chunk_split(runs, live):
+    """Where a chunk's ``live`` blocks split into their two runs (``live``
+    itself where they are one), or -1 where they are more than two."""
+    head, reach = runs & 0xFFFF, runs >> 16
+    if isinstance(live, np.ndarray):
+        return np.where(live <= reach, np.minimum(head, live), -1)
+    return jnp.where(live <= reach, jnp.minimum(head, live), -1)
+
+
+def decode_copy_plan(row, block_size: int, latent: bool = False) -> np.ndarray:
+    """Copies `paged_flash_decode` issues over one leaf of one layer for a slot
+    whose table row is ``row``, by how many of its blocks are live: entry
+    ``n`` for the row's first ``n`` blocks (entry 0, a slot that holds
+    nothing, rides at position 0 on one live block). A per-head pool's blocks
+    take one copy each. Over a latent pool, a chunk's live blocks that are at
+    most two runs in the pool take one copy for each set bit of each run's
+    length, other live blocks one copy each. The kernel follows the same rule
+    (``_decode_kernel``)."""
+    row = np.asarray(row, np.int64)
+    n = np.arange(1, row.size + 1)
+    if not latent:
+        return np.concatenate([[1], n])
+    C = decode_chunk_positions(block_size, latent) // block_size
+    live = np.arange(1, C + 1)
+    split = _chunk_split(decode_chunk_runs(row[None], block_size)[0][:, None], live)
+    bits = np.bitwise_count
+    cost = np.where(split >= 0, bits(np.maximum(split, 0)) + bits(live - split), live)
+    before = np.concatenate([[0], np.cumsum(cost[:, -1])])  # the whole chunks ahead
+    plan = before[(n - 1) // C] + cost[(n - 1) // C, (n - 1) % C]
+    return np.concatenate([plan[:1], plan])
+
+
+def decode_copy_descriptors(row, live: int, block_size: int, latent: bool = False) -> int:
+    """Copies `paged_flash_decode` issues over one leaf of one layer for a slot
+    that holds ``live`` positions (its ``pos + 1``) through table row ``row``
+    (:func:`decode_copy_plan`)."""
+    blocks = min(-(-live // block_size), len(row))
+    return int(decode_copy_plan(row, block_size, latent)[blocks])
+
+
+def _pieces(blocks: int):
+    """Static sizes of the copies that move a run of up to ``blocks`` blocks:
+    one power of two for each bit a count of blocks can have, the largest
+    first. A run of ``n`` blocks takes the sizes of ``n``'s set bits, each at
+    the offset of the larger ones before it."""
+    return [1 << k for k in reversed(range(blocks.bit_length()))]
+
+
+def _decode_kernel(tables_ref, pos_ref, *refs, block_size, chunk_blocks, blocks_per_row,
+                   n_rep, d, scale, softcap, quantized, value_dim):
     # a latent pool (``value_dim``) has no pool of values and no buffer for
-    # them: a row's values are its own first ``value_dim`` columns
+    # them: a row's values are its own first ``value_dim`` columns; its
+    # chunks' runs (``decode_chunk_runs``) ride in as a third scalar operand
     latent = value_dim is not None
+    runs_ref, q_ref, k_hbm, *rest = refs if latent else (None, *refs)
     if latent:
         v_hbm = vbuf = None
         o_ref, kbuf, sem, acc_ref, base_ref = rest
@@ -207,7 +279,7 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, *rest,
         # unrolled: rolled, the descriptors' scalar work reads 7-12% slower a call
         lax.fori_loop(0, C, block, 0, unroll=True)
 
-    def start(slot_b, chunk, buf):
+    def start_blocks(slot_b, chunk, buf):
         def fn(copy_k, copy_v, jj, live):
             @pl.when(live)
             def _():
@@ -216,7 +288,15 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, *rest,
                     copy_v.start()
         each_live_block(slot_b, chunk, buf, fn)
 
-    def wait(slot_b, chunk, buf):
+    def zero(buf, jj):
+        # A block past the slot's position is never fetched: its rows of the
+        # buffer hold whatever an earlier chunk left there. Its scores are
+        # masked below, but a weight of exactly 0 times a stale NaN is a NaN
+        # in P x V: its value rows read as zeros.
+        held = kbuf if latent else vbuf  # a latent row is its own value
+        held[buf, jj] = jnp.zeros(held.shape[2:], held.dtype)
+
+    def wait_blocks(slot_b, chunk, buf):
         def fn(copy_k, copy_v, jj, live):
             @pl.when(live)
             def _():
@@ -224,15 +304,58 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, *rest,
                 if copy_v is not None:
                     copy_v.wait()  # graft: wait-ok
 
-            # A block past the slot's position is never fetched: its rows of
-            # the buffer hold whatever an earlier chunk left there. Its
-            # scores are masked below, but a weight of exactly 0 times a
-            # stale NaN is a NaN in P x V: its value rows read as zeros.
-            @pl.when(jnp.logical_not(live))
-            def _():
-                held = kbuf if latent else vbuf  # a latent row is its own value
-                held[buf, jj] = jnp.zeros(held.shape[2:], held.dtype)
+            pl.when(jnp.logical_not(live))(lambda: zero(buf, jj))
         each_live_block(slot_b, chunk, buf, fn)
+
+    start, wait = start_blocks, wait_blocks
+    if latent:
+        # A latent chunk's live blocks that are at most two runs in the pool
+        # (``decode_chunk_runs``: the first ``split`` of them and the rest)
+        # are copied a power of two blocks of a run at a time, one copy for
+        # each set bit of the run's length (``_pieces``), where 32 copies a
+        # chunk, one a block, cost more than the chunk's bytes; other chunks
+        # a block at a time. The rule is ``decode_copy_plan``'s.
+        def live_and_split(slot_b, chunk):
+            held = jnp.minimum(pos_ref[slot_b] // bs + 1, blocks_per_row)
+            live = jnp.clip(held - chunk * C, 0, C)  # the chunk's leading blocks
+            return live, _chunk_split(runs_ref[slot_b, chunk], live)
+
+        def block_of(slot_b, chunk, jj):
+            j = chunk * C + jj
+            return tables_ref[slot_b, jnp.minimum(j, blocks_per_row - 1) if blocks_per_row % C else j]
+
+        def each_piece(slot_b, chunk, buf, live, split, fn):
+            runs = [(block_of(slot_b, chunk, 0), 0, split),
+                    (block_of(slot_b, chunk, jnp.minimum(split, C - 1)), split, live - split)]
+            # a run is no longer than the chunk, the row or the pool
+            for size in _pieces(min(C, blocks_per_row, k_hbm.shape[0])):
+                for first, at, length in runs:
+                    off = length // (2 * size) * (2 * size)  # the larger pieces before it
+                    copy = pltpu.make_async_copy(k_hbm.at[pl.ds(first + off, size)],
+                                                 kbuf.at[buf, pl.ds(at + off, size)],
+                                                 sem.at[0, buf])
+                    pl.when((length & size) != 0)(functools.partial(fn, copy))
+
+        def start(slot_b, chunk, buf):
+            live, split = live_and_split(slot_b, chunk)
+            pl.when(split >= 0)(lambda: each_piece(slot_b, chunk, buf, live, split,
+                                                   lambda copy: copy.start()))
+            pl.when(split < 0)(lambda: start_blocks(slot_b, chunk, buf))
+
+        def wait(slot_b, chunk, buf):
+            live, split = live_and_split(slot_b, chunk)
+
+            @pl.when(split >= 0)
+            def _():
+                each_piece(slot_b, chunk, buf, live, split,
+                           lambda copy: copy.wait())  # graft: wait-ok
+
+                @pl.when(live < C)  # only a slot's last chunk has blocks past it
+                def _():
+                    for jj in range(C):
+                        pl.when(jj >= live)(functools.partial(zero, buf, jj))
+
+            pl.when(split < 0)(lambda: wait_blocks(slot_b, chunk, buf))
 
     @pl.when(b == 0)
     def _first():
@@ -384,6 +507,7 @@ def paged_flash_decode(
     softcap: Optional[float] = None,
     layer: Optional[jax.Array] = None,
     value_dim: Optional[int] = None,
+    chunk_runs: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Single-token paged decode attention as a Pallas flash kernel.
@@ -417,15 +541,22 @@ def paged_flash_decode(
     a row's first ``value_dim`` columns are also its value, and each live row
     is copied out of HBM once and serves both matmuls. Returns (B, 1, h,
     ``value_dim``).
+
+    The kernel copies a chunk's live blocks one at a time, but over a latent
+    pool a chunk whose live blocks are at most two runs in the pool a power of
+    two blocks of a run at a time, one copy for each set bit of each run's
+    length (:func:`decode_copy_plan`). ``chunk_runs``, a latent pool's alone,
+    is :func:`decode_chunk_runs` of the tables, made here where it is not
+    handed in (a caller that calls this once a layer makes it once).
     """
     walk = _latent_decode if value_dim is not None else _paged_flash_decode
     return walk(q, k_pool, v_pool, block_tables, pos, k_scale=k_scale, v_scale=v_scale,
                 scale=scale, softcap=softcap, layer=layer, value_dim=value_dim,
-                interpret=interpret)
+                chunk_runs=chunk_runs, interpret=interpret)
 
 
 def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scale, scale,
-                        softcap, layer, value_dim, interpret):
+                        softcap, layer, value_dim, chunk_runs, interpret):
     b, sq, h, d = q.shape
     if sq != 1:
         raise ValueError(f"paged_flash_decode takes one query token, got {sq}")
@@ -455,6 +586,20 @@ def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scal
     chunk = decode_chunk_positions(bs, latent)
     chunk_blocks = chunk // bs
     n_chunks = -(-bpr // chunk_blocks)
+    # the scalar operands: the tables, the positions and, over a latent pool,
+    # how its chunks break into runs
+    scalars = [block_tables, pos.astype(jnp.int32)]
+    if latent:
+        if chunk_runs is None:
+            chunk_runs = decode_chunk_runs(block_tables, bs)
+        if chunk_runs.shape != (b, n_chunks):
+            raise ValueError(
+                f"chunk_runs {chunk_runs.shape} is not decode_chunk_runs of these tables: "
+                f"({b}, {n_chunks}) for chunks of {chunk_blocks} blocks"
+            )
+        scalars.append(chunk_runs)
+    elif chunk_runs is not None:
+        raise ValueError("chunk_runs is a latent pool's: a per-head pool is copied a block at a time")
 
     # one row a query head, zero outside the lanes of its own kv head, padded
     # to whole sublane tiles of the operand's dtype. The query is laid flat
@@ -468,7 +613,7 @@ def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scal
     qx = jnp.where(own[None, :, None, :], qi, 0).reshape(b, h, lanes)
     qx = jnp.pad(qx, ((0, 0), (0, rows - h), (0, 0)))
 
-    q_spec = pl.BlockSpec((1, rows, lanes), lambda bb, t, p: (bb, 0, 0))
+    q_spec = pl.BlockSpec((1, rows, lanes), lambda bb, *_: (bb, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec] + [pool_spec] * len(pools)
     args = [qx, *pools]
@@ -476,7 +621,7 @@ def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scal
     value_buffers = [] if latent else [
         pltpu.VMEM((2, chunk_blocks, bs, lanes), pools[1].dtype)]
     if quantized:
-        s_spec = pl.BlockSpec((1, n_chunks, 1, chunk), lambda bb, t, p: (bb, 0, 0, 0))
+        s_spec = pl.BlockSpec((1, n_chunks, 1, chunk), lambda bb, *_: (bb, 0, 0, 0))
         in_specs += [s_spec, s_spec]
         args += [
             _gathered_scale_rows(k_scale, block_tables, n_chunks, chunk),
@@ -484,10 +629,10 @@ def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scal
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, d_out), lambda bb, t, p: (bb, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, d_out), lambda bb, *_: (bb, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, chunk_blocks, bs, lanes), k_pool.dtype),
             *value_buffers,
@@ -508,7 +653,7 @@ def _paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *, k_scale, v_scal
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(block_tables, pos.astype(jnp.int32), *args)
+    )(*scalars, *args)
     return out[:, :h].reshape(b, 1, h, d_out)
 
 

@@ -214,6 +214,138 @@ def test_pool_reregistration_after_partial_prefix_eviction():
     assert pool.active_blocks() == 11
 
 
+# ------------------------------------------------------- the pool's runs
+def _runs(blocks):
+    """Lengths of the ascending runs ``blocks`` is made of, in row order."""
+    breaks = np.flatnonzero(np.diff(blocks) != 1) + 1
+    return np.diff(np.r_[0, breaks, len(blocks)]).tolist()
+
+
+def test_pool_hands_a_fresh_request_its_blocks_as_two_runs():
+    # the blocks a request registers (its full prompt blocks) from the top of
+    # the pool, the rest from the bottom: each one ascending run
+    pool = _pool(num_blocks=64, block_size=4, slots=3, blocks_per_row=16)
+    row, _ = pool.acquire(0, np.arange(1, 11, dtype=np.int32), budget=14)  # 6 blocks, 2 full
+    assert row[:6].tolist() == [62, 63, 1, 2, 3, 4]
+    row, _ = pool.acquire(1, np.arange(20, 23, dtype=np.int32), budget=10)  # 4, none full
+    assert row[:4].tolist() == [5, 6, 7, 8]
+    row, _ = pool.acquire(2, np.arange(30, 42, dtype=np.int32), budget=4, defer_register=True)
+    assert row[:4].tolist() == [59, 60, 61, 9]  # deferred: registered later, placed the same
+
+
+def test_pool_finds_a_run_after_shuffled_releases_and_admits_without_one():
+    rng = np.random.default_rng(0)
+    pool = _pool(num_blocks=101, block_size=4, slots=8, blocks_per_row=25)
+    for step in range(200):
+        slot = int(rng.integers(8))
+        pool.release(slot)
+        # no full prompt block: every private block comes from the bottom
+        budget = int(rng.integers(1, 80))
+        need = pool.blocks_needed(1, budget)
+        free = pool._is_free.copy()
+        edges = np.flatnonzero(np.diff(free, prepend=False, append=False))
+        longest = (edges[1::2] - edges[::2]).max() if edges.size else 0
+        if not pool.can_admit(np.array([7], np.int32), budget):
+            continue
+        row, _ = pool.acquire(slot, np.array([7], np.int32), budget)
+        blocks = row[:need]
+        assert free[blocks].all() and len(set(blocks.tolist())) == need
+        if longest >= need:  # a run of its length was free: it got one
+            assert _runs(blocks) == [need]
+        else:  # none was: admitted all the same, in runs to the walk's chunks at most
+            assert sum(_runs(blocks)) == need
+
+
+def test_pool_prefix_hits_lead_the_row_and_stay_while_active():
+    pool = _pool(num_blocks=13, block_size=4, slots=3, blocks_per_row=8)
+    prompt = np.arange(1, 13, dtype=np.int32)  # 3 full blocks
+    row_a, _ = pool.acquire(0, prompt, budget=4)
+    row_b, shared = pool.acquire(1, prompt, budget=8)
+    assert shared == 3 and (row_b[:3] == row_a[:3]).all()
+    pool.release(0)
+    # a stranger that needs every block left: the shared blocks are active
+    # (slot 1 holds them), so nothing of them is evicted
+    stranger = np.arange(100, 101, dtype=np.int32)
+    budget = 4 * pool.free_blocks() - 1
+    assert pool.can_admit(stranger, budget) and not pool.can_admit(stranger, budget + 1)
+    row_c, _ = pool.acquire(0, stranger, budget)
+    assert not set(row_c[row_c > 0].tolist()) & set(row_b[:3].tolist())
+    assert pool._shared_prefix(prompt) == row_b[:3].tolist()
+
+
+def test_pool_evictions_go_through_the_spill_hook_lru_first():
+    pool = _pool(num_blocks=9, block_size=4, slots=4, blocks_per_row=8)
+    spilled = []
+    pool.spill_fn = lambda key, blk: spilled.append(blk)
+    first = [pool.acquire(s, np.arange(10 * s, 10 * s + 8, dtype=np.int32), budget=1)[0]
+             for s in range(2)]  # 3 blocks each, 2 registered
+    pool.release(1)
+    pool.release(0)  # cached, LRU first: slot 1's two, then slot 0's
+    row, _ = pool.acquire(2, np.array([99], np.int32), budget=27)  # 7 blocks: 4 free + 3 cached
+    assert spilled == [first[1][0], first[1][1], first[0][0]]
+    assert set(spilled) <= set(row[:7].tolist())
+
+
+class _OneAtATime(PagedBlockPool):
+    """The pool as it took blocks before runs: one at a time, a free block
+    while any is left, else the LRU cached one, registering as it goes."""
+
+    def acquire(self, slot, prompt, budget, defer_register=False):
+        prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
+        assert self.can_admit(prompt, budget) and not defer_register
+        bs, total = self.block_size, self.blocks_needed(len(prompt), budget)
+        full = len(prompt) // bs
+        hits = self._shared_prefix(prompt)
+        row = []
+        for blk in hits:
+            if self._ref[blk] == 0:
+                del self._cached[blk]
+            self._ref[blk] += 1
+            row.append(blk)
+        for j in range(len(hits), total):
+            free = np.flatnonzero(self._is_free)
+            blk = int(free[-1]) if free.size else self._evict_one()
+            self._is_free[blk] = False
+            self._ref[blk] = 1
+            if j < full:
+                self._register(prompt[: (j + 1) * bs].tobytes(), blk)
+            row.append(blk)
+        self._rows[slot] = row
+        self.tables[slot] = 0
+        self.tables[slot, : len(row)] = row
+        return self.tables[slot].copy(), len(hits)
+
+
+def test_pool_admits_and_caches_as_a_pool_that_takes_blocks_one_at_a_time():
+    # shared prefixes of six system prompts, cut at random depths, so that
+    # hits, evictions of shallow blocks and orphaned deeper ones all happen
+    # (this seed: 85 evictions, 5 orphans superseded and 77 blocks hit a pool)
+    rng = np.random.default_rng(3)
+    systems = [rng.integers(1, 50, size=24).astype(np.int32) for _ in range(6)]
+    kw = dict(num_blocks=40, block_size=4, slots=5, blocks_per_row=12)
+    pools = (PagedBlockPool(**kw, run_blocks=4), _OneAtATime(**kw))
+    probes = [(s[: 4 * d + 2], 5) for s in systems for d in range(6)]
+    for step in range(400):
+        slot = int(rng.integers(5))
+        if rng.random() < 0.4:
+            for pool in pools:
+                pool.release(slot)
+        else:
+            s = systems[int(rng.integers(len(systems)))]
+            prompt = np.r_[s[: int(rng.integers(1, 24))], rng.integers(50, 60, size=2)]
+            budget = int(rng.integers(1, 20))
+            admit = [pool.can_admit(prompt, budget) and not pool._rows[slot] for pool in pools]
+            assert admit[0] == admit[1]
+            if admit[0]:
+                shared = [pool.acquire(slot, prompt, budget)[1] for pool in pools]
+                assert shared[0] == shared[1]
+        runs, one = pools
+        assert runs.free_blocks() == one.free_blocks(), step
+        assert runs.stats()["blocks_cached"] == one.stats()["blocks_cached"], step
+        assert sorted(runs._registry) == sorted(one._registry), step
+        assert [runs.can_admit(*x) for x in probes] == [one.can_admit(*x) for x in probes], step
+
+
 # ------------------------------------------------------------------ int8 KV
 def test_kv_quantize_roundtrip_bound_and_determinism():
     rng = np.random.default_rng(0)
@@ -472,6 +604,53 @@ def test_walked_tokens_count_whole_chunks_of_the_decode_kernel(model, family, kv
         assert eng.walked_tokens() == 0
     eng.drain()
     assert eng.walked_tokens() == 2 * chunk
+
+
+@pytest.mark.parametrize(
+    "family,attention_impl", [("llama", "pallas"), ("llama", "reference"), ("axk1", "pallas")])
+def test_copy_counters_of_the_decode_step_follow_the_kernels_rule(
+        model, monkeypatch, family, attention_impl):
+    """`kv_live_blocks` and `kv_copy_descriptors` of the `engine.decode_step`
+    span: each slot's live blocks and the copies the decode kernel issues for
+    them by `decode_copy_descriptors` over the slot's table row (the rule the
+    kernel's own copies are held to in tests/test_paged_decode_runs.py: runs
+    over a latent pool, a copy a block over a per-head one), one block and one
+    copy for a vacant slot; nothing where the kernel does not run."""
+    from accelerate_tpu import tracing
+    from accelerate_tpu.models.axk1 import AxK1Config, create_axk1
+    from accelerate_tpu.ops.paged_decode import decode_copy_descriptors
+
+    latent = family == "axk1"
+    if latent:
+        model = create_axk1(AxK1Config.tiny(compute_dtype=jnp.float32), seed=3)
+    eng = ContinuousBatchingEngine(
+        model, slots=2, max_len=64, prompt_bucket=32, kv_cache="paged",
+        block_size=8, attention_impl=attention_impl,
+    )
+    kernel = attention_impl == "pallas"
+    assert eng.walk_copies() == ((2, 2) if kernel else (0, 0))  # both vacant
+    # 30 positions: 3 registered prompt blocks (one run from the top of a
+    # fresh pool) and a fourth from the bottom, so over a latent pool 2 + 1
+    # copies for 4 blocks; a per-head pool takes a copy a block
+    occ = eng.insert(_prompts(1, lens=(30,), seed=31)[0], max_new_tokens=20, pad_token_id=0)
+    row = eng._backend.pool.tables[occ.slot]
+    copies = 3 if latent else 4
+    assert decode_copy_descriptors(row, 30, 8, latent) == copies
+    assert eng.walk_copies() == ((5, copies + 1) if kernel else (0, 0))
+    seen = []
+    real = tracing.span
+    monkeypatch.setattr(tracing, "span", lambda name, **attrs: (
+        seen.append(attrs) if name == "engine.decode_step" else None, real(name, **attrs))[1])
+    for _ in range(3):
+        eng.step()
+    for attrs in seen:  # each step's pair is the rule's for what the step held
+        live = attrs["kv_live_tokens"]
+        want = (-(-live // 8) + 1, decode_copy_descriptors(row, live, 8, latent) + 1)
+        assert (attrs["kv_live_blocks"], attrs["kv_copy_descriptors"]) == (
+            want if kernel else (0, 0))
+    assert len(seen) == 3
+    eng.drain()
+    assert eng.walk_copies() == ((2, 2) if kernel else (0, 0))
 
 
 def test_device_tables_are_a_copy_of_the_hosts_rows(model):
